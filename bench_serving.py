@@ -22,8 +22,7 @@ unbatched server (``PIO_BATCH_ENABLED=off`` semantics) so the batched
 p99 is judged against the per-request-dispatch baseline at identical
 load.  ``--engine twotower`` runs the sweep against a deep-model engine
 (vectorized ``top_k_scores`` batch predict).  Combined with ``--faults``
-the top level is re-driven with the fault plan installed
-(BENCH_SERVING_r01.json carries clean + faulted rounds).
+the top level is re-driven with the fault plan installed.
 
 With ``--faults SPEC`` (PIO_FAULTS grammar, e.g.
 ``http.engine:delay:5ms:0.05``) the python frontend is driven TWICE on

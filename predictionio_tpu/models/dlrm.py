@@ -38,7 +38,6 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from predictionio_tpu.obs.runtime import get_compile_tracker
-from predictionio_tpu.parallel.compat import shard_map
 from predictionio_tpu.parallel.mesh import AXIS_EXPERT, put_sharded
 
 __all__ = ["DLRMConfig", "DLRMState", "init_state", "train_step",
@@ -151,7 +150,7 @@ def sharded_embedding_lookup(
         return jax.lax.psum_scatter(part, AXIS_EXPERT, scatter_dimension=0,
                                     tiled=True)            # [B/S, F, E]
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(AXIS_EXPERT, None), P(AXIS_EXPERT, None)),
         out_specs=P(AXIS_EXPERT, None, None),

@@ -10,8 +10,7 @@ The start/stop callables are injectable so tests exercise the whole
 state machine — busy, finished, platform-can't-capture — with fakes and
 no real profiler artifacts; the HTTP layer maps
 :class:`ProfilerUnavailable` to a clear **501** instead of crashing when
-the platform cannot capture (no jax, no profiler plugin, remote-tunnel
-backends).
+the platform cannot capture (no jax, no profiler plugin).
 """
 
 from __future__ import annotations

@@ -11,8 +11,12 @@ Grid: one program per solve row; per-program working set is
 ``L·K + K² + K`` floats (≤ ~0.6 MB at L=1024, K=128 — well inside VMEM).
 Matmuls sit on the MXU via ``dot_general`` with f32 accumulation.
 
-On CPU (tests) the kernel runs in interpret mode; ``fused_gram_vector``
-dispatches to the plain einsum path unless Pallas is requested/available.
+On CPU (tests) the kernels run in interpret mode; the ``fused_*`` /
+``pq_scan`` dispatchers take the XLA twin unless Pallas is requested or
+the backend is a TPU.  Every kernel here has been compiled by Mosaic
+(libtpu 0.0.34, v5e) and checked against its XLA twin by
+``chip_smoke.py``; ``tests/test_pallas_kernels.py`` keeps the
+jaxpr→Mosaic lowering green on CPU.
 """
 
 from __future__ import annotations
@@ -33,11 +37,12 @@ __all__ = ["fused_gram_vector", "fused_gram_vector_pallas",
 
 
 def pallas_supported() -> bool:
-    """True when the default backend can run the compiled kernel."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    """True when the default backend runs the Mosaic-compiled kernels
+    (TPU); anywhere else a requested kernel runs in the Pallas
+    interpreter (the CPU test path).  A backend that cannot start raises
+    here — there is no quiet CPU answer (``backend.resolve_backend``
+    logs which of the two modes a process got)."""
+    return jax.default_backend() == "tpu"
 
 
 # Per-program VMEM budget: the double-buffered [TILE_R, L, K] f32 input
@@ -390,53 +395,102 @@ def fused_gram_vector(f: jax.Array, w: jax.Array, c: jax.Array,
 
 _TOPK_TILE = 1024        # corpus rows per grid step (lane-aligned)
 _TOPK_NEG_INF = -3.4e38  # matches ops.topk.NEG_INF
+_LANES = 128
 
 
-def _topk_kernel(q_ref, items_ref, out_s_ref, out_i_ref, m_ref, mi_ref,
-                 *, tile: int, k: int, n_real: int):
-    """One corpus tile folded into the running top-k.
+def _lane_pad(k: int) -> int:
+    return -(-k // _LANES) * _LANES
 
-    ``m_ref``/``mi_ref`` are [B, k+T] merged-candidate scratch: the first
-    k lanes hold the running best (read back from the output refs, which
-    persist across the sequential TPU grid), the remaining T lanes this
-    tile's scores.  Tail tiles read an OOB-padded block — the garbage
-    columns are overwritten with NEG_INF via the global-id mask before
-    any of them can win a slot (`where` selects, never propagates a NaN).
+
+def _fold_tile_topk(s, j, out_s_ref, out_i_ref, m_ref, mi_ref, *,
+                    tile: int, k: int, n_real: int):
+    """Fold one tile's scores ``s [B, T]`` into the running top-k.
+
+    ``out_*_ref`` are [B, kp] blocks (kp = k rounded up to a lane
+    multiple) that persist across the sequential TPU grid: lanes < k hold
+    the running best, lanes ≥ k stay NEG_INF forever.  ``m_ref``/
+    ``mi_ref`` are [B, kp+T] merged-candidate scratch — running best in
+    the first kp lanes, this tile's scores behind them, so both stores
+    land on lane-aligned offsets (Mosaic refuses an unaligned or traced
+    lane index on a store).  Tail tiles read an OOB-padded block — the
+    garbage columns are overwritten with NEG_INF via the global-id mask
+    before any of them can win a slot (`where` selects, never propagates
+    a NaN).  The winner of each extract step is written with an
+    iota-select over the whole [B, kp] block for the same reason.
     """
-    j = pl.program_id(0)
-    b = q_ref.shape[0]
+    b = s.shape[0]
+    kp = out_s_ref.shape[1]
+    width = kp + tile
 
     @pl.when(j == 0)
     def _init():
         out_s_ref[:] = jnp.full_like(out_s_ref, _TOPK_NEG_INF)
         out_i_ref[:] = jnp.zeros_like(out_i_ref)
 
-    m_ref[:, :k] = out_s_ref[:]
-    mi_ref[:, :k] = out_i_ref[:]
-    s = jax.lax.dot_general(                     # MXU: [B,D]·[T,D]ᵀ
-        q_ref[:], items_ref[:],
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    m_ref[:, :kp] = out_s_ref[:]
+    mi_ref[:, :kp] = out_i_ref[:]
     gid = j * tile + jax.lax.broadcasted_iota(jnp.int32, (b, tile), 1)
-    m_ref[:, k:] = jnp.where(gid < n_real, s, _TOPK_NEG_INF)
-    mi_ref[:, k:] = gid
-    cols = jax.lax.broadcasted_iota(jnp.int32, (b, k + tile), 1)
+    m_ref[:, kp:] = jnp.where(gid < n_real, s, _TOPK_NEG_INF)
+    mi_ref[:, kp:] = gid
+    cols = jax.lax.broadcasted_iota(jnp.int32, (b, width), 1)
+    kcols = jax.lax.broadcasted_iota(jnp.int32, (b, kp), 1)
 
     def extract(slot, _):
         m = m_ref[:]
         v = jnp.max(m, axis=1, keepdims=True)            # [B, 1]
         # Lowest column among the ties = exactly one winner per row; its
         # id is recovered with a sum-select (no gather needed).
-        amax = jnp.min(jnp.where(m == v, cols, k + tile),
+        amax = jnp.min(jnp.where(m == v, cols, width),
                        axis=1, keepdims=True)
         sel = cols == amax
         cid = jnp.sum(jnp.where(sel, mi_ref[:], 0), axis=1, keepdims=True)
-        out_s_ref[:, pl.ds(slot, 1)] = v
-        out_i_ref[:, pl.ds(slot, 1)] = cid
+        here = kcols == slot
+        out_s_ref[:] = jnp.where(here, v, out_s_ref[:])
+        out_i_ref[:] = jnp.where(here, cid, out_i_ref[:])
         m_ref[:] = jnp.where(sel, _TOPK_NEG_INF, m)
         return 0
 
     jax.lax.fori_loop(0, k, extract, 0, unroll=False)
+
+
+def _running_topk_call(kernel, grid: int, in_specs, bp: int, k: int,
+                       tile: int, interpret: bool):
+    """The shared pallas_call scaffolding of the two running-top-k
+    kernels: [bp, kp] f32/int32 outputs revisited by every grid step,
+    [bp, kp+tile] merged-candidate scratch."""
+    kp = _lane_pad(k)
+    return pl.pallas_call(
+        kernel,
+        grid=(grid,),
+        in_specs=in_specs,
+        out_specs=[
+            pl.BlockSpec((bp, kp), lambda j: (0, 0)),
+            pl.BlockSpec((bp, kp), lambda j: (0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bp, kp), jnp.float32),
+            jax.ShapeDtypeStruct((bp, kp), jnp.int32),
+        ],
+        scratch_shapes=[pltpu.VMEM((bp, kp + tile), jnp.float32),
+                        pltpu.VMEM((bp, kp + tile), jnp.int32)],
+        interpret=interpret,
+    )
+
+
+def _topk_kernel(q_ref, items_ref, out_s_ref, out_i_ref, m_ref, mi_ref,
+                 *, tile: int, k: int, n_real: int):
+    """One corpus tile scored on the MXU and folded into the running
+    top-k (:func:`_fold_tile_topk`)."""
+    # HIGHEST: Mosaic's default, like XLA:TPU's, rounds f32 operands to
+    # bfloat16 (1.6e-3 relative on a v5e) and the exact rungs promise
+    # float32 scores (ops.topk.SCORE_PRECISION).
+    s = jax.lax.dot_general(                     # MXU: [B,D]·[T,D]ᵀ
+        q_ref[:], items_ref[:],
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    _fold_tile_topk(s, pl.program_id(0), out_s_ref, out_i_ref, m_ref,
+                    mi_ref, tile=tile, k=k, n_real=n_real)
 
 
 @functools.partial(jax.jit,
@@ -464,26 +518,13 @@ def fused_topk_pallas(queries: jax.Array, items: jax.Array, k: int, *,
         queries = jnp.pad(queries, ((0, b_pad), (0, 0)))
     bp = b + b_pad
     kernel = functools.partial(_topk_kernel, tile=tile, k=k, n_real=n_real)
-    out_s, out_i = pl.pallas_call(
-        kernel,
-        grid=(-(-n // tile),),
-        in_specs=[
-            pl.BlockSpec((bp, d), lambda j: (0, 0)),
-            pl.BlockSpec((tile, d), lambda j: (j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bp, k), lambda j: (0, 0)),
-            pl.BlockSpec((bp, k), lambda j: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bp, k), jnp.float32),
-            jax.ShapeDtypeStruct((bp, k), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.VMEM((bp, k + tile), jnp.float32),
-                        pltpu.VMEM((bp, k + tile), jnp.int32)],
-        interpret=interpret,
+    out_s, out_i = _running_topk_call(
+        kernel, -(-n // tile),
+        [pl.BlockSpec((bp, d), lambda j: (0, 0)),
+         pl.BlockSpec((tile, d), lambda j: (j, 0))],
+        bp, k, tile, interpret,
     )(queries.astype(jnp.float32), items.astype(jnp.float32))
-    return out_s[:b], out_i[:b]
+    return out_s[:b, :k], out_i[:b, :k]
 
 
 def fused_topk(queries: jax.Array, items: jax.Array, k: int, *,
@@ -544,19 +585,9 @@ def _pq_scan_kernel(luts_ref, codes_ref, out_s_ref, out_i_ref, m_ref,
     ``luts_ref`` is the flattened [B, S·256] table stack (lane slices
     ``pl.ds(t·256, 256)`` address table t); ``codes_ref`` the [S, T]
     uint8 tile.  Tail tiles read OOB-padded garbage codes — their
-    columns are overwritten with NEG_INF via the global-id mask before
-    any can win a slot (same discipline as ``_topk_kernel``).
+    columns are masked in :func:`_fold_tile_topk`.
     """
-    j = pl.program_id(0)
     b = luts_ref.shape[0]
-
-    @pl.when(j == 0)
-    def _init():
-        out_s_ref[:] = jnp.full_like(out_s_ref, _TOPK_NEG_INF)
-        out_i_ref[:] = jnp.zeros_like(out_i_ref)
-
-    m_ref[:, :k] = out_s_ref[:]
-    mi_ref[:, :k] = out_i_ref[:]
     codes = codes_ref[:].astype(jnp.int32)               # [S, T]
     cc = jax.lax.broadcasted_iota(jnp.int32, (256, tile), 0)
     s = jnp.zeros((b, tile), jnp.float32)
@@ -567,24 +598,8 @@ def _pq_scan_kernel(luts_ref, codes_ref, out_s_ref, out_i_ref, m_ref,
             luts_ref[:, pl.ds(t * 256, 256)], oh,
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-    gid = j * tile + jax.lax.broadcasted_iota(jnp.int32, (b, tile), 1)
-    m_ref[:, k:] = jnp.where(gid < n_real, s, _TOPK_NEG_INF)
-    mi_ref[:, k:] = gid
-    cols = jax.lax.broadcasted_iota(jnp.int32, (b, k + tile), 1)
-
-    def extract(slot, _):
-        m = m_ref[:]
-        v = jnp.max(m, axis=1, keepdims=True)
-        amax = jnp.min(jnp.where(m == v, cols, k + tile),
-                       axis=1, keepdims=True)
-        sel = cols == amax
-        cid = jnp.sum(jnp.where(sel, mi_ref[:], 0), axis=1, keepdims=True)
-        out_s_ref[:, pl.ds(slot, 1)] = v
-        out_i_ref[:, pl.ds(slot, 1)] = cid
-        m_ref[:] = jnp.where(sel, _TOPK_NEG_INF, m)
-        return 0
-
-    jax.lax.fori_loop(0, k, extract, 0, unroll=False)
+    _fold_tile_topk(s, pl.program_id(0), out_s_ref, out_i_ref, m_ref,
+                    mi_ref, tile=tile, k=k, n_real=n_real)
 
 
 @functools.partial(jax.jit,
@@ -612,26 +627,13 @@ def pq_scan_pallas(luts: jax.Array, codes: jax.Array, k: int, *,
     bp = b + b_pad
     kernel = functools.partial(_pq_scan_kernel, tile=tile, k=k,
                                n_real=n_real, n_tables=s)
-    out_s, out_i = pl.pallas_call(
-        kernel,
-        grid=(-(-n // tile),),
-        in_specs=[
-            pl.BlockSpec((bp, s * 256), lambda j: (0, 0)),
-            pl.BlockSpec((s, tile), lambda j: (0, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bp, k), lambda j: (0, 0)),
-            pl.BlockSpec((bp, k), lambda j: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bp, k), jnp.float32),
-            jax.ShapeDtypeStruct((bp, k), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.VMEM((bp, k + tile), jnp.float32),
-                        pltpu.VMEM((bp, k + tile), jnp.int32)],
-        interpret=interpret,
+    out_s, out_i = _running_topk_call(
+        kernel, -(-n // tile),
+        [pl.BlockSpec((bp, s * 256), lambda j: (0, 0)),
+         pl.BlockSpec((s, tile), lambda j: (0, j))],
+        bp, k, tile, interpret,
     )(luts.astype(jnp.float32).reshape(bp, s * 256), codes)
-    return out_s[:b], out_i[:b]
+    return out_s[:b, :k], out_i[:b, :k]
 
 
 def pq_scan_xla(luts: jax.Array, codes: jax.Array, k: int, *,

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from predictionio_tpu.parallel.compat import shard_map
 
 __all__ = ["collective_microbench"]
 
@@ -51,20 +50,20 @@ def collective_microbench(
     results: Dict[str, Dict[str, float]] = {}
 
     @partial(
-        shard_map, mesh=mesh, in_specs=in_spec, out_specs=PartitionSpec()
+        jax.shard_map, mesh=mesh, in_specs=in_spec, out_specs=PartitionSpec()
     )
     def _psum(v):
         return jax.lax.psum(v, axis)
 
     @partial(
-        shard_map, mesh=mesh, in_specs=in_spec, out_specs=PartitionSpec(),
+        jax.shard_map, mesh=mesh, in_specs=in_spec, out_specs=PartitionSpec(),
         check_vma=False,  # all_gather output replication isn't statically inferable
     )
     def _all_gather(v):
         return jax.lax.all_gather(v, axis, tiled=True)
 
     @partial(
-        shard_map, mesh=mesh, in_specs=in_spec, out_specs=in_spec
+        jax.shard_map, mesh=mesh, in_specs=in_spec, out_specs=in_spec
     )
     def _all_to_all(v):
         return jax.lax.all_to_all(

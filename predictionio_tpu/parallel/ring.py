@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from predictionio_tpu.parallel.compat import shard_map
 from predictionio_tpu.parallel.mesh import AXIS_SEQUENCE
 
 __all__ = ["ring_attention", "ulysses_attention", "local_attention"]
@@ -115,7 +114,7 @@ def ring_attention(
         out = acc / jnp.maximum(l, 1e-30)[..., None]          # [B,H,S/n,D]
         return out.transpose(0, 2, 1, 3).astype(q_blk.dtype)  # [B,S/n,H,D]
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, axis), P(None, axis), P(None, axis)),
         out_specs=P(None, axis),
@@ -153,7 +152,7 @@ def ulysses_attention(
         out = local_attention(qf, kf, vf, causal=causal)
         return heads_to_seq(out)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, axis), P(None, axis), P(None, axis)),
         out_specs=P(None, axis),

@@ -249,12 +249,15 @@ def _device_search_impl(queries, centroids, lists, items, *, k: int,
     import jax
     import jax.numpy as jnp
 
+    from predictionio_tpu.ops.topk import SCORE_PRECISION
+
     cq = jnp.einsum("bd,cd->bc", queries, centroids,
                     preferred_element_type=jnp.float32)
     _, probe = jax.lax.top_k(cq, nprobe)               # [B, P]
     cand = lists[probe].reshape(queries.shape[0], -1)  # [B, P·L]
     vecs = items[jnp.maximum(cand, 0)]                 # [B, P·L, D]
     sc = jnp.einsum("bd,bnd->bn", queries, vecs,
+                    precision=SCORE_PRECISION,
                     preferred_element_type=jnp.float32)
     sc = jnp.where(cand < 0, jnp.float32(_NEG_INF), sc)
     top_s, pos = jax.lax.top_k(sc, k)

@@ -417,11 +417,14 @@ def _rerank_device(q, cand_s, cand_i, rvecs, scales, k: int):
     import jax
     import jax.numpy as jnp
 
+    from predictionio_tpu.ops.topk import SCORE_PRECISION
+
     safe = jnp.maximum(cand_i, 0)
     vecs = rvecs[safe].astype(jnp.float32)          # [B, R, D]
     if scales is not None:
         vecs = vecs * scales[safe][..., None]
     exact = jnp.einsum("bd,brd->br", q, vecs,
+                       precision=SCORE_PRECISION,
                        preferred_element_type=jnp.float32)
     exact = jnp.where(cand_s <= jnp.float32(_SENTINEL),
                       jnp.float32(_NEG_INF), exact)

@@ -156,7 +156,7 @@ class AdminServer:
             return 409, {"message": str(e)}
         except ProfilerUnavailable as e:
             # The clear degrade: this platform/process cannot capture
-            # (no jax, no profiler plugin, remote-tunnel backend) — a
+            # (no jax, no profiler plugin) — a
             # 501 the caller can act on, never a crash/500.
             return 501, {"message": f"profiler capture unavailable: {e}"}
         return 200, {"status": "profiling", **info}

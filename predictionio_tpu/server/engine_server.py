@@ -207,6 +207,7 @@ class EngineServer:
         # the memory-sampler thread (jax is loaded here — models are).
         start_runtime_introspection()
         self._swap_lock = threading.Lock()
+        self._stop_requested = threading.Event()  # POST /stop, see handle()
         self._httpd: Optional[ThreadingHTTPServer] = None
         self._thread: Optional[threading.Thread] = None
         self._instance = None
@@ -640,6 +641,13 @@ class EngineServer:
         try:
             fault_point("http.engine")
             if path == "/" and method == "GET":
+                # Lazy: the server package stays importable without jax
+                # (`pio eventserver` and the storage tools never need it).
+                from predictionio_tpu.backend import (
+                    compile_stats,
+                    describe_backend,
+                )
+
                 with self._swap_lock:
                     inst = self._instance
                     loaded = self._loaded_at
@@ -667,6 +675,11 @@ class EngineServer:
                     "batcher": self.scheduler.snapshot(),
                     "resultCache": self.result_cache.snapshot(),
                     "slo": self.slo.snapshot(),
+                    # The accelerator THIS process serves from, as jax
+                    # reports it (the instance's env carries the one it
+                    # was trained on).
+                    "backend": {**describe_backend().as_json(),
+                                **compile_stats()},
                     "version": __version__,
                 }
             if path == "/ready" and method == "GET":
@@ -913,7 +926,11 @@ class EngineServer:
                     logger.exception("query failed")
                     return 500, {"message": "Internal server error."}
             if path == "/stop" and method == "POST":
-                threading.Thread(target=self.stop, daemon=True).start()
+                # The transport stops the server AFTER this answer is on
+                # the wire (Handler.do_POST): stopping from here raced the
+                # response write, and `pio deploy` could exit with the
+                # client still waiting for its 200.
+                self._stop_requested.set()
                 return 200, {"status": "stopping"}
             return 404, {"message": "Not Found"}
         except DeadlineExceeded as e:
@@ -970,6 +987,10 @@ class EngineServer:
 
             def do_POST(self):  # noqa: N802
                 self.dispatch("POST")
+                if server_self._stop_requested.is_set():
+                    server_self._stop_requested.clear()
+                    threading.Thread(target=server_self.stop,
+                                     daemon=True).start()
 
         return Handler
 
@@ -986,10 +1007,13 @@ class EngineServer:
             self._thread.start()
 
     def stop(self) -> None:
-        if self._httpd:
-            self._httpd.shutdown()
-            self._httpd.server_close()
-            self._httpd = None
+        # Safe to call twice and from two threads (POST /stop's thread and
+        # the owner's own stop()): whoever takes the server shuts it down.
+        with self._swap_lock:
+            httpd, self._httpd = self._httpd, None
+        if httpd:
+            httpd.shutdown()
+            httpd.server_close()
         if self._evict_timer is not None:
             self._evict_timer.cancel()
             self._evict_timer = None

@@ -137,6 +137,11 @@ def run_train(
            REFRESH_MODE_KEY: "warm" if warm_from is not None else "full"}
     if warm_from is not None and getattr(warm_from, "instance", None):
         env[WARM_FROM_KEY] = warm_from.instance.id
+    # Which accelerator trained this generation (platform / deviceKind /
+    # deviceCount / pallas): a model trained on a CPU fallback says so.
+    from predictionio_tpu.backend import describe_backend
+
+    env.update(describe_backend().as_env())
     instance = EngineInstance(
         id=None,
         status="TRAINING",

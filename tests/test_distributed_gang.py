@@ -47,8 +47,7 @@ mesh = Mesh(devs, ("data",))
 local = jnp.asarray([float(rank + 1)])
 
 with mesh:
-    from jax.experimental.shard_map import shard_map
-    out = jax.jit(shard_map(
+    out = jax.jit(jax.shard_map(
         lambda x: jax.lax.psum(x, "data"),
         mesh=mesh, in_specs=P("data"), out_specs=P("data"),
     ))(multihost_utils.host_local_array_to_global_array(

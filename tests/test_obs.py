@@ -525,7 +525,7 @@ class TestDeviceMemorySampler:
 
     def test_device_enumeration_failure_is_quiet(self):
         def boom():
-            raise RuntimeError("tunnel down")
+            raise RuntimeError("backend down")
 
         sampler = DeviceMemorySampler(interval_s=0, devices_fn=boom,
                                       live_arrays_fn=lambda: [])

@@ -82,9 +82,7 @@ def test_psum_semantics_on_mesh():
     x = jnp.ones((8, 4))
     xs = jax.device_put(x, batch_sharding(m))
 
-    from predictionio_tpu.parallel.compat import shard_map
-
-    @partial(shard_map, mesh=m, in_specs=PartitionSpec(AXIS_DATA),
+    @partial(jax.shard_map, mesh=m, in_specs=PartitionSpec(AXIS_DATA),
              out_specs=PartitionSpec())
     def global_sum(v):
         return jax.lax.psum(v.sum(keepdims=True), AXIS_DATA)

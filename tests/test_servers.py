@@ -226,6 +226,28 @@ class TestEngineServer:
         assert status == 200
         assert body["status"] == "alive" and body["engineInstanceId"]
 
+    def test_status_page_names_the_backend(self, deployed):
+        srv, storage, *_ = deployed
+        _, body = _req("GET", f"http://127.0.0.1:{srv.port}/")
+        b = body["backend"]
+        assert b["platform"] == "cpu" and b["pallas"] == "interpret"
+        assert b["deviceCount"] >= 1 and "compileSeconds" in b
+        # ...and the generation says what it was trained on
+        inst = storage.get_engine_instances().get(body["engineInstanceId"])
+        assert inst.env["platform"] == "cpu"
+        assert inst.env["deviceCount"] == str(b["deviceCount"])
+
+    def test_stop_answers_before_it_stops(self, deployed):
+        """POST /stop used to start the shutdown from inside the handler,
+        racing its own response: `pio deploy` could exit with the client
+        still waiting for the 200."""
+        srv, *_ = deployed
+        thread = srv._thread
+        status, body = _req("POST", f"http://127.0.0.1:{srv.port}/stop", {})
+        assert status == 200 and body == {"status": "stopping"}
+        thread.join(timeout=10)
+        assert not thread.is_alive()
+
     def test_query(self, deployed):
         srv, *_ = deployed
         status, body = _req("POST", f"http://127.0.0.1:{srv.port}/queries.json",

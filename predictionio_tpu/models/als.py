@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from predictionio_tpu.obs import phase
 from predictionio_tpu.ops.linalg import gram, masked_gram
 from predictionio_tpu.ops.pallas_kernels import (
     fits_vmem,
@@ -609,7 +610,8 @@ def prepare_als_inputs(
     # rows; this brings the host/mesh layout into lock-step).
     d = mesh.shape[AXIS_DATA] if mesh is not None else 1
     pad_rows = math.lcm(LEN_ALIGN, d)
-    uf, itf = _init_factors(n_users, n_items, k, config.seed)
+    with phase("prep.init_factors"):
+        uf, itf = _init_factors(n_users, n_items, k, config.seed)
     sharded = mesh is not None and _shard_factors(config, n_users, n_items)
     window = config.gather_window
     if window == "auto":
@@ -636,22 +638,22 @@ def prepare_als_inputs(
         uf = put_sharded(uf, mesh, NamedSharding(mesh, spec))
         itf = put_sharded(itf, mesh, NamedSharding(mesh, spec))
 
-    user_buckets = _device_buckets(
-        bucket_by_length(user_ids, item_ids, ratings, n_users,
-                         bucket_bounds=config.bucket_bounds,
-                         max_len=config.max_degree, pad_rows_to=pad_rows,
-                         split_above=config.split_above),
-        mesh, k, config.max_block_floats, pad_rows,
-        window_n_src=n_items if window else None,
-    )
-    item_buckets = _device_buckets(
-        bucket_by_length(item_ids, user_ids, ratings, n_items,
-                         bucket_bounds=config.bucket_bounds,
-                         max_len=config.max_degree, pad_rows_to=pad_rows,
-                         split_above=config.split_above),
-        mesh, k, config.max_block_floats, pad_rows,
-        window_n_src=n_users if window else None,
-    )
+    def one_side(rows, cols, n_rows, n_src):
+        # One side at a time, so only one side's host buckets are alive:
+        # both phases are observed once per side here.
+        with phase("prep.plan"):
+            buckets = bucket_by_length(
+                rows, cols, ratings, n_rows,
+                bucket_bounds=config.bucket_bounds,
+                max_len=config.max_degree, pad_rows_to=pad_rows,
+                split_above=config.split_above)
+        with phase("prep.upload"):
+            return _device_buckets(
+                buckets, mesh, k, config.max_block_floats, pad_rows,
+                window_n_src=n_src if window else None)
+
+    user_buckets = one_side(user_ids, item_ids, n_users, n_items)
+    item_buckets = one_side(item_ids, user_ids, n_items, n_users)
     return ALSInputs(uf0=uf, itf0=itf, user_buckets=user_buckets,
                      item_buckets=item_buckets, n_users=n_users,
                      n_items=n_items)
@@ -856,17 +858,22 @@ def _prepare_als_inputs_device(
             return h, jnp.asarray(h)
         return None, jnp.asarray(ids, dtype=jnp.int32)
 
-    host_u, rows_u = one_input(user_ids,
-                               host_ids[0] if host_ids else None)
-    host_i, rows_i = one_input(item_ids,
-                               host_ids[1] if host_ids else None)
-    if ratings is None:
-        vals = jnp.ones(rows_u.shape[0], jnp.float32)
-    else:
-        vals = jnp.asarray(ratings, dtype=jnp.float32)
+    # The prep.* phases (pio_train_phase_ms) time the FOREGROUND thread
+    # only, and add no wait: uploads, dispatches and the build run are
+    # asynchronous, the compiles run on background threads.
+    with phase("prep.upload"):
+        host_u, rows_u = one_input(user_ids,
+                                   host_ids[0] if host_ids else None)
+        host_i, rows_i = one_input(item_ids,
+                                   host_ids[1] if host_ids else None)
+        if ratings is None:
+            vals = jnp.ones(rows_u.shape[0], jnp.float32)
+        else:
+            vals = jnp.asarray(ratings, dtype=jnp.float32)
 
-    plan_u = _plan_side(rows_u, n_users, config, host_rows=host_u)
-    plan_i = _plan_side(rows_i, n_items, config, host_rows=host_i)
+    with phase("prep.plan"):
+        plan_u = _plan_side(rows_u, n_users, config, host_rows=host_u)
+        plan_i = _plan_side(rows_i, n_items, config, host_rows=host_i)
 
     # The build program emits BUCKET-level arrays (chunk slicing happens
     # in-graph inside the training loop — see _expand_chunks); its compile
@@ -890,8 +897,10 @@ def _prepare_als_inputs_device(
     co = _build_cache_get((build_u, build_i, nnz))
     pend = None
     if co is None:
-        lowered = jax.jit(build_both, static_argnames=("pu", "pi")).lower(
-            rows_u, rows_i, vals, pu=build_u, pi=build_i)
+        with phase("prep.lower_build"):
+            lowered = jax.jit(
+                build_both, static_argnames=("pu", "pi")).lower(
+                    rows_u, rows_i, vals, pu=build_u, pi=build_i)
         # Daemon thread + Future (same pattern as _compile_train_loop): a
         # non-daemon executor worker would block interpreter exit if the
         # backend compile RPC ever hangs.
@@ -926,8 +935,9 @@ def _prepare_als_inputs_device(
         fut = concurrent.futures.Future()
         loop_statics = None
         try:
-            loop_statics, loop_lowered = _lower_train_loop_from_plans(
-                config, plan_u, plan_i, n_users, n_items)
+            with phase("prep.lower_loop"):
+                loop_statics, loop_lowered = _lower_train_loop_from_plans(
+                    config, plan_u, plan_i, n_users, n_items)
             threading.Thread(target=_compile_train_loop,
                              args=(loop_statics, loop_lowered, fut),
                              daemon=True).start()
@@ -942,13 +952,16 @@ def _prepare_als_inputs_device(
         _warm_cache_put(warm_key, cached)
     fut, warm_statics = cached
 
-    uf, itf = _init_factors(n_users, n_items, k, config.seed)
+    with phase("prep.init_factors"):
+        uf, itf = _init_factors(n_users, n_items, k, config.seed)
 
     if pend is not None:
-        co = pend.result()
+        with phase("prep.compile_wait"):
+            co = pend.result()
         _build_cache_put((build_u, build_i, nnz), co)
 
-    side_u, side_i = co(rows_u, rows_i, vals)
+    with phase("prep.build_run"):
+        side_u, side_i = co(rows_u, rows_i, vals)
 
     def one_side(built, plan):
         plain, split = built
@@ -1050,7 +1063,9 @@ def train_als_prepared(inputs: ALSInputs, config: ALSConfig, *,
     warm_exe = None
     if (inputs.loop_warm is not None and factor_shardings == (None, None)
             and inputs.loop_warm_statics == statics):
-        warm = inputs.loop_warm.result()  # blocks only while still compiling
+        with phase("train.loop_wait"):
+            # blocks only while the pre-warm is still compiling
+            warm = inputs.loop_warm.result()
         if warm is not None and warm[0] == statics:
             warm_exe = warm[1]
 
@@ -1088,8 +1103,9 @@ def train_als_prepared(inputs: ALSInputs, config: ALSConfig, *,
             while done < config.iterations:
                 n = min(save_every, config.iterations - done)
                 watchdog.arm(done + n)
-                uf2, itf2 = sweeps(uf, itf, n)
-                finite = all_finite((uf2, itf2))  # forces the dispatch
+                with phase("train.dispatch"):
+                    uf2, itf2 = sweeps(uf, itf, n)
+                    finite = all_finite((uf2, itf2))  # forces the dispatch
                 watchdog.disarm()
                 if not finite:
                     # Rollback IN PLACE: re-restore the latest durable
@@ -1126,10 +1142,13 @@ def train_als_prepared(inputs: ALSInputs, config: ALSConfig, *,
         watchdog = StepWatchdog("als")
         watchdog.arm(int(config.iterations))
         try:
-            uf, itf = sweeps(uf, itf, config.iterations)
+            with phase("train.dispatch"):
+                uf, itf = sweeps(uf, itf, config.iterations)
+                # forces the dispatch
+                finite = all_finite((uf, itf))
             # No checkpoint to roll back to: a non-finite result is a
             # terminal divergence (never silently returned/persisted).
-            if not all_finite((uf, itf)):
+            if not finite:
                 raise TrainDiverged("als", int(config.iterations),
                                     "non-finite factors", 0)
         finally:

@@ -102,32 +102,40 @@ __all__ = [
     "span",
     "trace",
     "phase",
+    "dispatch_stage",
     "reset_observability",
 ]
 
-import contextlib as _contextlib
+def phase(name: str, **attrs) -> span:
+    """Span + per-phase duration histogram + ``pio:<name>`` profiler
+    annotation: a thin use of :class:`span`'s two trace-independent
+    sinks.
 
-
-@_contextlib.contextmanager
-def phase(name: str, **attrs):
-    """Span + per-phase duration histogram in one context manager.
-
-    The workflow's named phases (datasource / prepare / train / persist)
-    show up both in the trace tree AND as ``pio_train_phase_ms{phase=...}``
-    series, so a dashboard can watch phase drift without trace plumbing.
+    The workflow's named phases (datasource / prepare / train / persist,
+    and the ``prep.*`` / ``train.*`` phases inside ALS prep and train)
+    show up in the trace tree, as ``pio_train_phase_ms{phase=...}``
+    series, and on a profiler capture's host timeline — crashed phases
+    too, the runs most worth seeing.
     (The metric name is a literal by design — tools/lint_metrics.py
     keeps every registered name statically checkable.)
     """
     hist = get_registry().histogram(
         "pio_train_phase_ms", "Workflow phase duration by phase name.",
         ("phase",))
-    with span(name, **attrs) as s:
-        try:
-            yield s
-        finally:
-            # record crashed phases too — the runs most worth seeing
-            s.finish()
-            hist.observe(s.duration_ms or 0.0, phase=name)
+    return span(name, hist=hist, labels={"phase": name}, annotate=True,
+                **attrs)
+
+
+def dispatch_stage(name: str, stage: str, **attrs) -> span:
+    """:func:`phase`'s serving twin: one host stage of a batched
+    dispatch (bind, supplement, lookup, h2d, launch, wait, assemble,
+    serve) as span ``name``, ``pio_dispatch_stage_ms{stage}`` and a
+    ``pio:<name>`` annotation.  Per dispatch, never per request."""
+    hist = get_registry().histogram(
+        "pio_dispatch_stage_ms",
+        "Host stages of one batched dispatch, by stage.", ("stage",))
+    return span(name, hist=hist, labels={"stage": stage}, annotate=True,
+                **attrs)
 
 
 def reset_observability() -> None:

@@ -11,9 +11,19 @@ Usage shape (the tentpole's API)::
   asyncio tasks never share state).  On exit the finished span tree is
   handed to the process :class:`TraceRecorder`.
 - :func:`span` opens a child of the innermost open span.  Outside any
-  trace it still times the block but records nothing — instrumented
+  trace it still times the block but joins no tree — instrumented
   library code (feeder, device_prep, serving internals) costs two
   ``perf_counter`` calls when tracing is not active.
+- A span has two more sinks, both independent of an open trace, for the
+  few spans that run per dispatch, per batcher turn or per prep call
+  (never per request): ``hist=``/``labels=`` observes its duration into
+  a histogram series on exit, and ``annotate=True`` holds a
+  ``jax.profiler.TraceAnnotation("pio:<name>")`` open for its lifetime.
+  ``TraceMe`` is inert while no profiler capture runs; under one (``pio
+  profile``, ``POST /admin/profile``, a harness's ``start_trace``) the
+  span sits on the device trace's clock, nested per thread.  jax is
+  taken lazily and only where the process already imported it, so this
+  module stays stdlib-only on import.
 - Trace ids are accepted/propagated over HTTP via ``X-Request-ID``
   (server/http.py); ids are sanitized here so a hostile header cannot
   smuggle newlines into the JSONL export or response headers.
@@ -36,6 +46,7 @@ import json
 import logging
 import os
 import re
+import sys
 import threading
 import time
 import uuid
@@ -96,6 +107,32 @@ def current_span() -> Optional[Span]:
 # span tree sits on ~ms-scale request hot paths and must cost µs.
 _EPOCH_WALL = time.time() - time.perf_counter()
 
+# THE spans' clock.  A module attribute so a test can put every span on
+# an injected clock (tiling and self-time invariants hold exactly there).
+_now = time.perf_counter
+
+# jax.profiler.TraceAnnotation once the process holds jax (None before).
+_TraceAnnotation = None
+
+
+def _open_annotation(name: str):
+    """An entered ``TraceAnnotation("pio:<name>")``, or None where the
+    process has no jax: without jax no profiler capture can be running,
+    so there is nothing to annotate and obs imports nothing heavy."""
+    global _TraceAnnotation
+    cls = _TraceAnnotation
+    if cls is None:
+        if "jax" not in sys.modules:
+            return None
+        try:
+            from jax.profiler import TraceAnnotation as cls
+        except ImportError:
+            return None
+        _TraceAnnotation = cls
+    ann = cls("pio:" + name)
+    ann.__enter__()
+    return ann
+
 
 class Span:
     """One timed node of a trace tree (name, attrs, children)."""
@@ -106,7 +143,7 @@ class Span:
         self.name = name
         self.attrs: Dict[str, Any] = attrs if attrs is not None else {}
         self.children: List[Span] = []
-        self._t0 = time.perf_counter()
+        self._t0 = _now()
         self.duration_ms: Optional[float] = None
 
     @property
@@ -115,7 +152,7 @@ class Span:
 
     def finish(self) -> None:
         if self.duration_ms is None:
-            self.duration_ms = (time.perf_counter() - self._t0) * 1e3
+            self.duration_ms = (_now() - self._t0) * 1e3
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -155,16 +192,27 @@ class span:
     A hand-rolled context manager (not ``contextlib``): the generator
     protocol costs several µs per use, and seven spans ride every served
     query.  Detached use (no open trace) still times the block — callers
-    may read ``.duration_ms`` — but records nothing.
+    may read ``.duration_ms`` — but joins no tree.
+
+    ``hist``/``labels`` and ``annotate`` are the two sinks that do not
+    need an open trace (module docstring); the exception path closes
+    both.
     """
 
-    __slots__ = ("_name", "_attrs", "_span", "_token")
+    __slots__ = ("_name", "_attrs", "_span", "_token", "_hist", "_labels",
+                 "_annotate", "_ann")
 
-    def __init__(self, name: str, **attrs):
+    def __init__(self, name: str, *, hist=None,
+                 labels: Optional[Dict[str, str]] = None,
+                 annotate: bool = False, **attrs):
         self._name = name
         self._attrs = attrs
+        self._hist = hist
+        self._labels = labels or {}
+        self._annotate = annotate
 
     def __enter__(self) -> Span:
+        self._ann = _open_annotation(self._name) if self._annotate else None
         parent = _current_span.get()
         s = self._span = Span(self._name, self._attrs)
         if parent is None:
@@ -175,9 +223,14 @@ class span:
         return s
 
     def __exit__(self, *exc) -> bool:
-        self._span.finish()
+        s = self._span
+        s.finish()
         if self._token is not None:
             _current_span.reset(self._token)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self._hist is not None:
+            self._hist.observe(s.duration_ms, **self._labels)
         return False
 
 
@@ -205,17 +258,21 @@ def attach_event(parent: Optional[Span], name: str, **attrs) -> Span:
 @contextlib.contextmanager
 def trace(name: str, trace_id: Optional[str] = None,
           slow_ms: Optional[float] = None, recorder: Optional["TraceRecorder"] = None,
-          **attrs):
+          *, hist=None, labels: Optional[Dict[str, str]] = None,
+          annotate: bool = False, **attrs):
     """Root span + trace id binding; records the finished tree on exit.
 
     Nested ``trace()`` calls degrade to plain child spans of the enclosing
     trace (one tree per request/run, never silently dropped timing).
+    ``hist``/``labels``/``annotate`` are :class:`span`'s.
     """
     if _current_span.get() is not None:
-        with span(name, **attrs) as s:
+        with span(name, hist=hist, labels=labels, annotate=annotate,
+                  **attrs) as s:
             yield s
         return
     tid = sanitize_trace_id(trace_id) or new_trace_id()
+    ann = _open_annotation(name) if annotate else None
     root = Span(name, attrs)
     tok_span = _current_span.set(root)
     tok_tid = _current_trace_id.set(tid)
@@ -225,6 +282,10 @@ def trace(name: str, trace_id: Optional[str] = None,
         root.finish()
         _current_span.reset(tok_span)
         _current_trace_id.reset(tok_tid)
+        if ann is not None:
+            ann.__exit__(*sys.exc_info())
+        if hist is not None:
+            hist.observe(root.duration_ms, **(labels or {}))
         (recorder or get_recorder()).record(tid, root, slow_ms=slow_ms)
 
 

@@ -540,16 +540,19 @@ class Retriever:
         q = np.ascontiguousarray(queries, dtype=np.float32)
         b = q.shape[0]
         p = self.plan(b, num, has_exclude=exclude is not None)
-        t0 = time.perf_counter()
-        with span("retrieval", corpus=self.name, rung=p.rung, batch=b,
-                  k=p.k) as sp:
+        # annotate: pio:retrieval on a profiler capture's host timeline,
+        # around the exact device rungs' pio:retrieval.h2d/.launch/.wait.
+        with span("retrieval", annotate=True, corpus=self.name,
+                  rung=p.rung, batch=b, k=p.k) as sp:
             scores, ids, scanned = self._execute(q, p, exclude)
             if p.nprobe:
                 sp.set(nprobe=p.nprobe)
             if p.rerank:
                 sp.set(rerank=p.rerank)
             sp.set(candidates=scanned)
-        ms = (time.perf_counter() - t0) * 1e3
+        # The span's own reading, so pio_retrieval_ms minus its children
+        # (pio_dispatch_stage_ms h2d/launch/wait) is the span's self time.
+        ms = sp.duration_ms
         self._m_requests.inc(rung=p.rung, corpus=self.name)
         self._m_candidates.inc(scanned, rung=p.rung, corpus=self.name)
         self._m_latency.observe(ms, rung=p.rung)

@@ -30,6 +30,7 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from predictionio_tpu.obs import dispatch_stage
 from predictionio_tpu.ops.pallas_kernels import fused_topk, pallas_supported
 from predictionio_tpu.ops.topk import (
     chunked_top_k,
@@ -57,6 +58,24 @@ def _cached(jit_cache: Dict, key, build):
                 fn = build()
                 jit_cache[key] = fn
     return fn
+
+
+def _staged_call(fn, stage_args) -> Tuple[np.ndarray, np.ndarray]:
+    """THE device round trip of the exact device rungs, in the three
+    host stages a dispatch spends around the kernel: ``h2d``
+    (``stage_args()``: the per-call uploads), ``launch`` (the jitted call
+    returning; dispatch is asynchronous) and ``wait`` (``device_get``
+    returning: kernel + D2H).  No synchronisation beyond the one
+    ``device_get`` the rungs always made."""
+    import jax
+
+    with dispatch_stage("retrieval.h2d", "h2d"):
+        args = stage_args()
+    with dispatch_stage("retrieval.launch", "launch"):
+        out = fn(*args)
+    with dispatch_stage("retrieval.wait", "wait"):
+        s, i = jax.device_get(out)
+    return np.asarray(s), np.asarray(i)
 
 
 def exact_host(queries: np.ndarray, host_vecs: np.ndarray, k: int, *,
@@ -95,7 +114,8 @@ def exact_device(queries: np.ndarray, items_dev, n_items: int, k: int, *,
             return jax.jit(_fn)
 
         fn = _cached(jit_cache, ("device", b, k, False, has_pad), build)
-        out = fn(jnp.asarray(queries, jnp.float32), items_dev, pad_row)
+        return _staged_call(fn, lambda: (
+            jnp.asarray(queries, jnp.float32), items_dev, pad_row))
     else:
         ne = exclude.shape[1]
 
@@ -110,10 +130,9 @@ def exact_device(queries: np.ndarray, items_dev, n_items: int, k: int, *,
         # exclude changes per request — it rides as a traced arg, so the
         # cache key only needs the static shapes.
         fn = _cached(jit_cache, ("device", b, k, True, has_pad, ne), build)
-        out = fn(jnp.asarray(queries, jnp.float32), items_dev,
-                 jnp.asarray(exclude), pad_row)
-    s, i = jax.device_get(out)
-    return np.asarray(s), np.asarray(i)
+        return _staged_call(fn, lambda: (
+            jnp.asarray(queries, jnp.float32), items_dev,
+            jnp.asarray(exclude), pad_row))
 
 
 def exact_chunked(queries: np.ndarray, items_dev, n_items: int, k: int, *,
@@ -152,10 +171,9 @@ def exact_chunked(queries: np.ndarray, items_dev, n_items: int, k: int, *,
         return jax.jit(_fn)
 
     fn = _cached(jit_cache, ("chunked", b, k, use_pallas, ne), build)
-    s, i = jax.device_get(fn(
+    return _staged_call(fn, lambda: (
         jnp.asarray(queries, jnp.float32), items_dev,
         jnp.asarray(exclude) if exclude is not None else None))
-    return np.asarray(s), np.asarray(i)
 
 
 def exact_sharded(queries: np.ndarray, items_sharded, n_items: int, k: int,
@@ -177,6 +195,5 @@ def exact_sharded(queries: np.ndarray, items_sharded, n_items: int, k: int,
         return jax.jit(_fn)
 
     fn = _cached(jit_cache, ("sharded", b, k), build)
-    s, i = jax.device_get(fn(jnp.asarray(queries, jnp.float32),
-                             items_sharded))
-    return np.asarray(s), np.asarray(i)
+    return _staged_call(fn, lambda: (
+        jnp.asarray(queries, jnp.float32), items_sharded))

@@ -25,14 +25,10 @@ from __future__ import annotations
 import json
 import logging
 import threading
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional
 
 from predictionio_tpu.data.storage import AccessKey, App, Storage, get_storage
-from predictionio_tpu.obs.profiler import (
-    ProfilerBusy,
-    ProfilerUnavailable,
-    get_profiler,
-)
+from predictionio_tpu.obs.profiler import handle_http as profiler_http
 from predictionio_tpu.server.http import (
     BaseHandler,
     ThreadingHTTPServer,
@@ -67,23 +63,13 @@ class AdminServer:
         try:
             if path == "/" and method == "GET":
                 return 200, {"status": "alive", "version": __version__}
-            if path == "/admin/profile":
-                return self._handle_profile(method, params)
-            if path == "/admin/profile/artifact" and method == "GET":
-                # Download the finished capture as a tar.gz (ISSUE 9
-                # satellite): remote/fleet operators no longer need box
-                # access to pick up the server-local artifact dir.
-                try:
-                    art = get_profiler().artifact()
-                except ProfilerBusy as e:
-                    return 409, {"message": str(e)}
-                if art is None:
-                    return 404, {"message": "no finished profiler capture "
-                                            "in this process"}
-                data, filename = art
-                return 200, data, "application/gzip", {
-                    "Content-Disposition":
-                        f'attachment; filename="{filename}"'}
+            if path.startswith("/admin/profile"):
+                # On-demand profiler capture + artifact download (ISSUE 3
+                # tentpole part 3, ISSUE 9 satellite); shared with the
+                # engine server.
+                out = profiler_http(method, path, params)
+                if out is not None:
+                    return out
             if path == "/timeline.json" and method == "GET":
                 return 200, timeline_payload(params)
             if path == "/v1/cmd/app" and method == "GET":
@@ -133,33 +119,6 @@ class AdminServer:
         except Exception:
             logger.exception("admin server error")
             return 500, {"message": "Internal server error."}
-
-    def _handle_profile(self, method: str,
-                        params: Dict[str, List[str]]) -> Tuple[int, dict]:
-        """On-demand profiler capture (ISSUE 3 tentpole part 3)."""
-        profiler = get_profiler()
-        if method == "GET":
-            return 200, profiler.status()
-        if method != "POST":
-            return 404, {"message": "Not Found"}
-        raw = params.get("duration_ms", ["2000"])[0]
-        try:
-            duration_ms = float(raw)
-            if not duration_ms > 0:
-                raise ValueError
-        except ValueError:
-            return 400, {"message": f"bad duration_ms: {raw!r}"}
-        out_dir = params.get("out", [None])[0]
-        try:
-            info = profiler.start(duration_ms, out_dir)
-        except ProfilerBusy as e:
-            return 409, {"message": str(e)}
-        except ProfilerUnavailable as e:
-            # The clear degrade: this platform/process cannot capture
-            # (no jax, no profiler plugin) — a
-            # 501 the caller can act on, never a crash/500.
-            return 501, {"message": f"profiler capture unavailable: {e}"}
-        return 200, {"status": "profiling", **info}
 
     def _make_handler(server_self):
         class Handler(BaseHandler):

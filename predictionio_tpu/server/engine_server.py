@@ -31,12 +31,14 @@ from predictionio_tpu.data.storage import (
     get_storage,
 )
 from predictionio_tpu.obs import (
+    dispatch_stage,
     get_registry,
     publish_event,
     span,
     start_runtime_introspection,
 )
 from predictionio_tpu.obs import waterfall as _waterfall
+from predictionio_tpu.obs.profiler import handle_http as profiler_http
 from predictionio_tpu.obs.quality import SERVE_ID_HEADER, QualityMonitor
 from predictionio_tpu.obs.recall import RecallMonitor
 from predictionio_tpu.obs.slo import SLOConfig, SLOEngine
@@ -616,21 +618,24 @@ class EngineServer:
             algorithms, models, serving, generation = (
                 self._algorithms, self._models, self._serving,
                 self._generation)
-        queries = [serving.supplement(q) for q in bound_queries]
-        indexed = list(enumerate(queries))
+        with dispatch_stage("dispatch.supplement", "supplement"):
+            queries = [serving.supplement(q) for q in bound_queries]
+            indexed = list(enumerate(queries))
         per_algo = [dict(a.batch_predict(m, indexed))
                     for a, m in zip(algorithms, models)]
-        return [
-            self._result_to_json(
-                serving.serve(q, [pa[i] for pa in per_algo]))
-            for i, q in indexed
-        ], generation
+        with dispatch_stage("dispatch.serve", "serve"):
+            return [
+                self._result_to_json(
+                    serving.serve(q, [pa[i] for pa in per_algo]))
+                for i, q in indexed
+            ], generation
 
     def query_batch(self, query_jsons: List[Any]) -> List[Any]:
         """Batched predict (native frontend, ``pio batchpredict``): the
         scheduler's dispatch path without the generation tag."""
-        return self._dispatch_batch(
-            [self._bind_query(qj) for qj in query_jsons])[0]
+        with dispatch_stage("query_batch.bind", "bind"):
+            bound = [self._bind_query(qj) for qj in query_jsons]
+        return self._dispatch_batch(bound)[0]
 
     # -- HTTP ---------------------------------------------------------------
 
@@ -768,6 +773,13 @@ class EngineServer:
                 return 200, {"status": "reloaded",
                              "engineInstanceId": instance_id,
                              "generation": self._generation}
+            if path.startswith("/admin/profile"):
+                # The operator's capture of THIS process, the one that
+                # holds the chip (`pio profile --url <engine> --out F`):
+                # device ops beside the pio: host spans.
+                out = profiler_http(method, path, params)
+                if out is not None:
+                    return out
             if path == "/admin/rollback" and method == "POST":
                 try:
                     instance_id = self.rollback()

@@ -40,7 +40,7 @@ import uuid
 from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 from predictionio_tpu.obs import get_registry
-from predictionio_tpu.obs.trace import attach_event, trace as _trace
+from predictionio_tpu.obs.trace import attach_event, span, trace as _trace
 from predictionio_tpu.obs.waterfall import Waterfall, dispatch_sink
 from predictionio_tpu.resilience.deadline import DeadlineExceeded
 from predictionio_tpu.serving.queue import (
@@ -59,11 +59,14 @@ __all__ = ["MicroBatcher", "BATCH_SIZE_BUCKETS"]
 # ceiling — the distribution, not just the mean, shows coalescing health.
 BATCH_SIZE_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256)
 
-# Coalescing-ratio buckets (dispatches per request = 1/batch_size):
-# 1.0 = no coalescing, 1/64 = perfect 64-way sharing.
-COALESCE_BUCKETS = (0.015625, 0.03125, 0.0625, 0.125, 0.25, 0.5, 1.0)
+# The batcher thread's ledger: every instant of the thread's wall
+# belongs to exactly one of these (``pio_batcher_thread_ms{phase}``).
+THREAD_PHASES = ("wait_empty", "wait_window", "shed", "dispatch", "finish")
 
 _FAR_FUTURE = float("inf")
+
+# Longest single wait_empty observation of an idle batcher thread.
+IDLE_SLICE_S = 0.05
 
 
 class MicroBatcher:
@@ -110,17 +113,20 @@ class MicroBatcher:
         self._m_batch_size = reg.histogram(
             "pio_batch_size", "Queries coalesced per dispatch.",
             ("model",), buckets=BATCH_SIZE_BUCKETS)
-        self._m_coalesce = reg.histogram(
-            "pio_batch_dispatches_per_request",
-            "1/batch_size observed per member request — mean < 1 means "
-            "the scheduler is coalescing.",
-            ("model",), buckets=COALESCE_BUCKETS)
         self._m_dispatch_ms = reg.histogram(
             "pio_batch_dispatch_ms", "Wall time of one batched dispatch.",
             ("model",))
         self._m_wait_ms = reg.histogram(
             "pio_queue_wait_ms",
             "Queue wait from admission to dispatch start.", ("model",))
+        self._m_thread = reg.histogram(
+            "pio_batcher_thread_ms",
+            "The batcher thread's wall, by what it was doing: blocked on "
+            "an empty queue, waiting out the batch window, claiming and "
+            "shedding, inside dispatch_fn, handing results back.",
+            ("model", "phase"))
+        self._phase_labels = {p: {"model": model, "phase": p}
+                              for p in THREAD_PHASES}
         self._m_dispatches = reg.counter(
             "pio_batch_dispatch_total", "Batched dispatches.", ("model",))
         self._m_requests = reg.counter(
@@ -152,6 +158,15 @@ class MicroBatcher:
 
     # -- gather -------------------------------------------------------------
 
+    def _phase(self, phase: str) -> span:
+        """One entry of the thread's ledger: span ``batcher.<phase>``,
+        ``pio_batcher_thread_ms{phase}`` and a ``pio:`` annotation (the
+        span's trace-independent sinks; per batcher turn, never per
+        request).  The five phases tile the thread's wall: whatever the
+        loop does belongs inside one of them."""
+        return span("batcher." + phase, hist=self._m_thread,
+                    labels=self._phase_labels[phase], annotate=True)
+
     def _latest_dispatch_s(self, entry: Pending) -> float:
         """Latest clock time this entry could still be dispatched and
         (per the EWMA estimate) answered inside its deadline."""
@@ -164,10 +179,19 @@ class MicroBatcher:
         window closes, the most-constrained member's slack runs out, or
         ``max_size`` is reached.  Returns [] only when the queue closed.
         """
-        if first is None:
-            first = self.queue.take(self.clock, timeout=None)
-            if first is None:
+        while first is None:
+            # In slices, so an idle thread's time reaches the ledger as
+            # it passes (a rate over wait_empty stays smooth, a window's
+            # delta is off by one slice at most), not in one observation
+            # when the next request ends an hour of quiet.
+            with self._phase("wait_empty"):
+                first = self.queue.take(self.clock, timeout=IDLE_SLICE_S)
+            if first is None and self.queue.closed():
                 return []
+        with self._phase("wait_window"):
+            return self._fill(first)
+
+    def _fill(self, first: Pending) -> List[Pending]:
         batch = [first]
         opened = self.clock.now()
         # Waterfall: gather pickup splits the member's admission→dispatch
@@ -220,15 +244,79 @@ class MicroBatcher:
         """Claim, shed expired, run ONE vectorized dispatch, finish all.
 
         Returns the number of entries actually dispatched (after sheds
-        and abandons) — 0 means the whole batch evaporated.
+        and abandons) — 0 means the whole batch evaporated.  Three
+        ledger phases: ``shed`` (up to the dispatch), ``dispatch``
+        (around ``dispatch_fn``; also the root trace), ``finish``
+        (everything after it, the failure path's retries included).
         """
+        with self._phase("shed"):
+            live = self._claim_live(batch)
+            if not live:
+                return 0
+            # One draw names the dispatch's trace and its batch: drawn
+            # here, inside a phase, because os.urandom releases the GIL
+            # and a busy server's handler threads then take their turn
+            # (measured on the chip at 200/s: about a millisecond).
+            trace_id = uuid.uuid4().hex
+            batch_id = trace_id[:12]
+            # Per-dispatch stage sink: library code under the dispatch
+            # (the retrieval facade) records stages here; the result is
+            # fanned out to every member's waterfall below — one corpus
+            # scan, one shared "retrieval" reading per cohort.
+            sink = Waterfall()
+            # The cohort shares ONE retrieval scan, so it shares one
+            # recall sampling decision: carry the first member's
+            # per-request draw (ISSUE 11 shared-u contract) onto the
+            # dispatch sink, where the retrieval facade's recall capture
+            # reads it.
+            for e in live:
+                wf = e.waterfall
+                if wf is not None and wf.sample_u is not None:
+                    sink.sample_u = wf.sample_u
+                    break
+            t0 = self.clock.now()
+            # queue_wait/batch_wait are fully determined at dispatch
+            # start — stamp them NOW, on every outcome path (success,
+            # failure, retry), so no finish path leaks its wait into the
+            # resume residual.
+            for e in live:
+                self._stamp_waits(e, t0)
+        try:
+            # The dispatch is its own root trace (the batcher thread has
+            # no request context): the ring shows every coalesced device
+            # dispatch, and member requests join it by batch_id via the
+            # zero-duration event attached to their spans below.
+            with _trace("batcher.dispatch", trace_id=trace_id,
+                        hist=self._m_thread,
+                        labels=self._phase_labels["dispatch"],
+                        annotate=True, model=self.model,
+                        batch_id=batch_id, batch_size=len(live)) as troot:
+                with dispatch_sink(sink):
+                    results, generation = self.dispatch_fn(
+                        [e.query for e in live])
+                if len(results) != len(live):
+                    raise ValueError(
+                        f"dispatch returned {len(results)} results for "
+                        f"{len(live)} queries")
+                troot.set(generation=generation)
+        except Exception as exc:
+            with self._phase("finish"):
+                self._finish_failed(live, batch_id, t0, exc)
+            return len(live)
+        with self._phase("finish"):
+            self._finish_served(live, results, generation, batch_id, t0,
+                                sink)
+        return len(live)
+
+    def _claim_live(self, batch: Sequence[Pending]) -> List[Pending]:
+        """Claim each entry; shed the ones whose deadline expired in the
+        queue (504 upstream, no device work)."""
         now = self.clock.now()
         live: List[Pending] = []
         for e in batch:
             if not e.claim():
                 continue  # waiter already walked (deadline) — silent drop
             if e.deadline_s is not None and now >= e.deadline_s:
-                # Expired in the queue: 504 upstream, no device work.
                 # Stamp the waits first so the 504's wide event bills
                 # this wall to queue_wait/batch_wait — NOT to the
                 # waiter's resume residual, which would misread pure
@@ -240,61 +328,31 @@ class MicroBatcher:
                     f"({(now - e.deadline_s) * 1e3:.0f}ms over budget)"))
                 continue
             live.append(e)
-        if not live:
-            return 0
-        batch_id = uuid.uuid4().hex[:12]
-        # Per-dispatch stage sink: library code under the dispatch (the
-        # retrieval facade) records stages here; the result is fanned out
-        # to every member's waterfall below — one corpus scan, one shared
-        # "retrieval" reading per cohort.
-        sink = Waterfall()
-        # The cohort shares ONE retrieval scan, so it shares one recall
-        # sampling decision: carry the first member's per-request draw
-        # (ISSUE 11 shared-u contract) onto the dispatch sink, where the
-        # retrieval facade's recall capture reads it.
+        return live
+
+    def _finish_failed(self, live: List[Pending], batch_id: str,
+                       t0: float, exc: Exception) -> None:
+        # The failed attempt's device time is real wall the members
+        # waited through — bill it (stamps accumulate by design: a
+        # retried dispatch bills both attempts).
+        dt_fail = (self.clock.now() - t0) * 1e3
         for e in live:
-            wf = e.waterfall
-            if wf is not None and wf.sample_u is not None:
-                sink.sample_u = wf.sample_u
-                break
-        t0 = self.clock.now()
-        # queue_wait/batch_wait are fully determined at dispatch start —
-        # stamp them NOW, on every outcome path (success, failure, retry),
-        # so no finish path leaks its wait into the resume residual.
-        for e in live:
-            self._stamp_waits(e, t0)
-        try:
-            # The dispatch is its own root trace (the batcher thread has
-            # no request context): the ring shows every coalesced device
-            # dispatch, and member requests join it by batch_id via the
-            # zero-duration event attached to their spans below.
-            with _trace("batcher.dispatch", model=self.model,
-                        batch_id=batch_id, batch_size=len(live)) as troot:
-                with dispatch_sink(sink):
-                    results, generation = self.dispatch_fn(
-                        [e.query for e in live])
-                if len(results) != len(live):
-                    raise ValueError(
-                        f"dispatch returned {len(results)} results for "
-                        f"{len(live)} queries")
-                troot.set(generation=generation)
-        except Exception as exc:
-            # The failed attempt's device time is real wall the members
-            # waited through — bill it (stamps accumulate by design: a
-            # retried dispatch bills both attempts).
-            dt_fail = (self.clock.now() - t0) * 1e3
-            for e in live:
-                if e.waterfall is not None:
-                    e.waterfall.stamp("dispatch", dt_fail,
-                                      batchSize=len(live), failed=True,
-                                      model=self.model)
-            if len(live) == 1:
-                # Retrying a singleton would replay the IDENTICAL call —
-                # pure double work for the same error.
-                live[0].finish(error=exc)
-            else:
-                self._finish_individually(live, batch_id)
-            return len(live)
+            if e.waterfall is not None:
+                e.waterfall.stamp("dispatch", dt_fail,
+                                  batchSize=len(live), failed=True,
+                                  model=self.model)
+        if len(live) == 1:
+            # Retrying a singleton would replay the IDENTICAL call —
+            # pure double work for the same error.
+            live[0].finish(error=exc)
+        else:
+            self._finish_individually(live, batch_id)
+
+    def _finish_served(self, live: List[Pending], results: List[Any],
+                       generation: int, batch_id: str, t0: float,
+                       sink: Waterfall) -> None:
+        """After ``dispatch_fn`` returned: metrics, waterfall merge, the
+        join event, the autotuner, and each member's wake-up."""
         dt = self.clock.now() - t0
         # EWMA (alpha .25): reactive enough to track a model swap,
         # smooth enough that one slow dispatch doesn't shed the queue.
@@ -309,7 +367,6 @@ class MicroBatcher:
         for e, r in zip(live, results):
             wait_ms = (t0 - e.enqueued_s) * 1e3
             self._m_wait_ms.observe(wait_ms, model=self.model)
-            self._m_coalesce.observe(1.0 / n, model=self.model)
             if e.waterfall is not None:
                 # queue_wait/batch_wait already stamped at dispatch start.
                 e.waterfall.stamp("dispatch", dt * 1e3,
@@ -332,7 +389,6 @@ class MicroBatcher:
             e.finish(result=r)
         if self.autotuner is not None:
             self.autotuner.after_dispatch(self)
-        return n
 
     def _finish_individually(self, live: List[Pending],
                              batch_id: str) -> None:
@@ -374,7 +430,6 @@ class MicroBatcher:
                 self._m_dispatches.inc(model=self.model)
                 self._m_requests.inc(model=self.model)
                 self._m_batch_size.observe(1, model=self.model)
-                self._m_coalesce.observe(1.0, model=self.model)
                 e.finish(result=results[0])
             except Exception as exc:  # noqa: BLE001 - per-item verdict
                 if e.waterfall is not None:

@@ -40,6 +40,7 @@ from predictionio_tpu.controller import (
 from predictionio_tpu.controller.params import Params
 from predictionio_tpu.data.event import BiMap
 from predictionio_tpu.models import als as als_lib
+from predictionio_tpu.obs import dispatch_stage
 from predictionio_tpu.obs.quality import Scorecard, scorecard_from_matrix
 from predictionio_tpu.obs.recall import (
     RecallScorecard,
@@ -991,22 +992,25 @@ class ALSAlgorithm(Algorithm):
         second dispatch.  Users with no usable events still answer the
         cold-start empty result.
         """
-        known = [(i, q) for i, q in queries if q.user in model.user_index]
-        rows: List[np.ndarray] = []
-        cold: List[Tuple[int, "Query"]] = []
-        folded: List[Tuple[int, "Query"]] = []
-        for i, q in queries:
-            if q.user in model.user_index:
-                continue
-            vec = model.fold_in_user(q.user)
-            if vec is None:
-                cold.append((i, q))
-            else:
-                folded.append((i, q))
-                rows.append(vec)
-        out = [(i, PredictedResult(itemScores=[])) for i, q in cold]
-        answerable = known + folded
-        if answerable:
+        with dispatch_stage("predict.lookup", "lookup"):
+            known = [(i, q) for i, q in queries
+                     if q.user in model.user_index]
+            rows: List[np.ndarray] = []
+            cold: List[Tuple[int, "Query"]] = []
+            folded: List[Tuple[int, "Query"]] = []
+            for i, q in queries:
+                if q.user in model.user_index:
+                    continue
+                vec = model.fold_in_user(q.user)
+                if vec is None:
+                    cold.append((i, q))
+                else:
+                    folded.append((i, q))
+                    rows.append(vec)
+            out = [(i, PredictedResult(itemScores=[])) for i, q in cold]
+            answerable = known + folded
+            if not answerable:
+                return out
             num = max(q.num for _, q in answerable)
             uf = model.host_user_factors()
             qmat_parts = []
@@ -1018,7 +1022,8 @@ class ALSAlgorithm(Algorithm):
                 qmat_parts.append(np.stack(rows))
             qmat = np.concatenate(qmat_parts, axis=0) \
                 if len(qmat_parts) > 1 else qmat_parts[0]
-            scores, ids, _info = model.retriever().topk(qmat, num)
+        scores, ids, _info = model.retriever().topk(qmat, num)
+        with dispatch_stage("predict.assemble", "assemble"):
             inv = model.item_index.inverse
             for row, (i, q) in enumerate(answerable):
                 out.append((i, PredictedResult(itemScores=[

@@ -618,7 +618,6 @@ class TestEngineServerIntegration:
                 text = resp.read().decode()
             for family in ("pio_batch_size_bucket", "pio_queue_wait_ms",
                            "pio_batch_dispatch_total",
-                           "pio_batch_dispatches_per_request",
                            "pio_batch_window_ms", "pio_queue_depth"):
                 assert family in text, f"{family} missing from /metrics"
             with urllib.request.urlopen(f"{base}/stats.json",

@@ -648,8 +648,8 @@ def run_kernels(args) -> int:
     for b in (1, 64):
         q_d = jnp.asarray(queries_h[:b])
         for k in K_MENU:
-            s, i = pk.fused_topk_pallas(q_d, corpus_d, k, n_valid=n,
-                                        interpret=interpret)
+            s, i, _ = pk.fused_topk_pallas(q_d, corpus_d, k, n_valid=n,
+                                           interpret=interpret)
             worst = max(worst, topk_agrees(f"fused_topk b{b} k{k}", s, i,
                                            queries_h[:b], k))
             ts, ti = twin(chunked_top_k)(q_d, corpus_d, k, chunk=262_144)

@@ -32,7 +32,7 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["fused_gram_vector", "fused_gram_vector_pallas",
            "fused_gram_vector_xla", "pallas_supported",
            "ridge_solve_gj_pallas", "ridge_solve_lu_pallas", "gj_fits_vmem",
-           "fused_topk", "fused_topk_pallas",
+           "fused_topk", "fused_topk_pallas", "fused_topk_tiles",
            "pq_scan", "pq_scan_pallas", "pq_scan_xla"]
 
 
@@ -386,11 +386,18 @@ def fused_gram_vector(f: jax.Array, w: jax.Array, c: jax.Array,
 # (chunked_top_k).  This kernel streams corpus tiles into VMEM, scores a
 # tile on the MXU, and folds it into a running top-K held in VMEM — the
 # [B, N] scores never exist anywhere, and HBM traffic is one read of the
-# corpus plus O(B·k) output.  The merge is a k-step extract-max built
-# ONLY from Mosaic-supported primitives (axis reductions, where,
-# broadcasted_iota, pl.ds stores) — no in-kernel sort/top_k dependence —
-# so the selection costs k·(k+T)·B VPU ops per tile: the kernel targets
-# large-N / menu-k serving shapes where the MXU tile score dominates.
+# corpus plus O(B·k) output.  The fold is built ONLY from Mosaic-supported
+# primitives (axis reductions, where, broadcasted_iota, a lane roll,
+# whole-block stores) — no in-kernel sort/top_k dependence — and costs
+# what the tile's scores can change: every tile pays one compare of its
+# [B, T] scores against each row's running k-th best and a count of the
+# winners; only a tile that holds one pays selection rounds, one per
+# candidate of its worst row (≤ k), each a few passes over [B, T] and
+# [B, kp].  Over a corpus in no score order a row's top-k changes in about
+# k·ln(N/T) tiles, so the rounds summed over a scan are a small fraction
+# of the tiles (pio_topk_fold_rounds_per_tile) and the kernel's time is
+# the tile's DMA (B ≤ 64 or so) or its HIGHEST matmul (B = 256); a corpus
+# stored in ascending score order is the worst input, k rounds a tile.
 # ---------------------------------------------------------------------------
 
 _TOPK_TILE = 1024        # corpus rows per grid step (lane-aligned)
@@ -402,62 +409,89 @@ def _lane_pad(k: int) -> int:
     return -(-k // _LANES) * _LANES
 
 
-def _fold_tile_topk(s, j, out_s_ref, out_i_ref, m_ref, mi_ref, *,
+def fused_topk_tiles(n: int, tile: int = _TOPK_TILE) -> int:
+    """Corpus tiles one ``fused_topk_pallas`` call scans over ``n`` rows:
+    its grid, and what its round count is read against."""
+    return -(-n // tile)
+
+
+def _fold_tile_topk(s, j, out_s_ref, out_i_ref, rounds_ref, m_ref, *,
                     tile: int, k: int, n_real: int):
-    """Fold one tile's scores ``s [B, T]`` into the running top-k.
+    """Fold one tile's scores ``s [B, T]`` into the running top-k, as far
+    as the scores can change it.
 
     ``out_*_ref`` are [B, kp] blocks (kp = k rounded up to a lane
     multiple) that persist across the sequential TPU grid: lanes < k hold
-    the running best, lanes ≥ k stay NEG_INF forever.  ``m_ref``/
-    ``mi_ref`` are [B, kp+T] merged-candidate scratch — running best in
-    the first kp lanes, this tile's scores behind them, so both stores
-    land on lane-aligned offsets (Mosaic refuses an unaligned or traced
-    lane index on a store).  Tail tiles read an OOB-padded block — the
-    garbage columns are overwritten with NEG_INF via the global-id mask
-    before any of them can win a slot (`where` selects, never propagates
-    a NaN).  The winner of each extract step is written with an
-    iota-select over the whole [B, kp] block for the same reason.
+    the running best SORTED descending (equal scores in ascending id),
+    lanes ≥ k stay NEG_INF forever.  A row's threshold is therefore lane
+    k-1: NEG_INF until the row has seen k real items, its k-th best
+    after.  Only a real score (global id < ``n_real``; tail tiles read an
+    OOB-padded block whose garbage columns fail that test) STRICTLY over
+    the threshold is a candidate: a tile with none ends at the test, with
+    no store and no round.  Otherwise the candidates alone are staged in
+    ``m_ref [B, T]`` and the tile runs as many insertion rounds as its
+    worst row has candidates, at most k.  A round takes each row's best
+    remaining candidate (lowest column among equals) and, where it still
+    beats the row's threshold, inserts it behind every running score ≥
+    it: the lanes behind shift by one (``pltpu.roll``) and the old k-th
+    falls off.  A row with no candidate left is rewritten as it was.
+    Strictly-over is what keeps an all-equal row (the zero pad rows of a
+    cohort) from entering every tile, and what keeps the earlier id when
+    scores tie.  Every store is a whole lane-aligned block picked by an
+    iota-select (Mosaic refuses an unaligned or traced lane index on a
+    store).  ``rounds_ref`` (SMEM [1, 1]) counts the rounds run.
     """
     b = s.shape[0]
     kp = out_s_ref.shape[1]
-    width = kp + tile
 
     @pl.when(j == 0)
     def _init():
         out_s_ref[:] = jnp.full_like(out_s_ref, _TOPK_NEG_INF)
         out_i_ref[:] = jnp.zeros_like(out_i_ref)
+        rounds_ref[0, 0] = 0
 
-    m_ref[:, :kp] = out_s_ref[:]
-    mi_ref[:, :kp] = out_i_ref[:]
-    gid = j * tile + jax.lax.broadcasted_iota(jnp.int32, (b, tile), 1)
-    m_ref[:, kp:] = jnp.where(gid < n_real, s, _TOPK_NEG_INF)
-    mi_ref[:, kp:] = gid
-    cols = jax.lax.broadcasted_iota(jnp.int32, (b, width), 1)
-    kcols = jax.lax.broadcasted_iota(jnp.int32, (b, kp), 1)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (b, tile), 1)
+    cand = (s > out_s_ref[:, k - 1:k]) & (cols < n_real - j * tile)
+    worst = jnp.max(jnp.sum(cand.astype(jnp.int32), axis=1, keepdims=True))
+    trip = jnp.minimum(worst, k)
+    rounds_ref[0, 0] += trip
 
-    def extract(slot, _):
-        m = m_ref[:]
-        v = jnp.max(m, axis=1, keepdims=True)            # [B, 1]
-        # Lowest column among the ties = exactly one winner per row; its
-        # id is recovered with a sum-select (no gather needed).
-        amax = jnp.min(jnp.where(m == v, cols, width),
-                       axis=1, keepdims=True)
-        sel = cols == amax
-        cid = jnp.sum(jnp.where(sel, mi_ref[:], 0), axis=1, keepdims=True)
-        here = kcols == slot
-        out_s_ref[:] = jnp.where(here, v, out_s_ref[:])
-        out_i_ref[:] = jnp.where(here, cid, out_i_ref[:])
-        m_ref[:] = jnp.where(sel, _TOPK_NEG_INF, m)
-        return 0
+    @pl.when(trip > 0)
+    def _enter():
+        m_ref[:] = jnp.where(cand, s, _TOPK_NEG_INF)
+        lanes = jax.lax.broadcasted_iota(jnp.int32, (b, kp), 1)
 
-    jax.lax.fori_loop(0, k, extract, 0, unroll=False)
+        def insert(_, carry):
+            m = m_ref[:]
+            v = jnp.max(m, axis=1, keepdims=True)            # [B, 1]
+            col = jnp.min(jnp.where(m == v, cols, tile),
+                          axis=1, keepdims=True)
+            m_ref[:] = jnp.where(cols == col, _TOPK_NEG_INF, m)
+            run_s, run_i = out_s_ref[:], out_i_ref[:]
+            # Slot = how many running scores stay ahead; kp (past every
+            # lane: nothing moves) for a row whose best no longer enters.
+            slot = jnp.where(
+                v > out_s_ref[:, k - 1:k],
+                jnp.sum((run_s >= v).astype(jnp.int32), axis=1,
+                        keepdims=True), kp)
+            keep = lanes < slot
+            if k < kp:
+                keep |= lanes >= k
+            here = lanes == slot
+            out_s_ref[:] = jnp.where(keep, run_s, jnp.where(
+                here, v, pltpu.roll(run_s, 1, 1)))
+            out_i_ref[:] = jnp.where(keep, run_i, jnp.where(
+                here, j * tile + col, pltpu.roll(run_i, 1, 1)))
+            return carry
+
+        jax.lax.fori_loop(0, trip, insert, 0)
 
 
 def _running_topk_call(kernel, grid: int, in_specs, bp: int, k: int,
                        tile: int, interpret: bool):
     """The shared pallas_call scaffolding of the two running-top-k
-    kernels: [bp, kp] f32/int32 outputs revisited by every grid step,
-    [bp, kp+tile] merged-candidate scratch."""
+    kernels: [bp, kp] f32/int32 outputs revisited by every grid step, the
+    [1, 1] round counter in SMEM, [bp, tile] candidate scratch."""
     kp = _lane_pad(k)
     return pl.pallas_call(
         kernel,
@@ -466,18 +500,19 @@ def _running_topk_call(kernel, grid: int, in_specs, bp: int, k: int,
         out_specs=[
             pl.BlockSpec((bp, kp), lambda j: (0, 0)),
             pl.BlockSpec((bp, kp), lambda j: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bp, kp), jnp.float32),
             jax.ShapeDtypeStruct((bp, kp), jnp.int32),
+            jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((bp, kp + tile), jnp.float32),
-                        pltpu.VMEM((bp, kp + tile), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((bp, tile), jnp.float32)],
         interpret=interpret,
     )
 
 
-def _topk_kernel(q_ref, items_ref, out_s_ref, out_i_ref, m_ref, mi_ref,
+def _topk_kernel(q_ref, items_ref, out_s_ref, out_i_ref, rounds_ref, m_ref,
                  *, tile: int, k: int, n_real: int):
     """One corpus tile scored on the MXU and folded into the running
     top-k (:func:`_fold_tile_topk`)."""
@@ -489,8 +524,8 @@ def _topk_kernel(q_ref, items_ref, out_s_ref, out_i_ref, m_ref, mi_ref,
         dimension_numbers=(((1,), (1,)), ((), ())),
         precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
-    _fold_tile_topk(s, pl.program_id(0), out_s_ref, out_i_ref, m_ref,
-                    mi_ref, tile=tile, k=k, n_real=n_real)
+    _fold_tile_topk(s, pl.program_id(0), out_s_ref, out_i_ref, rounds_ref,
+                    m_ref, tile=tile, k=k, n_real=n_real)
 
 
 @functools.partial(jax.jit,
@@ -499,15 +534,18 @@ def fused_topk_pallas(queries: jax.Array, items: jax.Array, k: int, *,
                       tile: int = _TOPK_TILE,
                       n_valid: Optional[int] = None,
                       interpret: bool = False
-                      ) -> Tuple[jax.Array, jax.Array]:
+                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Scores+ids of the top-k items per query — [B,D]·[N,D]ᵀ without
     ever materializing the [B, N] score block.
 
-    Returns ([B, k] f32, [B, k] int32) sorted descending.  ``n_valid``
-    masks trailing corpus-padding rows.  Tie order is lowest-running-slot
-    first, which can differ from ``lax.top_k``'s lowest-global-id order
-    on exactly-equal scores — callers compare id SETS, not sequences,
-    when scores tie.
+    Returns ([B, k] f32, [B, k] int32, int32 scalar): scores sorted
+    descending, their ids, and the selection rounds the scan ran (see
+    :func:`_fold_tile_topk`; at most k per tile).  ``n_valid`` masks
+    trailing corpus-padding rows.  An equal score that arrives later
+    never displaces an earlier one, so exactly-equal scores come out in
+    ascending id, ``lax.top_k``'s order — on equal SCORES; callers still
+    compare id SETS, not sequences, across rungs, whose matmuls round
+    differently.
     """
     b, d = queries.shape
     n = items.shape[0]
@@ -518,13 +556,13 @@ def fused_topk_pallas(queries: jax.Array, items: jax.Array, k: int, *,
         queries = jnp.pad(queries, ((0, b_pad), (0, 0)))
     bp = b + b_pad
     kernel = functools.partial(_topk_kernel, tile=tile, k=k, n_real=n_real)
-    out_s, out_i = _running_topk_call(
-        kernel, -(-n // tile),
+    out_s, out_i, rounds = _running_topk_call(
+        kernel, fused_topk_tiles(n, tile),
         [pl.BlockSpec((bp, d), lambda j: (0, 0)),
          pl.BlockSpec((tile, d), lambda j: (j, 0))],
         bp, k, tile, interpret,
     )(queries.astype(jnp.float32), items.astype(jnp.float32))
-    return out_s[:b, :k], out_i[:b, :k]
+    return out_s[:b, :k], out_i[:b, :k], rounds[0, 0]
 
 
 def fused_topk(queries: jax.Array, items: jax.Array, k: int, *,
@@ -550,7 +588,7 @@ def fused_topk(queries: jax.Array, items: jax.Array, k: int, *,
         use_pallas = pallas_supported()
     if use_pallas:
         return fused_topk_pallas(queries, items, k, n_valid=n_valid,
-                                 interpret=not pallas_supported())
+                                 interpret=not pallas_supported())[:2]
     if chunk:
         return chunked_top_k(queries, items, k, chunk=chunk,
                              n_valid=n_valid)
@@ -568,8 +606,9 @@ def fused_topk(queries: jax.Array, items: jax.Array, k: int, *,
 # a [B, 256]×[256, T] matmul per table, which is exactly the gather
 # "lut[t, code]" expressed as the small-integer arithmetic the MXU eats
 # (Mosaic has no vector gather; the one-hot contraction is the
-# supported spelling).  Tile scores fold into the same running-top-K
-# VMEM scratch pattern as fused_topk — the [B, N] score block never
+# supported spelling).  Tile scores fold into the running top-K through
+# fused_topk's own fold, gated on the running k-th best in the same way
+# (_fold_tile_topk) — the [B, N] score block never
 # materializes, and HBM traffic is ONE read of the (1+M)-byte-per-item
 # codes instead of 4·D bytes of fp32 corpus.
 # ---------------------------------------------------------------------------
@@ -577,8 +616,8 @@ def fused_topk(queries: jax.Array, items: jax.Array, k: int, *,
 _PQ_TILE = 512  # code rows per grid step (lane-aligned)
 
 
-def _pq_scan_kernel(luts_ref, codes_ref, out_s_ref, out_i_ref, m_ref,
-                    mi_ref, *, tile: int, k: int, n_real: int,
+def _pq_scan_kernel(luts_ref, codes_ref, out_s_ref, out_i_ref, rounds_ref,
+                    m_ref, *, tile: int, k: int, n_real: int,
                     n_tables: int):
     """One code tile LUT-scored and folded into the running top-k.
 
@@ -598,8 +637,8 @@ def _pq_scan_kernel(luts_ref, codes_ref, out_s_ref, out_i_ref, m_ref,
             luts_ref[:, pl.ds(t * 256, 256)], oh,
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-    _fold_tile_topk(s, pl.program_id(0), out_s_ref, out_i_ref, m_ref,
-                    mi_ref, tile=tile, k=k, n_real=n_real)
+    _fold_tile_topk(s, pl.program_id(0), out_s_ref, out_i_ref, rounds_ref,
+                    m_ref, tile=tile, k=k, n_real=n_real)
 
 
 @functools.partial(jax.jit,
@@ -612,8 +651,9 @@ def pq_scan_pallas(luts: jax.Array, codes: jax.Array, k: int, *,
     [S, N] uint8 codes without ever materializing the [B, N] block.
 
     Returns ([B, k] f32, [B, k] int32) sorted descending; ``n_valid``
-    masks trailing padding columns.  Same tie-order caveat as
-    ``fused_topk_pallas``: compare id SETS on exactly-equal scores.
+    masks trailing padding columns.  Same fold as ``fused_topk_pallas``
+    (:func:`_fold_tile_topk`), so the same cost and tie order: equal
+    scores in ascending id.
     """
     b, s, width = luts.shape
     assert width == 256, f"LUT width {width} != 256"
@@ -627,7 +667,7 @@ def pq_scan_pallas(luts: jax.Array, codes: jax.Array, k: int, *,
     bp = b + b_pad
     kernel = functools.partial(_pq_scan_kernel, tile=tile, k=k,
                                n_real=n_real, n_tables=s)
-    out_s, out_i = _running_topk_call(
+    out_s, out_i, _ = _running_topk_call(
         kernel, -(-n // tile),
         [pl.BlockSpec((bp, s * 256), lambda j: (0, 0)),
          pl.BlockSpec((s, tile), lambda j: (0, j))],

@@ -30,8 +30,12 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
-from predictionio_tpu.obs import dispatch_stage
-from predictionio_tpu.ops.pallas_kernels import fused_topk, pallas_supported
+from predictionio_tpu.obs import dispatch_stage, get_registry
+from predictionio_tpu.ops.pallas_kernels import (
+    fused_topk_pallas,
+    fused_topk_tiles,
+    pallas_supported,
+)
 from predictionio_tpu.ops.topk import (
     chunked_top_k,
     host_top_k,
@@ -60,13 +64,14 @@ def _cached(jit_cache: Dict, key, build):
     return fn
 
 
-def _staged_call(fn, stage_args) -> Tuple[np.ndarray, np.ndarray]:
+def _staged_call(fn, stage_args) -> Tuple[np.ndarray, ...]:
     """THE device round trip of the exact device rungs, in the three
     host stages a dispatch spends around the kernel: ``h2d``
     (``stage_args()``: the per-call uploads), ``launch`` (the jitted call
     returning; dispatch is asynchronous) and ``wait`` (``device_get``
     returning: kernel + D2H).  No synchronisation beyond the one
-    ``device_get`` the rungs always made."""
+    ``device_get`` the rungs always made; whatever ``fn`` returns beside
+    scores and ids (the Pallas top-k's round count) rides the same one."""
     import jax
 
     with dispatch_stage("retrieval.h2d", "h2d"):
@@ -74,8 +79,8 @@ def _staged_call(fn, stage_args) -> Tuple[np.ndarray, np.ndarray]:
     with dispatch_stage("retrieval.launch", "launch"):
         out = fn(*args)
     with dispatch_stage("retrieval.wait", "wait"):
-        s, i = jax.device_get(out)
-    return np.asarray(s), np.asarray(i)
+        out = jax.device_get(out)
+    return tuple(np.asarray(x) for x in out)
 
 
 def exact_host(queries: np.ndarray, host_vecs: np.ndarray, k: int, *,
@@ -143,7 +148,11 @@ def exact_chunked(queries: np.ndarray, items_dev, n_items: int, k: int, *,
 
     ``exclude`` ([B, ≤N] bool) rides the scan chunk-by-chunk — the
     Pallas kernel takes no mask, so excluded requests use the XLA scan
-    (score memory stays bounded at [B, chunk] either way).
+    (score memory stays bounded at [B, chunk] either way).  A Pallas call
+    observes ``pio_topk_fold_rounds_per_tile{rung}`` once: the selection
+    rounds the kernel ran over the corpus tiles it scanned (k on a kernel
+    that folds every tile in full; near k·ln(N/tile)/tiles per row when a
+    tile is folded only as far as its scores beat the running k-th).
     """
     import jax
     import jax.numpy as jnp
@@ -159,8 +168,8 @@ def exact_chunked(queries: np.ndarray, items_dev, n_items: int, k: int, *,
     def build():
         if use_pallas:
             def _fn(q, items, e):
-                return fused_topk(q, items, k, n_valid=n_items,
-                                  use_pallas=True)
+                return fused_topk_pallas(q, items, min(k, n),
+                                         n_valid=n_items)
         else:
             def _fn(q, items, e):
                 if e is not None and ne < n:
@@ -171,9 +180,17 @@ def exact_chunked(queries: np.ndarray, items_dev, n_items: int, k: int, *,
         return jax.jit(_fn)
 
     fn = _cached(jit_cache, ("chunked", b, k, use_pallas, ne), build)
-    return _staged_call(fn, lambda: (
+    s, i, *rounds = _staged_call(fn, lambda: (
         jnp.asarray(queries, jnp.float32), items_dev,
         jnp.asarray(exclude) if exclude is not None else None))
+    if rounds:
+        get_registry().histogram(
+            "pio_topk_fold_rounds_per_tile",
+            "Selection rounds the fused top-k kernel ran per corpus tile "
+            "it scanned, per call.", ("rung",),
+            buckets=(0.01, 0.03, 0.1, 0.3, 1, 3, 10, 30, 100, 300, 1000),
+        ).observe(int(rounds[0]) / fused_topk_tiles(n), rung="chunked")
+    return s, i
 
 
 def exact_sharded(queries: np.ndarray, items_sharded, n_items: int, k: int,

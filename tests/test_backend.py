@@ -233,7 +233,7 @@ def test_running_topk_kernels_store_on_lane_aligned_offsets():
                     jnp.float32)
     items = jnp.asarray(np.random.default_rng(1).standard_normal((300, 16)),
                         jnp.float32)
-    s, i = pk.fused_topk_pallas(q, items, 10, tile=128, interpret=True)
+    s, i, _ = pk.fused_topk_pallas(q, items, 10, tile=128, interpret=True)
     assert s.shape == (3, 10) and i.shape == (3, 10)
     ref = np.argsort(-(np.asarray(q) @ np.asarray(items).T), axis=1)[:, :10]
     assert [set(r) for r in np.asarray(i).tolist()] == \

@@ -1,6 +1,7 @@
 """Pallas fused gram kernel == einsum oracle (interpret mode on CPU)."""
 
 import numpy as np
+import pytest
 import jax.numpy as jnp
 
 from predictionio_tpu.ops.pallas_kernels import (
@@ -121,8 +122,8 @@ def test_fused_topk_kernel_matches_oracle():
     from predictionio_tpu.ops.pallas_kernels import fused_topk_pallas
 
     q, items = _topk_inputs()
-    s, i = fused_topk_pallas(jnp.asarray(q), jnp.asarray(items), 10,
-                             tile=256, interpret=True)
+    s, i, _ = fused_topk_pallas(jnp.asarray(q), jnp.asarray(items), 10,
+                                tile=256, interpret=True)
     s, i = np.asarray(s), np.asarray(i)
     want = _oracle_ids(q, items, 10)
     np.testing.assert_array_equal(np.sort(i, axis=1),
@@ -140,13 +141,111 @@ def test_fused_topk_kernel_tail_tile_and_n_valid():
 
     q, items = _topk_inputs(n=600)
     items[500:] = 50.0  # poison rows past n_valid
-    s, i = fused_topk_pallas(jnp.asarray(q), jnp.asarray(items), 8,
-                             tile=256, n_valid=500, interpret=True)
+    s, i, _ = fused_topk_pallas(jnp.asarray(q), jnp.asarray(items), 8,
+                                tile=256, n_valid=500, interpret=True)
     i = np.asarray(i)
     assert int(i.max()) < 500
     want = _oracle_ids(q, items[:500], 8)
     np.testing.assert_array_equal(np.sort(i, axis=1),
                                   np.sort(want, axis=1))
+
+
+# The fold is gated on each row's running k-th best (ISSUE 26): a tile
+# costs selection rounds only as far as its scores can enter.  The third
+# return is the rounds the scan ran.
+
+_GATE_TILE = 256
+_GATE_N = 5 * _GATE_TILE - 37          # five tiles, the last one ragged
+
+
+def _gate_corpus(order, b, seed=11):
+    q, items = _topk_inputs(b=b, n=_GATE_N, seed=seed)
+    first = items @ q[0]
+    if order == "ascending":            # every tile beats row 0's k-th
+        items = items[np.argsort(first, kind="stable")]
+    elif order == "descending":         # only the first tile does
+        items = items[np.argsort(-first, kind="stable")]
+    return q, np.ascontiguousarray(items)
+
+
+def _gated(q, items, k, **kw):
+    from predictionio_tpu.ops.pallas_kernels import fused_topk_pallas
+
+    s, i, rounds = fused_topk_pallas(jnp.asarray(q), jnp.asarray(items), k,
+                                     tile=_GATE_TILE, interpret=True, **kw)
+    return np.asarray(s), np.asarray(i), int(rounds)
+
+
+@pytest.mark.parametrize("b", [1, 8, 24])
+@pytest.mark.parametrize("k", [1, 10, 128, 200])
+@pytest.mark.parametrize("order", ["random", "ascending", "descending"])
+def test_gated_fold_matches_oracle(order, k, b):
+    q, items = _gate_corpus(order, b)
+    s, i, rounds = _gated(q, items, k)
+    scores = q @ items.T
+    want = _oracle_ids(q, items, k)
+    np.testing.assert_array_equal(np.sort(i, axis=1), np.sort(want, axis=1))
+    np.testing.assert_allclose(
+        s, np.take_along_axis(scores, want, axis=1), rtol=1e-5, atol=1e-6)
+    assert (np.diff(s, axis=1) <= 0).all()          # sorted descending
+    tiles = -(-_GATE_N // _GATE_TILE)
+    # The first tile fills k slots; no tile ever runs more than k rounds.
+    assert k <= rounds <= k * tiles
+    if b == 1 and order == "descending":
+        assert rounds == k                          # k / tiles a tile
+    if b == 1 and order == "ascending":
+        assert rounds == k * tiles                  # the worst input
+
+
+@pytest.mark.parametrize("n_valid", [_GATE_N - 1, 3 * _GATE_TILE + 5,
+                                     2 * _GATE_TILE, 40])
+def test_gated_fold_masks_what_lies_past_n_valid(n_valid):
+    """Rows past n_valid (a ragged tail, a cut inside a tile, whole
+    tiles) are never candidates: they win no slot and add no round."""
+    q, items = _gate_corpus("random", 4)
+    items[n_valid:] = 50.0                          # poison
+    s, i, rounds = _gated(q, items, 8, n_valid=n_valid)
+    assert int(i.max()) < n_valid
+    np.testing.assert_array_equal(
+        np.sort(i, axis=1), np.sort(_oracle_ids(q, items[:n_valid], 8), 1))
+    _, _, clean = _gated(q, np.ascontiguousarray(items[:n_valid]), 8)
+    assert rounds == clean
+
+
+@pytest.mark.parametrize("k", [1, 10, 128])
+def test_gated_fold_keeps_the_earlier_id_among_equal_scores(k):
+    """Duplicated rows whose equal scores straddle tiles: the score
+    multiset is the oracle's, every id is a real holder of its score,
+    none repeats, and a tie is settled for the lower id (lax.top_k's
+    order, which a stable argsort gives the oracle)."""
+    q, items = _gate_corpus("random", 3, seed=5)
+    items[_GATE_TILE:2 * _GATE_TILE] = items[:_GATE_TILE]
+    items[3 * _GATE_TILE + 7:3 * _GATE_TILE + 107] = items[50:150]
+    s, i, _ = _gated(q, items, k)
+    scores = q @ items.T
+    want = _oracle_ids(q, items, k)
+    np.testing.assert_allclose(
+        s, np.take_along_axis(scores, want, axis=1), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(
+        np.take_along_axis(scores, i, axis=1), s, rtol=1e-5, atol=1e-6)
+    assert all(len(set(r)) == k for r in i.tolist())
+    np.testing.assert_array_equal(i, want)
+
+
+@pytest.mark.parametrize("real, pad", [(1, 7), (5, 3), (9, 7)])
+def test_gated_fold_zero_pad_rows_add_no_round(real, pad):
+    """The all-zero rows a cohort is padded with score 0.0 everywhere:
+    under a strict compare they enter in the first tile only, return k
+    zeros, and the scan runs the rounds of the real rows alone."""
+    q, items = _gate_corpus("random", real, seed=7)
+    padded = np.concatenate([q, np.zeros((pad, q.shape[1]), np.float32)])
+    s, i, rounds = _gated(padded, items, 10)
+    s_real, i_real, rounds_real = _gated(q, items, 10)
+    np.testing.assert_array_equal(s[:real], s_real)
+    np.testing.assert_array_equal(i[:real], i_real)
+    assert (s[real:] == 0.0).all()
+    np.testing.assert_array_equal(i[real:], np.tile(np.arange(10), (pad, 1)))
+    assert rounds == rounds_real
 
 
 def test_fused_topk_dispatcher_cpu_falls_back_to_chunked():

@@ -234,6 +234,46 @@ class TestRouting:
                                           np.sort(want, axis=1),
                                           err_msg=rung)
 
+    def test_chunked_pallas_call_observes_the_kernels_round_count(
+            self, monkeypatch):
+        """One exact_chunked call on the Pallas path (interpreted here)
+        observes pio_topk_fold_rounds_per_tile once, with the number the
+        kernel itself returns over the tiles it scanned; the XLA scan
+        (an exclude mask, or no Pallas) observes nothing; fused_topk's
+        callers still get two arrays."""
+        from predictionio_tpu.obs import get_registry
+        from predictionio_tpu.ops import pallas_kernels as pk
+        from predictionio_tpu.retrieval import exact
+
+        q, items = _corpus(n=2500)
+        dev = jnp.asarray(items)
+        want = _exact_ids(q, items, 10)
+        series = "pio_topk_fold_rounds_per_tile"
+
+        s, i = exact.exact_chunked(q, dev, 2500, 10, jit_cache={})
+        assert get_registry().get(series) is None      # XLA scan on the CPU
+
+        monkeypatch.setattr(exact, "pallas_supported", lambda: True)
+        monkeypatch.setattr(
+            exact, "fused_topk_pallas",
+            lambda *a, **kw: pk.fused_topk_pallas(*a, **kw, interpret=True))
+        s, i = exact.exact_chunked(q, dev, 2500, 10, jit_cache={})
+        np.testing.assert_array_equal(np.sort(i, 1), np.sort(want, 1))
+        hist = get_registry().get(series)
+        assert hist.count(rung="chunked") == 1
+        _, _, rounds = pk.fused_topk_pallas(jnp.asarray(q), dev, 10,
+                                            n_valid=2500, interpret=True)
+        assert pk.fused_topk_tiles(2500) == 3
+        assert hist.sum(rung="chunked") == pytest.approx(int(rounds) / 3)
+        assert 10 / 3 <= hist.sum(rung="chunked") <= 10
+
+        excl = np.zeros((len(q), 2500), bool)
+        exact.exact_chunked(q, dev, 2500, 10, jit_cache={}, exclude=excl)
+        assert hist.count(rung="chunked") == 1
+        two = pk.fused_topk(jnp.asarray(q), dev, 10, use_pallas=True)
+        assert len(two) == 2
+        np.testing.assert_array_equal(np.asarray(two[1]), i)
+
 
 # -- sharded-exact ≡ single-device parity (tentpole acceptance) --------------
 

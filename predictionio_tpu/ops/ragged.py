@@ -320,3 +320,130 @@ def _split_bucket(
     ent_ids[: len(sel)] = sel.astype(np.int32)
     return Padded(indices=indices, values=values, mask=mask, row_ids=row_ids,
                   seg_ids=seg_ids, ent_ids=ent_ids)
+
+
+# -- turns of event sequences -> token buckets ------------------------------
+
+@dataclasses.dataclass
+class TurnPack:
+    """Turns packed for one dispatch of a sequence model.
+
+    A SEGMENT is one key's new tokens in this pack, contiguous in
+    ``tokens``; several turns of one key share a segment, in arrival
+    order, and each reads its answer at its own last token.
+
+    - ``tokens``   int32 ``[n]`` — item ids
+    - ``tok_seg``  int32 ``[n]`` — segment of each token
+    - ``tok_idx``  int32 ``[n]`` — index within the segment
+    - ``seg_key``  the key of each segment
+    - ``seg_len``  int32 ``[g]`` — tokens of each segment
+    - ``seg_last`` int32 ``[g]`` — index in ``tokens`` of its last token
+    - ``read_turn`` the caller's id of each turn answered from this pack
+    - ``read_tok`` int32 ``[r]`` — the token at which that turn reads its
+      answer; -1: the turn brought no token and no earlier turn of its key
+      is in the pack, so it reads the key's stored state
+    - ``read_key`` the key of each read
+    """
+
+    tokens: np.ndarray
+    tok_seg: np.ndarray
+    tok_idx: np.ndarray
+    seg_key: List
+    seg_len: np.ndarray
+    seg_last: np.ndarray
+    read_turn: List
+    read_tok: np.ndarray
+    read_key: List
+
+    @property
+    def n_tokens(self) -> int:
+        return len(self.tokens)
+
+
+def pack_turns(turns, *, max_tokens: int, max_reads: int,
+               max_pages: Optional[int] = None, pages_of=None):
+    """Yield :class:`TurnPack`\\ s covering ``turns`` — ``(turn id, key,
+    item ids)`` in arrival order — each within ``max_tokens`` tokens and
+    ``max_reads`` answers.  A turn that does not fit the room left is
+    split: its first tokens close this pack, the rest open the next, and
+    the turn is answered from the pack that holds its last token.
+
+    ``pages_of(key, n_new)`` (with ``max_pages``) is how many cache pages
+    the key's state holds once ``n_new`` tokens are added; a pack stops
+    growing before its segments' pages pass ``max_pages``.  It is asked
+    when a pack is being formed, so a caller that applies each pack
+    before asking for the next sees its own earlier packs counted.
+    """
+    segs: dict = {}     # key -> list of item-id chunks, in arrival order
+    held: dict = {}     # key -> tokens of the key in the pack so far
+    order: List = []
+    reads: List = []    # (turn id, key, tokens of the key so far)
+    n = 0
+
+    def close():
+        nonlocal segs, held, order, reads, n
+        starts, tokens, tok_seg, tok_idx, seg_len = {}, [], [], [], []
+        at = 0
+        for s, key in enumerate(order):
+            ids = np.concatenate(segs[key]) if segs[key] else \
+                np.zeros(0, np.int32)
+            starts[key] = at
+            tokens.append(ids)
+            tok_seg.append(np.full(len(ids), s, np.int32))
+            tok_idx.append(np.arange(len(ids), dtype=np.int32))
+            seg_len.append(len(ids))
+            at += len(ids)
+        seg_len_a = np.asarray(seg_len, np.int32)
+        pack = TurnPack(
+            tokens=np.concatenate(tokens).astype(np.int32)
+            if tokens else np.zeros(0, np.int32),
+            tok_seg=np.concatenate(tok_seg) if tok_seg
+            else np.zeros(0, np.int32),
+            tok_idx=np.concatenate(tok_idx) if tok_idx
+            else np.zeros(0, np.int32),
+            seg_key=list(order), seg_len=seg_len_a,
+            seg_last=(np.cumsum(seg_len_a) - 1).astype(np.int32),
+            read_turn=[r[0] for r in reads],
+            read_tok=np.asarray(
+                [starts[k] + upto - 1 if upto > 0 else -1
+                 for _, k, upto in reads], np.int32),
+            read_key=[r[1] for r in reads])
+        segs, held, order, reads, n = {}, {}, [], [], 0
+        return pack
+
+    for turn_id, key, items in turns:
+        items = np.asarray(items, np.int32)
+        at = 0
+        while True:
+            room = max_tokens - n
+            left = len(items) - at
+            take = min(room, left)
+            if take and max_pages is not None and pages_of is not None:
+                # Shrink the piece until the pack's pages fit the list.
+                others = sum(pages_of(k, held[k]) for k in order
+                             if k != key)
+                while take and others + pages_of(
+                        key, held.get(key, 0) + take) > max_pages:
+                    take //= 2
+                if not take and not order:
+                    raise ValueError(
+                        f"the state of {key!r} alone needs more than "
+                        f"{max_pages} pages: its history is longer than "
+                        "a dispatch can attend over")
+            if take:
+                if key not in segs:
+                    segs[key], held[key] = [], 0
+                    order.append(key)
+                segs[key].append(items[at:at + take])
+                held[key] += take
+                at += take
+                n += take
+            if at == len(items) and len(reads) < max_reads:
+                reads.append((turn_id, key, held.get(key, 0)))
+                break
+            # No room for the rest of the turn, or for its answer.
+            yield close()
+        if n == max_tokens or len(reads) == max_reads:
+            yield close()
+    if order or reads:
+        yield close()

@@ -13,6 +13,7 @@ latency histogram (SURVEY.md §5.5).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import datetime as _dt
 import json
@@ -422,6 +423,7 @@ class EngineServer:
                     self._serving, self._loaded_at, self._generation) \
                     if self._instance is not None and self._retain_previous \
                     else None
+                outgoing = self._models
                 self._instance = instance
                 self._models = models
                 self._algorithms = algorithms
@@ -431,6 +433,7 @@ class EngineServer:
                 gen = self._generation
                 prev = self._previous
                 retained = prev is not None
+            self._drop_serving_state(outgoing)
             self._gen_gauge.set(gen)
             self._prev_retained.set(1 if retained else 0)
             # Quality re-anchor (ISSUE 11): the new generation's
@@ -471,6 +474,7 @@ class EngineServer:
                     raise WorkflowError(
                         "No previous model generation retained — nothing "
                         "to roll back to.")
+                outgoing = self._models
                 self._previous = _Generation(
                     self._instance, self._models, self._algorithms,
                     self._serving, self._loaded_at, self._generation)
@@ -483,6 +487,7 @@ class EngineServer:
                 gen = self._generation
                 instance_id = prev.instance.id
                 restored_models = prev.models
+            self._drop_serving_state(outgoing)
             self._gen_gauge.set(gen)
             self._prev_retained.set(1)
             # Quality: the rollback ends any shadow session (the "new"
@@ -501,6 +506,18 @@ class EngineServer:
             logger.warning("Engine server rolled back to instance %s "
                            "(generation %d)", instance_id, gen)
             return instance_id
+
+    @staticmethod
+    def _drop_serving_state(models: List[Any]) -> None:
+        """A generation that stops serving forgets its per-user serving
+        state (``state_cache``) and gives that state's device memory
+        back: while another generation answers, the state falls behind
+        the users' events, so a rollback starts from misses (which
+        re-read the history) and never from stale state."""
+        for m in models or ():
+            drop = getattr(m, "drop_serving_state", None)
+            if callable(drop):
+                drop()
 
     def _arm_eviction(self, generation: int) -> None:
         """(Re)start the retained-previous TTL timer for ``generation``.
@@ -613,7 +630,19 @@ class EngineServer:
         Takes BOUND queries: binding is per-member, client-controlled
         failure, so it happens at admission (handler thread → its own
         400) and can never fail a cohort.  ``supplement`` stays here —
-        it belongs to the generation's serving instance."""
+        it belongs to the generation's serving instance.
+
+        The contract with ``batch_predict``: ``bound_queries`` are in
+        ARRIVAL order and are handed over in that order, so an algorithm
+        whose answers depend on earlier queries (a user's turns) applies
+        them as they came, within a cohort as across cohorts.  An
+        algorithm may hold state across calls ONLY through its model's
+        ``state_cache`` (:mod:`predictionio_tpu.serving.state_cache`):
+        the dispatch holds that cache's transaction from before the
+        first ``batch_predict`` until ``serve`` has answered every
+        member, so a dispatch that raises anywhere leaves every user's
+        state as it was, and the batcher's member-by-member retry of a
+        failed cohort applies no event twice."""
         with self._swap_lock:
             algorithms, models, serving, generation = (
                 self._algorithms, self._models, self._serving,
@@ -621,14 +650,19 @@ class EngineServer:
         with dispatch_stage("dispatch.supplement", "supplement"):
             queries = [serving.supplement(q) for q in bound_queries]
             indexed = list(enumerate(queries))
-        per_algo = [dict(a.batch_predict(m, indexed))
-                    for a, m in zip(algorithms, models)]
-        with dispatch_stage("dispatch.serve", "serve"):
-            return [
-                self._result_to_json(
-                    serving.serve(q, [pa[i] for pa in per_algo]))
-                for i, q in indexed
-            ], generation
+        with contextlib.ExitStack() as held:
+            for m in models:
+                cache = getattr(m, "state_cache", None)
+                if cache is not None:
+                    held.enter_context(cache.transaction())
+            per_algo = [dict(a.batch_predict(m, indexed))
+                        for a, m in zip(algorithms, models)]
+            with dispatch_stage("dispatch.serve", "serve"):
+                return [
+                    self._result_to_json(
+                        serving.serve(q, [pa[i] for pa in per_algo]))
+                    for i, q in indexed
+                ], generation
 
     def query_batch(self, query_jsons: List[Any]) -> List[Any]:
         """Batched predict (native frontend, ``pio batchpredict``): the

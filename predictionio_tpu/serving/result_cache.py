@@ -166,8 +166,13 @@ def canonical_query(query: Any,
     keys); integral floats normalize (JSON clients that send ``num: 10.0``).
 
     Raises TypeError for values JSON can't represent — callers bypass the
-    cache for such queries rather than guessing at a key.
+    cache for such queries rather than guessing at a key — and for a
+    query class that declares ``pio_stateful = True``: answering it moves
+    the state the next answer is computed from (the sequence template's
+    turns), so no earlier answer may stand in for it.
     """
+    if getattr(type(query), "pio_stateful", False):
+        raise TypeError(f"{type(query).__name__} is stateful: not cacheable")
     if dataclasses.is_dataclass(query) and not isinstance(query, type):
         if defaults is None:
             defaults = query_defaults(type(query))

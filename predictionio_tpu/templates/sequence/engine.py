@@ -1,0 +1,398 @@
+"""Sequence template — "what next" from a user's event history.
+
+A user's item events, in time order, are a sequence; the model
+(:mod:`predictionio_tpu.models.lfm2`: gated short convolutions, grouped-
+query attention, routed experts) scores every item as the next one.
+Serving keeps each user's state between queries in the
+:class:`~predictionio_tpu.serving.state_cache.StateCache`, so a query
+pays for the events it brings, not for the history behind them.
+
+Query/result JSON::
+
+    {"user": "u1", "num": 4, "events": ["i7", "i3"]}
+        -> {"itemScores": [{"item", "score"}]}
+
+``events``: the items of the user's events since their last query,
+oldest first.  The engine appends them to the user's state and answers
+at the last one; without ``events`` it answers from the state as it
+stands.  A user the cache holds nothing for (never seen, evicted, or
+after a ``/reload``) is first read back from the event store (the
+engine's ``appName``), so the store has to hold the events of the
+user's EARLIER turns and not yet those of this one: send a turn's events
+to the event server after its query has been answered.  A store that
+cannot answer fails the query (5xx) and moves no state: answering from
+this turn's events alone would commit a truncated history that every
+later turn would then hit.
+A query with ``events`` changes what the next one sees, so its answers
+are never served from the result cache.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import logging
+from typing import Any, ClassVar, Dict, List, Optional, Sequence
+
+import numpy as np
+
+from predictionio_tpu.controller import (
+    Algorithm,
+    DataSource,
+    Engine,
+    FirstServing,
+    Preparator,
+    RuntimeContext,
+)
+from predictionio_tpu.controller.params import Params
+from predictionio_tpu.data.event import BiMap
+from predictionio_tpu.obs import dispatch_stage
+
+logger = logging.getLogger(__name__)
+
+__all__ = [
+    "Query", "ItemScore", "PredictedResult", "Histories",
+    "DataSourceParams", "SequenceDataSource", "PreparatorParams",
+    "SequencePreparator", "SequenceAlgorithmParams", "SequenceModel",
+    "SequenceAlgorithm", "engine",
+]
+
+
+@dataclasses.dataclass
+class Query:
+    user: str
+    num: int = 10
+    events: Optional[List[str]] = None
+    # Answering advances the user's state: never a result-cache key.
+    pio_stateful: ClassVar[bool] = True
+
+
+@dataclasses.dataclass
+class ItemScore:
+    item: str
+    score: float
+
+
+@dataclasses.dataclass
+class PredictedResult:
+    itemScores: List[ItemScore]  # noqa: N815 — reference JSON field name
+
+
+@dataclasses.dataclass
+class Histories:
+    """Every user's item events in time order, users back to back:
+    user ``u``'s items are ``items[offsets[u]:offsets[u + 1]]``.  From
+    the datasource ``items`` are item strings; the preparator turns them
+    into ids below the vocabulary and fills ``item_index``."""
+
+    offsets: np.ndarray
+    items: np.ndarray
+    item_index: Optional[BiMap] = None
+    app_name: Optional[str] = None
+    event_names: Sequence[str] = ()
+
+
+@dataclasses.dataclass(frozen=True)
+class DataSourceParams(Params):
+    appName: str  # noqa: N815 — engine.json key parity
+    eventNames: Sequence[str] = ("view", "buy")  # noqa: N815
+
+
+class SequenceDataSource(DataSource):
+    """Reads each user's item events in time order."""
+
+    params_class = DataSourceParams
+
+    def read_training(self, ctx: RuntimeContext) -> Histories:
+        from predictionio_tpu.data.columnar import encode_ids
+
+        p: DataSourceParams = self.params
+        table = ctx.event_store.find_columnar(
+            p.appName, entity_type="user", target_entity_type="item",
+            event_names=list(p.eventNames), ordered=True,
+            columns=["entity_id", "target_entity_id"])
+        users, user_index = encode_ids(table.column("entity_id"))
+        items = np.asarray(table.column("target_entity_id").to_pylist(),
+                           dtype=object)
+        # A stable sort by user keeps each user's events in time order.
+        order = np.argsort(users, kind="stable")
+        counts = np.bincount(users, minlength=len(user_index))
+        return Histories(
+            offsets=np.concatenate([[0], np.cumsum(counts)]).astype(np.int64),
+            items=items[order], app_name=p.appName,
+            event_names=tuple(p.eventNames))
+
+
+@dataclasses.dataclass(frozen=True)
+class PreparatorParams(Params):
+    vocabSize: int = 65536  # noqa: N815 — items the model can name
+
+
+class SequencePreparator(Preparator):
+    """Maps items to ids below the vocabulary: the ``vocabSize`` most
+    frequent items, most frequent first; events on any other item are
+    dropped from the histories."""
+
+    params_class = PreparatorParams
+
+    def prepare(self, ctx: RuntimeContext, td: Histories) -> Histories:
+        vocab = int(self.params.vocabSize) if self.params else 65536
+        names, inverse, counts = np.unique(
+            td.items.astype(str), return_inverse=True, return_counts=True)
+        rank = np.argsort(-counts, kind="stable")[:vocab]
+        new_id = np.full(len(names), -1, np.int64)
+        new_id[rank] = np.arange(len(rank))
+        ids = new_id[inverse]
+        keep = ids >= 0
+        user_of = np.repeat(np.arange(len(td.offsets) - 1),
+                            np.diff(td.offsets))
+        counts_u = np.bincount(user_of[keep], minlength=len(td.offsets) - 1)
+        return Histories(
+            offsets=np.concatenate([[0], np.cumsum(counts_u)]).astype(
+                np.int64),
+            items=ids[keep].astype(np.int32),
+            item_index=BiMap({str(names[j]): i for i, j in enumerate(rank)}),
+            app_name=td.app_name, event_names=td.event_names)
+
+
+@dataclasses.dataclass(frozen=True)
+class SequenceAlgorithmParams(Params):
+    hiddenSize: int = 64  # noqa: N815
+    intermediateSize: int = 128  # noqa: N815
+    moeIntermediateSize: int = 32  # noqa: N815
+    numExperts: int = 8  # noqa: N815
+    numExpertsPerTok: int = 2  # noqa: N815
+    numAttentionHeads: int = 4  # noqa: N815
+    numKeyValueHeads: int = 2  # noqa: N815
+    layerTypes: Sequence[str] = (  # noqa: N815
+        "conv", "full_attention", "conv", "conv")
+    numDenseLayers: int = 1  # noqa: N815
+    # Training (next-item cross-entropy over windows of the histories).
+    steps: int = 200
+    batchSize: int = 16  # noqa: N815
+    window: int = 32
+    learningRate: float = 3e-3  # noqa: N815
+    seed: Optional[int] = None
+    # Serving state.
+    stateBudgetMB: float = 64.0  # noqa: N815
+    maxUsers: int = 1024  # noqa: N815
+
+
+@dataclasses.dataclass(eq=False)
+class SequenceModel:
+    """Trained weights + the item index; serving state hangs off it
+    (``state_cache``, keyed by user), so a model object IS a generation:
+    a reload loads a new object with an empty cache, and the server
+    frees this one's."""
+
+    config: Any                       # models.lfm2.LFM2Config
+    params: Dict[str, Any]            # host arrays
+    item_index: BiMap
+    app_name: Optional[str] = None
+    event_names: Sequence[str] = ()
+    state_budget_bytes: int = 64 << 20
+    max_users: int = 1024
+
+    def __post_init__(self):
+        self._init_transients()
+
+    def _init_transients(self) -> None:
+        self._runtime = None
+        self._ctx: Optional[RuntimeContext] = None
+
+    def __getstate__(self):
+        d = dict(self.__dict__)
+        for k in ("_runtime", "_ctx"):
+            d.pop(k, None)
+        d["params"] = _to_host(d["params"])
+        return d
+
+    def __setstate__(self, d):
+        self.__dict__.update(d)
+        self._init_transients()
+
+    def post_load(self, ctx) -> None:
+        self._ctx = ctx
+
+    def runtime(self):
+        """The device side, built on first use: serving-precision weights,
+        the state cache within its budget, the compiled programs."""
+        if self._runtime is None:
+            from predictionio_tpu.models import lfm2
+            from predictionio_tpu.serving.state_cache import StateCache
+
+            cfg = self.config
+            cache = StateCache(
+                n_fixed_layers=cfg.n_conv, n_paged_layers=cfg.n_attn,
+                width=cfg.hidden_size, paged_width=cfg.kv_width,
+                budget_bytes=self.state_budget_bytes,
+                max_users=self.max_users)
+            self._runtime = lfm2.SequenceRuntime(
+                cfg, lfm2.cast_for_serving(self.params), cache)
+        return self._runtime
+
+    @property
+    def state_cache(self):
+        """What the engine server holds a transaction on around a
+        dispatch, and frees when this generation stops serving."""
+        return self.runtime().cache
+
+    def drop_serving_state(self) -> None:
+        """Every user's state and the pools' device memory go (the
+        server may keep this model for a rollback)."""
+        if self._runtime is not None:
+            self._runtime.cache.free()
+
+    def stored_history(self, user: str, limit: int) -> np.ndarray:
+        """The user's item ids from the event store, oldest first (at
+        most the latest ``limit``); empty when the model names no app,
+        the store holds no such app (a model deployed with no store
+        behind it) or the user has no event.  Any other failure of the
+        store is the caller's: a history that could not be read is not
+        an empty one."""
+        if not self.app_name:
+            return np.zeros(0, np.int32)
+        ctx = self._ctx
+        if ctx is None:
+            ctx = self._ctx = RuntimeContext.create()
+        if ctx.storage.get_apps().get_by_name(self.app_name) is None:
+            return np.zeros(0, np.int32)
+        events = ctx.event_store.find_by_entity(
+            self.app_name, "user", user,
+            event_names=list(self.event_names) or None,
+            target_entity_type="item", limit=limit, latest=True)
+        ids = [self.item_index.get(e.target_entity_id)
+               for e in reversed(events)]
+        return np.asarray([i for i in ids if i is not None], np.int32)
+
+
+def _to_host(tree):
+    import jax
+
+    return jax.tree_util.tree_map(np.asarray, tree)
+
+
+class SequenceAlgorithm(Algorithm):
+    params_class = SequenceAlgorithmParams
+
+    def train(self, ctx: RuntimeContext, pd: Histories) -> SequenceModel:
+        """Next-item cross-entropy over random windows of the histories,
+        on the plain forward pass (tier-1 sizes: every expert runs on
+        every token)."""
+        import jax
+        import jax.numpy as jnp
+        import optax
+
+        from predictionio_tpu.models import lfm2, lfm2_reference
+
+        p: SequenceAlgorithmParams = self.params
+        if pd.item_index is None or len(pd.items) == 0:
+            raise ValueError("No item events found — check appName and "
+                             "eventNames.")
+        cfg = lfm2.LFM2Config(
+            vocab_size=len(pd.item_index), hidden_size=p.hiddenSize,
+            intermediate_size=p.intermediateSize,
+            moe_intermediate_size=p.moeIntermediateSize,
+            num_experts=p.numExperts,
+            num_experts_per_tok=p.numExpertsPerTok,
+            num_attention_heads=p.numAttentionHeads,
+            num_key_value_heads=p.numKeyValueHeads,
+            layer_types=tuple(p.layerTypes),
+            dense_ff=tuple(i < p.numDenseLayers
+                           for i in range(len(p.layerTypes))))
+        seed = p.seed if p.seed is not None else ctx.seed
+        params = lfm2.init_params(cfg, jax.random.PRNGKey(seed),
+                                  jnp.float32)
+        opt = optax.adam(p.learningRate)
+        opt_state = opt.init(params)
+
+        def loss_fn(params, tokens, mask):
+            logits = jax.vmap(lambda t: lfm2_reference.forward(
+                params, cfg, t))(tokens[:, :-1])
+            nll = optax.softmax_cross_entropy_with_integer_labels(
+                logits, tokens[:, 1:])
+            return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+
+        @jax.jit
+        def step(params, opt_state, tokens, mask):
+            loss, grads = jax.value_and_grad(loss_fn)(params, tokens, mask)
+            updates, opt_state = opt.update(grads, opt_state)
+            return optax.apply_updates(params, updates), opt_state, loss
+
+        rng = np.random.default_rng(seed)
+        lengths = np.diff(pd.offsets)
+        users = np.flatnonzero(lengths >= 2)
+        if len(users) == 0:
+            raise ValueError("No user has two item events to learn from.")
+        w = int(p.window)
+        loss = float("nan")
+        for _ in range(int(p.steps)):
+            tokens = np.zeros((p.batchSize, w + 1), np.int32)
+            mask = np.zeros((p.batchSize, w), np.float32)
+            for row, u in enumerate(rng.choice(users, p.batchSize)):
+                n = min(int(lengths[u]), w + 1)
+                start = pd.offsets[u] + rng.integers(
+                    0, lengths[u] - n + 1)
+                tokens[row, :n] = pd.items[start:start + n]
+                mask[row, :n - 1] = 1.0
+            params, opt_state, loss = step(params, opt_state,
+                                           jnp.asarray(tokens),
+                                           jnp.asarray(mask))
+        logger.info("sequence model trained: %d steps, loss %.4f",
+                    p.steps, float(loss))
+        return SequenceModel(
+            config=cfg, params=_to_host(params), item_index=pd.item_index,
+            app_name=pd.app_name, event_names=tuple(pd.event_names),
+            state_budget_bytes=int(p.stateBudgetMB * (1 << 20)),
+            max_users=p.maxUsers)
+
+    def predict(self, model: SequenceModel, query: Query) -> PredictedResult:
+        return self.batch_predict(model, [(0, query)])[0][1]
+
+    def batch_predict(self, model: SequenceModel, queries):
+        """One cohort: every turn's new events through the backbone
+        against its user's cached state, in arrival order (two turns of
+        one user share a segment; the second sees the first).  The state
+        moves only if the whole transaction does: inside the engine
+        server that is the dispatch, here the call."""
+        from predictionio_tpu.models.lfm2 import Turn
+
+        runtime = model.runtime()
+        cache = runtime.cache
+        with cache.transaction():
+            with dispatch_stage("predict.lookup", "lookup"):
+                turns: List[Turn] = []
+                prefill: Dict[Any, int] = {}
+                seen = set()
+                index = model.item_index
+                for _, q in queries:
+                    key = q.user
+                    ids = [index.get(e) for e in (q.events or ())]
+                    items = np.asarray([i for i in ids if i is not None],
+                                       np.int32)
+                    # A miss re-reads as much as one dispatch attends over.
+                    room = cache.max_events - len(items)
+                    if key not in seen and room > 0 \
+                            and not cache.has(key, count=True):
+                        history = model.stored_history(q.user, room)
+                        if len(history):
+                            prefill[key] = len(history)
+                            items = np.concatenate([history, items])
+                    seen.add(key)
+                    turns.append(Turn(key, items, max(int(q.num), 1)))
+            answers = runtime.extend(turns, prefill)
+            with dispatch_stage("predict.assemble", "assemble"):
+                inv = model.item_index.inverse
+                return [(i, PredictedResult(itemScores=[
+                    ItemScore(item=inv[int(ii)], score=float(ss))
+                    for ss, ii in zip(scores[:q.num], ids[:q.num])]))
+                    for (i, q), (scores, ids) in zip(queries, answers)]
+
+
+def engine() -> Engine:
+    return Engine(
+        datasource_class=SequenceDataSource,
+        preparator_class=SequencePreparator,
+        algorithm_classes={"sequence": SequenceAlgorithm},
+        serving_class=FirstServing,
+        query_class=Query,
+    )

@@ -23,4 +23,4 @@ def test_example_binds(path):
 def test_examples_cover_all_templates():
     names = {p.parent.name for p in EXAMPLES}
     assert names == {"recommendation", "classification", "similarproduct",
-                     "ecommerce", "twotower", "dlrm"}
+                     "ecommerce", "twotower", "dlrm", "sequence"}

@@ -584,6 +584,35 @@ def run_kernels(args) -> int:
     say("gram", shapes=gram_shapes, twin="fused_gram_vector_xla",
         worst_rel_err=f"{worst:.2e}", tol="1e-4")
 
+    # -- the dense gram kernel: a block of ratings over a whole factor
+    # table (NaN = no rating, a real 0.0 among the values), one and
+    # several source tiles, rows that pad a row tile.  Implicit weights
+    # round w*x to bfloat16 as the gathered kernel does, so they stand
+    # further from the float32 twin.
+    dense_shapes = [(40, 60_000), (20, 2_500)] if not interpret \
+        else [(5, 300)]
+    worst = {False: 0.0, True: 0.0}
+    for j, n_src in dense_shapes:
+        x = jnp.asarray(rng.standard_normal((n_src, RANK)) / 8, jnp.bfloat16)
+        vals = rng.integers(0, 6, (j, n_src)).astype(np.float32)
+        vals[rng.random((j, n_src)) > 0.05] = np.nan
+        block = jnp.asarray(vals, pk.DENSE_BLOCK_DTYPE)
+        for implicit in (False, True):
+            a, b = pk.fused_gram_dense_pallas(
+                block, x, 0.5, implicit=implicit, interpret=interpret)
+            ra, rb = twin(pk.fused_gram_dense_xla)(
+                block, x.astype(jnp.float32), 0.5, implicit=implicit)
+            check(bool(jnp.isfinite(a).all() and jnp.isfinite(b).all()),
+                  f"dense gram {j}x{n_src}: non-finite output")
+            worst[implicit] = max(worst[implicit], rel_err(a, ra),
+                                  rel_err(b, rb))
+    check(worst[False] <= 1e-4 and worst[True] <= 5e-3,
+          f"dense gram kernel off by {worst[False]:.2e} (explicit) / "
+          f"{worst[True]:.2e} (implicit) vs XLA twin")
+    say("gram_dense", shapes=dense_shapes, twin="fused_gram_dense_xla",
+        worst_rel_err=f"{worst[False]:.2e}/{worst[True]:.2e}",
+        tol="1e-4/5e-3")
+
     # -- LU and GJ solvers against the Cholesky branch of _ridge.
     nb = sizes["lu_batch"]
     y = jnp.asarray(rng.standard_normal((nb, 2 * RANK, RANK)) / 8,
@@ -888,8 +917,8 @@ def main(argv=None) -> int:
                 f"device_kind={d['kind']} devices={d['count']} "
                 f"wall_s={kern['wall_s']} compile_s={kern['compile_s']} "
                 f"(cache hits {kern['cache_hits']}/{kern['compiles']}) "
-                f"pallas={kern['pallas']}: gram, lu, gj, fused_topk, "
-                f"pq_scan each within tolerance of its XLA twin")
+                f"pallas={kern['pallas']}: gram, gram_dense, lu, gj, "
+                f"fused_topk, pq_scan each within tolerance of its XLA twin")
         ok = True
     except SmokeFailure as e:
         log(f"chip_smoke: FAILED: {e}")

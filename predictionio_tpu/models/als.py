@@ -8,7 +8,10 @@ between executors, per-block normal equations solved via JNI BLAS.
 The TPU design replaces all of that with one batched XLA program per side
 per iteration (SURVEY.md §7 step 5):
 
-- ragged ratings → degree-bucketed padded blocks (host-side, once)
+- ragged ratings → degree-bucketed padded blocks (host-side, once);
+  rows that rated a large enough share of the other side go to a dense
+  block instead, and their normal equations are a masked product over
+  the whole factor table (``ops.pallas_kernels.fused_gram_dense``)
 - per-entity normal equations built by batched einsum over gathered
   factors (MXU) — ``A_u = Σ_i w_ui · y_i y_iᵀ``
 - batched Cholesky solves (``ops.linalg.batched_ridge_solve``)
@@ -36,10 +39,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from predictionio_tpu.obs import phase
+from predictionio_tpu.obs import get_registry, phase
 from predictionio_tpu.ops.linalg import gram, masked_gram
 from predictionio_tpu.ops.pallas_kernels import (
+    DENSE_BLOCK_DTYPE,
+    dense_block_width,
     fits_vmem,
+    fused_gram_dense,
     fused_gram_vector_pallas,
     gj_fits_vmem,
     pallas_supported,
@@ -77,10 +83,15 @@ class ALSConfig:
     dtype: str = "float32"     # factor storage dtype; solves always f32
     # Gather + matmul input precision for the gram/rhs builds (factor
     # MASTER copies and all accumulation stay f32; only the gathered
-    # operands are cast).  The v5e gather engine is row-rate limited
-    # (~0.34 G rows/s f32, ~0.46 bf16 measured) and the training loop is
-    # gather-bound at ML-25M, so "auto" = bfloat16 on TPU, float32
-    # elsewhere (CPU tests keep numpy-oracle exactness).
+    # operands are cast).  XLA's gather on the v5e is bound by rows, not
+    # bytes: in bf16, 0.41-0.46 G rows/s (2.2-2.5 ns a row) from a table
+    # of up to 240,000 x 64 and 0.08-0.09 G rows/s (11.3-12.0 ns) from
+    # one of 480,189 x 64, sorted indices or not (one v5e, PR 29:
+    # als-netflix-r64's sweep and gathers alone; float32 gathers not
+    # measured there).  The gathered rows are what a sweep spends its
+    # time on wherever rows are too sparse for the dense block, so
+    # "auto" = bfloat16 on TPU, float32 elsewhere (CPU tests keep
+    # numpy-oracle exactness).
     gram_dtype: str = "auto"
     # Normal-equation solver: "auto" = the Pallas shrinking-elimination
     # kernel ("lu") on TPU — the XLA batched Cholesky was the single
@@ -561,14 +572,19 @@ class ALSInputs:
     item_buckets: List[Tuple]
     n_users: int
     n_items: int
-    # Per side: tuple over buckets of ("plain", ((cs, cn), ...)) or
-    # ("merged", pad_to, ((e0, e1, r0, r1), ...)); None = pre-chunked.
+    # Per side: tuple over buckets of ("plain", ((cs, cn), ...)),
+    # ("merged", pad_to, ((e0, e1, r0, r1), ...)) or ("dense", ());
+    # None = pre-chunked.
     chunk_specs: Optional[Tuple[Tuple, Tuple]] = None
     # Future resolving to (statics, compiled loop executable) from the
     # plan-shape pre-warm, or None; loop_warm_statics mirrors the statics
     # the pre-warm lowered so a mismatched train can skip the wait.
     loop_warm: Optional[object] = None
     loop_warm_statics: Optional[dict] = None
+    # Ratings a sweep builds normal equations from, per side (users,
+    # items) as (dense, gathered): what pio_als_gram_ratings_total
+    # advances by per sweep.
+    gram_ratings: Tuple[Tuple[int, int], Tuple[int, int]] = ((0, 0), (0, 0))
 
 
 def prepare_als_inputs(
@@ -647,16 +663,17 @@ def prepare_als_inputs(
                 bucket_bounds=config.bucket_bounds,
                 max_len=config.max_degree, pad_rows_to=pad_rows,
                 split_above=config.split_above)
+        gathered = sum(int(p.mask.sum()) for p in buckets)
         with phase("prep.upload"):
-            return _device_buckets(
+            return (0, gathered), _device_buckets(
                 buckets, mesh, k, config.max_block_floats, pad_rows,
                 window_n_src=n_src if window else None)
 
-    user_buckets = one_side(user_ids, item_ids, n_users, n_items)
-    item_buckets = one_side(item_ids, user_ids, n_items, n_users)
+    count_u, user_buckets = one_side(user_ids, item_ids, n_users, n_items)
+    count_i, item_buckets = one_side(item_ids, user_ids, n_items, n_users)
     return ALSInputs(uf0=uf, itf0=itf, user_buckets=user_buckets,
                      item_buckets=item_buckets, n_users=n_users,
-                     n_items=n_items)
+                     n_items=n_items, gram_ratings=(count_u, count_i))
 
 
 # (BucketPlan, nnz) -> AOT-compiled build program.  LRU-bounded: a
@@ -719,13 +736,21 @@ def _compile_build(lowered):
 
 
 def _plan_side(rows: jax.Array, n_rows: int, config: ALSConfig,
-               host_rows: Optional[np.ndarray] = None):
+               host_rows: Optional[np.ndarray] = None,
+               n_src: Optional[int] = None,
+               host_cols: Optional[np.ndarray] = None):
     """One side's :class:`~ops.device_prep.BucketPlan` from COO ids.
 
     With ``host_rows`` (the caller's numpy copy of the same ids) the
     degree statistics run as one ``np.bincount`` — ~0.3 s at 25M rows.
     The device fallback exists for device-only callers; each of its
     small jitted stats ops pays its own compile and dispatch.
+    ``n_src`` (the other side's row count) lets dense-enough rows go to
+    the dense block; without it the plan is the all-sparse one.  With
+    ``host_cols`` too, a side whose densest row repeats a (row, col) pair
+    (views, plays: the data a block cannot hold) is planned all-sparse
+    here, for the cost of looking at one row, rather than found out by
+    the build.
     """
     from predictionio_tpu.ops.device_prep import (
         degree_histogram, plan_buckets,
@@ -763,11 +788,21 @@ def _plan_side(rows: jax.Array, n_rows: int, config: ALSConfig,
             # needs them to place split-chunk boundaries (tiny D2H).
             ids = jnp.nonzero(counts > split_above, size=n_over)[0]
             over_deg = np.asarray(counts[ids])
-    return plan_buckets(hist, n_over, n_part, n_rows,
-                        split_above=split_above,
-                        bucket_bounds=config.bucket_bounds,
-                        max_block_floats=config.max_block_floats,
-                        rank=config.rank, over_degrees=over_deg)
+    def plan(n_src):
+        return plan_buckets(hist, n_over, n_part, n_rows,
+                            split_above=split_above,
+                            bucket_bounds=config.bucket_bounds,
+                            max_block_floats=config.max_block_floats,
+                            rank=config.rank, over_degrees=over_deg,
+                            n_src=n_src)
+
+    out = plan(n_src)
+    if out.dense_rows and host_rows is not None and host_cols is not None \
+            and len(host_cols) == len(host_rows):
+        top = np.asarray(host_cols)[host_rows == counts.argmax()]
+        if len(np.unique(top)) != len(top):
+            return plan(None)
+    return out
 
 
 def _plan_bucket_shapes(plan):
@@ -775,10 +810,11 @@ def _plan_bucket_shapes(plan):
 
     Mirrors ``_prepare_als_inputs_device.one_side``: plain buckets at
     BUCKET level (one entry per plan bucket, chunk slicing is in-graph),
-    then the merged split bucket.  Keeping this in lock-step with
-    ``ops.device_prep.build_buckets`` is what lets the loop pre-warm
-    lower an IDENTICAL program from shapes alone (test-asserted:
-    tests/test_device_prep.py::TestPlanShapeLockstep).
+    then the merged split bucket, then the dense block.  Keeping this
+    in lock-step with ``ops.device_prep.build_buckets`` and
+    ``build_dense_block`` is what lets the loop pre-warm lower an
+    IDENTICAL program from shapes alone (test-asserted:
+    tests/test_device_prep.py::TestPlanShapeLockstep, TestDensePrep).
     """
     S = jax.ShapeDtypeStruct
     f32, i32, b_ = jnp.float32, jnp.int32, jnp.bool_
@@ -792,6 +828,13 @@ def _plan_bucket_shapes(plan):
         out.append(("merged", S((pr, sl), i32), S((pr, sl), f32),
                     S((pr, sl), b_), S((pr,), i32), S((ns,), i32)))
         specs.append(("merged", plan.pad_rows_to, plan.split_chunks))
+    if plan.dense_rows:
+        jp = plan.dense_rows
+        out.append(("dense",
+                    S((jp, dense_block_width(plan.dense_src)),
+                      DENSE_BLOCK_DTYPE),
+                    S((jp,), i32), S((jp,), f32)))
+        specs.append(("dense", ()))
     return out, tuple(specs)
 
 
@@ -837,12 +880,33 @@ def _compile_train_loop(statics, lowered, fut) -> None:
         fut.set_result(None)
 
 
+def _dense_block_holds(ratings) -> bool:
+    """Whether a dense block can stand for these ratings: each is a value
+    its dtype holds exactly (and none is NaN, the block's "no rating"),
+    so the ``w`` and ``c`` the dense kernel derives are the gathered
+    path's, bit for bit.  Stars, half-stars, counts and ones are."""
+    if ratings is None:
+        return True
+    if isinstance(ratings, np.ndarray):
+        r = np.asarray(ratings, np.float32)
+        return np.array_equal(
+            r.astype(DENSE_BLOCK_DTYPE).astype(np.float32), r)
+    r = jnp.asarray(ratings, jnp.float32)
+    return bool(jnp.all(
+        r.astype(DENSE_BLOCK_DTYPE).astype(jnp.float32) == r))
+
+
 def _prepare_als_inputs_device(
     user_ids, item_ids, ratings, n_users: int, n_items: int,
-    config: ALSConfig, host_ids=None,
+    config: ALSConfig, host_ids=None, dense: bool = True,
 ) -> ALSInputs:
-    """Device-side prep: COO up once, layout transform on the chip."""
-    from predictionio_tpu.ops.device_prep import build_buckets
+    """Device-side prep: COO up once, layout transform on the chip.
+
+    ``dense=False`` plans no dense rows: the second pass of a prep whose
+    dense block could not hold its rows' ratings."""
+    from predictionio_tpu.ops.device_prep import (
+        build_buckets, build_dense_block,
+    )
 
     k = config.rank
     # The DEVICE data always comes from user_ids/item_ids — host_ids is a
@@ -858,9 +922,10 @@ def _prepare_als_inputs_device(
             return h, jnp.asarray(h)
         return None, jnp.asarray(ids, dtype=jnp.int32)
 
-    # The prep.* phases (pio_train_phase_ms) time the FOREGROUND thread
-    # only, and add no wait: uploads, dispatches and the build run are
-    # asynchronous, the compiles run on background threads.
+    # The prep.* phases (pio_train_phase_ms) time calls, not the device:
+    # uploads and dispatches are asynchronous.  All are the foreground
+    # thread's but prep.build_run, the build program's dispatch, made by
+    # the thread that compiled it while the loop is lowered here.
     with phase("prep.upload"):
         host_u, rows_u = one_input(user_ids,
                                    host_ids[0] if host_ids else None)
@@ -872,8 +937,14 @@ def _prepare_als_inputs_device(
             vals = jnp.asarray(ratings, dtype=jnp.float32)
 
     with phase("prep.plan"):
-        plan_u = _plan_side(rows_u, n_users, config, host_rows=host_u)
-        plan_i = _plan_side(rows_i, n_items, config, host_rows=host_i)
+        dense = dense and _dense_block_holds(ratings)
+        plan_u = _plan_side(rows_u, n_users, config, host_rows=host_u,
+                            n_src=n_items if dense else None,
+                            host_cols=host_i)
+        plan_i = _plan_side(rows_i, n_items, config, host_rows=host_i,
+                            n_src=n_users if dense else None,
+                            host_cols=host_u)
+    nnz = rows_u.shape[0]
 
     # The build program emits BUCKET-level arrays (chunk slicing happens
     # in-graph inside the training loop — see _expand_chunks); its compile
@@ -882,21 +953,35 @@ def _prepare_als_inputs_device(
     # bypass the in-memory jit cache, so memoize per (plans, nnz) — warm
     # re-preps (retrains, the bench's second pass) skip the compile; a
     # new process finds both programs in the persistent compile cache
-    # (backend.configure_compile_cache).  The factor init runs while the
-    # build compiles (XLA compiles off the GIL; the device is free).
+    # (backend.configure_compile_cache).  The program is RUN from the
+    # thread that compiled it, as soon as it exists, so the chip buckets
+    # while this thread lowers the loop (a warm compile cache answers in
+    # a second or two; the build run used to start only after the loop's
+    # lowering, and all of it was the caller's wait).
     import concurrent.futures
 
     build_u = dataclasses.replace(plan_u, plain_chunks=(), split_chunks=())
     build_i = dataclasses.replace(plan_i, plain_chunks=(), split_chunks=())
 
-    def build_both(ru, ri, v, *, pu, pi):
-        return (build_buckets.__wrapped__(ru, ri, v, pu),
-                build_buckets.__wrapped__(ri, ru, v, pi))
+    def dense_block(r, c, v, p):
+        if not p.dense_rows:
+            return None
+        return build_dense_block.__wrapped__(
+            r, c, v, n_rows=p.n_rows, n_src=p.dense_src,
+            dense_min=p.dense_min, dense_rows=p.dense_rows)
 
-    nnz = rows_u.shape[0]
+    def build_both(ru, ri, v, *, pu, pi):
+        return ((*build_buckets.__wrapped__(ru, ri, v, pu),
+                 dense_block(ru, ri, v, pu)),
+                (*build_buckets.__wrapped__(ri, ru, v, pi),
+                 dense_block(ri, ru, v, pi)))
+
     co = _build_cache_get((build_u, build_i, nnz))
-    pend = None
-    if co is None:
+    pend = concurrent.futures.Future()
+    if co is not None:
+        with phase("prep.build_run"):
+            pend.set_result((co, co(rows_u, rows_i, vals)))
+    else:
         with phase("prep.lower_build"):
             lowered = jax.jit(
                 build_both, static_argnames=("pu", "pi")).lower(
@@ -904,15 +989,17 @@ def _prepare_als_inputs_device(
         # Daemon thread + Future (same pattern as _compile_train_loop): a
         # non-daemon executor worker would block interpreter exit if the
         # backend compile RPC ever hangs.
-        pend = concurrent.futures.Future()
 
-        def _run_build_compile(lowered=lowered, fut=pend):
+        def _compile_and_run_build(lowered=lowered, fut=pend):
             try:
-                fut.set_result(_compile_build(lowered))
+                co = _compile_build(lowered)
+                with phase("prep.build_run"):
+                    out = co(rows_u, rows_i, vals)
+                fut.set_result((co, out))
             except BaseException as e:  # delivered to the waiter
                 fut.set_exception(e)
 
-        threading.Thread(target=_run_build_compile, daemon=True).start()
+        threading.Thread(target=_compile_and_run_build, daemon=True).start()
 
     # Fire the fused-loop compile from plan-derived shapes — its cold
     # compile overlaps prep execution and whatever the caller does
@@ -955,21 +1042,39 @@ def _prepare_als_inputs_device(
     with phase("prep.init_factors"):
         uf, itf = _init_factors(n_users, n_items, k, config.seed)
 
-    if pend is not None:
-        with phase("prep.compile_wait"):
-            co = pend.result()
-        _build_cache_put((build_u, build_i, nnz), co)
+    with phase("prep.compile_wait"):
+        co, (side_u, side_i) = pend.result()
+    _build_cache_put((build_u, build_i, nnz), co)
 
-    with phase("prep.build_run"):
-        side_u, side_i = co(rows_u, rows_i, vals)
+    # A block holds one value a (row, source) pair: where a dense row's
+    # ratings repeat a pair, fewer slots are filled than it has ratings,
+    # and the whole prep is made again all-sparse.  That costs a second
+    # build and lowering, so _plan_side keeps the common case out (a
+    # densest row that repeats a pair plans no dense rows); the read of
+    # a scalar a side waits for the build, only where a side has a block.
+    short = {side: (int(built[2][3]), plan.dense_ratings)
+             for side, built, plan in (("user", side_u, plan_u),
+                                       ("item", side_i, plan_i))
+             if built[2] is not None}
+    if any(filled != want for filled, want in short.values()):
+        logger.warning(
+            "ALS prep: dense rows repeat a (user, item) pair (slots filled, "
+            "ratings: %s); preparing again without dense rows", short)
+        del side_u, side_i
+        return _prepare_als_inputs_device(
+            user_ids, item_ids, ratings, n_users, n_items, config,
+            host_ids=host_ids, dense=False)
 
     def one_side(built, plan):
-        plain, split = built
+        plain, split, block = built
         out = [("plain", *b) for b in plain]
         specs = [("plain", ch) for ch in plan.plain_chunks]
         if split is not None:
             out.extend(("merged", *b) for b in split)
             specs.append(("merged", plan.pad_rows_to, plan.split_chunks))
+        if block is not None:
+            out.append(("dense", *block[:3]))
+            specs.append(("dense", ()))
         return out, tuple(specs)
 
     user_buckets, spec_u = one_side(side_u, plan_u)
@@ -977,7 +1082,10 @@ def _prepare_als_inputs_device(
     return ALSInputs(uf0=uf, itf0=itf, user_buckets=user_buckets,
                      item_buckets=item_buckets, n_users=n_users,
                      n_items=n_items, chunk_specs=(spec_u, spec_i),
-                     loop_warm=fut, loop_warm_statics=warm_statics)
+                     loop_warm=fut, loop_warm_statics=warm_statics,
+                     gram_ratings=tuple(
+                         (p.dense_ratings, nnz - p.dense_ratings)
+                         for p in (plan_u, plan_i)))
 
 
 def train_als(
@@ -1069,7 +1177,19 @@ def train_als_prepared(inputs: ALSInputs, config: ALSConfig, *,
         if warm is not None and warm[0] == statics:
             warm_exe = warm[1]
 
+    gram_ratings = get_registry().counter(
+        "pio_als_gram_ratings_total",
+        "Ratings whose normal equations an ALS dispatch built (the plan's "
+        "counts x sweeps), by side and by path: dense (a masked product "
+        "over the whole factor table) or gathered (factor rows by index).",
+        ("side", "path"))
+
     def sweeps(uf, itf, n):
+        for side, (dense, gathered) in zip(("user", "item"),
+                                           inputs.gram_ratings):
+            gram_ratings.inc(float(dense) * n, side=side, path="dense")
+            gram_ratings.inc(float(gathered) * n, side=side,
+                             path="gathered")
         if warm_exe is not None:
             return warm_exe(uf, itf, ubk, ibk, reg, alpha, jnp.int32(n))
         return _train_loop(
@@ -1184,7 +1304,9 @@ def _expand_chunks(buckets, specs):
         return buckets  # pre-chunked (host/mesh path)
     out = []
     for arrs, spec in zip(buckets, specs):
-        if spec[0] == "plain":
+        if spec[0] == "dense":
+            out.append(arrs)
+        elif spec[0] == "plain":
             idx, vals, msk, rid = arrs
             chunks = spec[1]
             if len(chunks) <= 1:
@@ -1258,8 +1380,12 @@ def _resolve_loop_statics(config: ALSConfig, user_buckets, item_buckets,
         # gram → in-kernel-transposing solve → scatter), which removes
         # those copies (measured 250.4 → 187.8 ms/iter, copy phase
         # 47.7 → 0.5; before PR 1, on another installation).
-        # (A scalar-loop in-kernel gather measured 0.30 G rows/s — worse
-        # than XLA's own engine; don't go back there.)
+        # (A scalar-loop gather inside the kernel measured 0.30 G rows/s
+        # before PR 1, on another installation; XLA's own reads 0.41-0.46
+        # from a small table and 0.08-0.09 from als-netflix-r64's user
+        # table, one v5e, PR 29.  Neither is the lever: a row that rated
+        # enough of the other side skips the fetch, see the ``dense``
+        # kind in _train_loop.)
         use_pallas = one_chip
 
     def _bucket_pallas(idx) -> bool:
@@ -1319,6 +1445,18 @@ def _train_loop(uf0, itf0, user_buckets, item_buckets, reg, alpha, iterations,
         yty = gram(src) if implicit else jnp.zeros(
             (src.shape[1], src.shape[1]), jnp.float32)
         for kind, use_pallas, arrs in zip(side_kinds, side_pallas, buckets):
+            if kind == "dense":
+                # Rows dense enough over ``src``: a masked product over
+                # the whole table stands in for the gather.
+                block, ent, deg = arrs
+                a, b = fused_gram_dense(block, src.astype(gdt), alpha,
+                                        implicit=implicit,
+                                        use_pallas=use_pallas)
+                if implicit:
+                    a = yty[None, :, :] + a
+                dst = _scatter_rows(dst, ent, _ridge(
+                    a, b, reg * jnp.maximum(deg, 1.0), solver))
+                continue
             if kind.endswith("_w"):
                 # windowed chunk: fetch only the factor rows it touches
                 *arrs, win = arrs

@@ -19,8 +19,17 @@ Two pieces:
   into a flat [total_slots] buffer at a computed destination, then views
   per-bucket [R, L] blocks out of it.
 
-Semantics match ``bucket_by_length(...)`` exactly (same bucket bounds
-policy, same split-bucket segment layout, same within-row event order);
+Rows that rated a large enough share of the other side
+(``pallas_kernels.dense_row_density``) leave the padded buckets for a
+dense ``[J, n_src]`` block of values, NaN where there is no rating: the
+loop builds their normal equations by a masked product over the whole
+factor table instead of from gathered rows.  ``plan_buckets`` picks them
+from the degree histogram alone; a side with no such row plans and
+builds exactly as before.
+
+For the rest, semantics match ``bucket_by_length(...)`` exactly (same
+bucket bounds policy, same split-bucket segment layout, same within-row
+event order);
 ``tests/test_device_prep.py`` pins host-vs-device equivalence.
 Truncation (``max_len``) is NOT supported here — callers with
 ``max_degree`` set fall back to the host path.
@@ -38,9 +47,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from predictionio_tpu.obs import get_registry
+from predictionio_tpu.ops.pallas_kernels import (
+    DENSE_BLOCK_DTYPE, DENSE_TILE_R, dense_block_width, dense_row_density,
+)
 from predictionio_tpu.ops.ragged import LEN_ALIGN, _round_up, fit_bounds
 
-__all__ = ["BucketPlan", "plan_buckets", "build_buckets", "degree_histogram"]
+__all__ = ["BucketPlan", "plan_buckets", "build_buckets",
+           "build_dense_block", "degree_histogram"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,6 +77,14 @@ class BucketPlan:
     # split_chunks  = ((e0, e1, r0, r1), ...) at entity granularity.
     plain_chunks: Tuple[Tuple[Tuple[int, int], ...], ...] = ()
     split_chunks: Tuple[Tuple[int, int, int, int], ...] = ()
+    # Dense block (rows whose density over the source side passes
+    # ``dense_row_density``), all zero when the side has none: rows of
+    # degree >= dense_min leave the plain and split buckets for a
+    # [dense_rows, dense_block_width(dense_src)] block of values.
+    dense_min: int = 0               # smallest degree that goes dense
+    dense_rows: int = 0              # block rows, padded to DENSE_TILE_R
+    dense_src: int = 0               # source-side rows the block spans
+    dense_ratings: int = 0           # real ratings the block holds
 
     @property
     def row_starts(self) -> Tuple[int, ...]:
@@ -105,6 +126,60 @@ def degree_histogram(counts: jax.Array, cap: int) -> Tuple[np.ndarray, int, int]
     return np.asarray(hist), int(n_over), int(n_part)
 
 
+# A side's dense block may take this share of the device's memory limit
+# (one number a chip kind, so the plan stays a function of the degree
+# histogram and the chip).  Two sides could hold 40% of the chip; a side
+# whose table is small has a high ρ* and takes far less (als-netflix-r64:
+# 3.36 GB for the items over 480,189 users, 0.8 GB for the users).
+_DENSE_BUDGET_SHARE = 0.2
+_DEFAULT_MEMORY_LIMIT = 16 << 30   # a backend that reports none (CPU)
+
+
+def dense_budget_bytes() -> int:
+    """Bytes one side's dense block may take on this chip."""
+    stats = jax.devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit") or _DEFAULT_MEMORY_LIMIT
+    return int(limit * _DENSE_BUDGET_SHARE)
+
+
+def _select_dense(hist: np.ndarray, n_over: int,
+                  over_degrees: Optional[np.ndarray], n_src: int, rank: int,
+                  budget: int) -> Optional[Tuple[int, int, int]]:
+    """``(dense_min, rows, ratings)`` of the rows that go dense, or None.
+
+    One rule: a row goes dense iff ``degree / n_src`` reaches
+    ``dense_row_density(rank)``, densest first, while the block stays
+    within ``budget`` bytes.  Rows are told apart by degree alone (the
+    device program sees ``counts >= dense_min``), so a class of
+    equal-degree rows that straddles the budget stays out whole.
+    """
+    if n_over and over_degrees is None:
+        return None
+    cap = len(hist) - 1
+    rho = dense_row_density(rank, n_src)
+    if rho >= 1.0:
+        return None
+    d_rho = max(1, int(np.ceil(rho * n_src)))
+    row_bytes = dense_block_width(n_src) * np.dtype(DENSE_BLOCK_DTYPE).itemsize
+    j_max = budget // row_bytes // DENSE_TILE_R * DENSE_TILE_R
+    # Degrees the histogram resolves (under the cap), the rows of exactly
+    # the cap (its last bin less the over-cap rows), the over-cap rows.
+    under = np.arange(d_rho, cap)
+    at_cap = int(hist[cap]) - n_over if d_rho <= cap else 0
+    over = np.asarray(over_degrees if n_over else (), np.int64)
+    deg = np.sort(np.concatenate([np.repeat(under, hist[under]),
+                                  np.full(at_cap, cap),
+                                  over[over >= d_rho]]))[::-1]
+    dense_min = d_rho
+    if j_max and len(deg) > j_max:
+        cut = int(deg[j_max - 1])
+        dense_min = cut if deg[j_max] < cut else cut + 1
+        deg = deg[deg >= dense_min]
+    if not j_max or not len(deg):
+        return None
+    return dense_min, len(deg), int(deg.sum())
+
+
 def plan_buckets(
     hist: np.ndarray,
     n_over: int,
@@ -117,6 +192,8 @@ def plan_buckets(
     max_block_floats: Optional[int] = None,
     rank: int = 64,
     over_degrees: Optional[np.ndarray] = None,
+    n_src: Optional[int] = None,
+    dense_budget: Optional[int] = None,
 ) -> BucketPlan:
     """Degree histogram → static bucket layout (host-side, cheap).
 
@@ -125,9 +202,35 @@ def plan_buckets(
     several row chunks by the device program.  Chunking the split bucket
     additionally needs ``over_degrees`` — the degrees of the over-cap
     entities in entity-id order (a tiny D2H).
+
+    ``n_src`` (the other side's row count) lets rows that are dense
+    enough over it go to the dense block (:func:`_select_dense`); without
+    it, or with no such row, the plan is the all-sparse one.
+    ``dense_budget`` is the block's byte budget, the chip's share
+    (:func:`dense_budget_bytes`) unless given.
     """
     _t0 = time.perf_counter()
     pad_to = max(pad_rows_to, LEN_ALIGN)  # batch dim also sublane-aligned
+    dense = None
+    if n_src:
+        dense = _select_dense(
+            hist, n_over, over_degrees, n_src, rank,
+            dense_budget_bytes() if dense_budget is None else dense_budget)
+    if dense is not None:
+        # The dense rows leave the histogram; everything below plans the
+        # rows that are left exactly as it plans a side without them.
+        cap = len(hist) - 1
+        hist = np.array(hist, copy=True)
+        if dense[0] > cap:
+            over_degrees = np.asarray(over_degrees)
+            over_degrees = over_degrees[over_degrees < dense[0]]
+            hist[cap] -= n_over - len(over_degrees)
+            n_over = len(over_degrees)
+            n_part = int(((over_degrees.astype(np.int64) + cap - 1)
+                          // cap).sum())
+        else:
+            hist[dense[0]:] = 0
+            n_over = n_part = 0
     degrees = np.arange(len(hist))
     present = degrees[(hist > 0) & (degrees < len(hist))]
     counts_rep = np.repeat(present, hist[present])  # ≤ n_rows ints
@@ -203,6 +306,11 @@ def plan_buckets(
                       split_segs=split_segs, n_rows=n_rows,
                       pad_rows_to=pad_to, plain_chunks=plain_chunks,
                       split_chunks=split_chunks)
+    if dense is not None:
+        plan = dataclasses.replace(
+            plan, dense_min=dense[0],
+            dense_rows=_round_up(dense[1], DENSE_TILE_R),
+            dense_src=n_src, dense_ratings=dense[2])
     # Pipeline observability: planning cost + how much padded HBM the
     # device program will touch (ISSUE: make ALS prep attributable next
     # to the feeder/training gauges).
@@ -236,6 +344,8 @@ def build_buckets(
     Returns ``(plain, split)`` where ``plain`` is a list of
     ``(indices [R,L], values, mask, row_ids)`` per plan bucket and
     ``split`` is ``(indices, values, mask, seg_ids, ent_ids)`` or None.
+    The plan's dense rows are in neither: :func:`build_dense_block`
+    builds theirs.
     """
     n = rows.shape[0]
     n_rows = plan.n_rows
@@ -247,7 +357,13 @@ def build_buckets(
                                  ).astype(jnp.int32)
     n_plain = len(plan.bounds)
     is_split_row = counts > (plan.split_len or jnp.int32(2 ** 30))
+    if plan.dense_rows:
+        is_dense_row = counts >= plan.dense_min
+        is_split_row = is_split_row & ~is_dense_row
     bucket_of = jnp.where(is_split_row, n_plain, bucket_of)
+    if plan.dense_rows:
+        # Sorted after every other row, and their entries dropped below.
+        bucket_of = jnp.where(is_dense_row, n_plain + 1, bucket_of)
 
     # --- slot of each entity within its bucket (stable by id) --------
     order = jnp.argsort(bucket_of, stable=True)
@@ -324,6 +440,8 @@ def build_buckets(
     else:
         dest = plain_dest
         total_slots = total_plain
+    if plan.dense_rows:
+        dest = jnp.where(b_of_e > n_plain, total_slots, dest)  # dropped
 
     flat_idx = jnp.zeros(total_slots, jnp.int32).at[dest].set(
         cols, mode="drop")
@@ -385,3 +503,49 @@ def build_buckets(
                 ))
         split = tuple(split)
     return tuple(plain), split
+
+
+def _running_count(flags: jax.Array, block: int = 1024) -> jax.Array:
+    """Inclusive running count of a long bool vector, as two short scans:
+    XLA:TPU compiles one 480,189-long ``cumsum`` in 9 s and this in
+    0.4 s (libtpu 0.0.34, compiled for a described v5e, PR 29)."""
+    n = flags.shape[0]
+    rows = jnp.pad(flags.astype(jnp.int32), (0, (-n) % block)
+                   ).reshape(-1, block)
+    within = jnp.cumsum(rows, axis=1)
+    before = jnp.cumsum(within[:, -1]) - within[:, -1]
+    return (within + before[:, None]).reshape(-1)[:n]
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n_rows", "n_src", "dense_min", "dense_rows"))
+def build_dense_block(
+    rows: jax.Array,     # [N] int32 entity ids (this side)
+    cols: jax.Array,     # [N] int32 other-side ids
+    vals: jax.Array,     # [N] f32
+    *, n_rows: int, n_src: int, dense_min: int, dense_rows: int,
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """A plan's dense rows (``degree >= dense_min``, in id order) as
+    ``(block [J,W], ent_ids [J], degrees [J], filled)``: each row's
+    ratings by source id, NaN where it has none (and in the padding rows,
+    whose ``ent_ids`` are -1), and the number of slots that hold a rating.
+
+    ``filled`` falls short of the plan's ``dense_ratings`` when a dense
+    row's ratings repeat a (row, col) pair or hold a NaN: a block cannot
+    stand for those, and the caller plans the side again without dense
+    rows.
+    """
+    counts = jnp.zeros(n_rows, jnp.int32).at[rows].add(1)
+    is_dense_row = counts >= dense_min
+    slot = jnp.where(is_dense_row, _running_count(is_dense_row) - 1,
+                     dense_rows)
+    ent = jnp.arange(n_rows, dtype=jnp.int32)
+    ent_ids = jnp.full(dense_rows, -1, jnp.int32).at[slot].set(
+        ent, mode="drop")
+    degrees = jnp.zeros(dense_rows, jnp.float32).at[slot].set(
+        counts.astype(jnp.float32), mode="drop")
+    block = jnp.full((dense_rows, dense_block_width(n_src)), jnp.nan,
+                     DENSE_BLOCK_DTYPE).at[slot[rows], cols].set(
+        vals.astype(DENSE_BLOCK_DTYPE), mode="drop")
+    return (block, ent_ids, degrees,
+            jnp.sum((block == block).astype(jnp.int32)))

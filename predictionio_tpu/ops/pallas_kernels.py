@@ -31,6 +31,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["fused_gram_vector", "fused_gram_vector_pallas",
            "fused_gram_vector_xla", "pallas_supported",
+           "fused_gram_dense", "fused_gram_dense_pallas",
+           "fused_gram_dense_xla", "dense_weights", "dense_block_width",
+           "dense_row_density", "DENSE_TILE_R",
            "ridge_solve_gj_pallas", "ridge_solve_lu_pallas", "gj_fits_vmem",
            "fused_topk", "fused_topk_pallas", "fused_topk_tiles",
            "pq_scan", "pq_scan_pallas", "pq_scan_xla"]
@@ -59,6 +62,40 @@ def fits_vmem(l: int, k: int) -> bool:
     bounds the working set (the [TILE_R, k, k] f32 accumulator)."""
     del l
     return k <= 256
+
+
+# Where the dense product beats the gather: two rates, measured on one
+# TPU v5e at K = 64 (my chip runs, PR 29; PERF.md §5 retrain).
+# - A gathered factor row (XLA's gather, ``factors.astype(bf16)[indices]``)
+#   costs 2.17 ns where the table is als-netflix-r64's 17,770 items
+#   (2.3 MB in bf16) and 11.97 ns where it is its 480,189 users (61.5 MB),
+#   read from the cell's device trace, a sweep's ``fusion`` ops split at
+#   the side boundary.  Alone: 2.33-2.46 ns a row for tables of 17,770
+#   to 240,000 rows, 11.26 ns at 480,189 rows, sorted indices or not: the
+#   rate follows the table's bytes, with its step between 30.7 and
+#   61.5 MB.
+# - A source row of the dense kernel costs 0.094 ns in the loop at a row
+#   tile of 16 (2,192 rows over 480,189 in 98.2 ms, 57,232 over 17,770 in
+#   99.0 ms) and 0.089-0.093 at the row tile of 32 it has now (3,488 rows
+#   in 149.3 ms, 23,488 in 38.7 ms: 92 TFLOP/s of the 98.5 an output 64
+#   wide leaves of the MXU); alone 0.098-0.104.  The rule keeps 0.094.
+_GATHER_NS_PER_ROW = (2.2, 12.0)     # table within / over the bytes below
+_GATHER_SMALL_TABLE_BYTES = 32 << 20
+_DENSE_NS_PER_SRC_ROW_K64 = 0.094
+
+
+def dense_row_density(rank: int, n_src: int) -> float:
+    """ρ*: the share of the ``n_src`` source rows a row must have rated
+    for the masked product over the whole table to cost less than
+    gathering its rows.  The product's cost a source row grows with K²;
+    the gather's cost a row follows the table's size (in the gram dtype,
+    bf16), not the rank.  Past rank 128 the dense kernel's float32
+    accumulators do not fit VMEM and no row is dense enough."""
+    if rank > 128:
+        return float("inf")
+    small = n_src * rank * 2 <= _GATHER_SMALL_TABLE_BYTES
+    return _DENSE_NS_PER_SRC_ROW_K64 * (rank / 64.0) ** 2 \
+        / _GATHER_NS_PER_ROW[0 if small else 1]
 
 
 def fused_gram_vector_xla(f: jax.Array, w: jax.Array, c: jax.Array
@@ -178,6 +215,159 @@ def fused_gram_vector_pallas(f: jax.Array, w: jax.Array, c: jax.Array,
         interpret=interpret,
     )(f, w.astype(jnp.float32), c.astype(jnp.float32))
     return a[:r], b[:r]
+
+
+# ---------------------------------------------------------------------------
+# Dense normal equations: a row that rated a large enough share of the
+# other side builds ``A = (Xᵀ ∘ w) X``, ``b = c X`` as a masked product
+# over the WHOLE factor table ``X [N, K]`` instead of from gathered rows.
+# The v5e fetches rows by index at a fixed cost a row whatever does it;
+# the MXU streams the table at a fixed cost a SOURCE row whatever the
+# row's degree, so above a density ``degree / N`` the product is the
+# cheaper fetch (:func:`dense_row_density`).  Same operands as the
+# gathered path — table in ``gram_dtype``, ``w·x`` rounded to it, float32
+# accumulation — so nothing is approximated; only the order of the sum
+# differs.
+#
+# The row's ratings arrive as one row of a ``[J, N]`` block of
+# ``DENSE_BLOCK_DTYPE`` values, NaN where the row has no rating (a real
+# rating of 0.0 is a zero, not an absence).  ops/device_prep.py builds the
+# block once and only from ratings that are exact in that dtype, so the
+# ``w`` and ``c`` derived here are the gathered path's, bit for bit.
+# ---------------------------------------------------------------------------
+
+DENSE_BLOCK_DTYPE = jnp.bfloat16
+DENSE_TILE_R = 32        # block rows per program: M = 32·K streamed LHS rows
+_DENSE_TILE_SRC = 2048   # source rows per grid step (contraction depth)
+
+
+def dense_src_tile(n_src: int, rank: int = 64) -> int:
+    """Source rows one grid step of the dense kernel contracts over: the
+    stacked ``[TILE_R·K, TU]`` operand is held at 8 MB of bf16."""
+    return min(_DENSE_TILE_SRC * 64 // max(rank, 64), _lane_pad(n_src))
+
+
+def dense_block_width(n_src: int) -> int:
+    """Columns of a dense block over ``n_src`` source rows: padded to the
+    kernel's largest source tile (every rank's tile divides it once the
+    side is longer than one), so the loop never pads the block itself."""
+    tu = dense_src_tile(n_src)
+    return -(-n_src // tu) * tu
+
+
+def dense_weights(vals: jax.Array, alpha, implicit: bool
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """``(w, c)`` in float32 from block values (NaN = no rating): what
+    ``models.als._gram_pieces`` derives from ``values`` and ``mask``."""
+    vals = vals.astype(jnp.float32)
+    present = vals == vals
+    v = jnp.where(present, vals, 0.0)
+    if implicit:
+        w = alpha * jnp.abs(v)
+        return w, (1.0 + w) * (v > 0).astype(jnp.float32)
+    return present.astype(jnp.float32), v
+
+
+def fused_gram_dense_xla(block: jax.Array, x: jax.Array, alpha, *,
+                         implicit: bool) -> Tuple[jax.Array, jax.Array]:
+    """XLA twin of :func:`fused_gram_dense_pallas` (CPU, tests):
+    ``block [J, ≥N]``, ``x [N, K]`` already in the gram dtype.  A row
+    tile at a time, so the weighted table it forms is ``[TILE_R, K, N]``
+    whatever ``J`` is."""
+    n, _ = x.shape
+    xt = x.T
+
+    def rows(vals):                                           # [N]
+        w, c = dense_weights(vals, alpha, implicit)
+        lhs = xt * w[None, :].astype(x.dtype)                 # [K, N]
+        return (jnp.dot(lhs, x, preferred_element_type=jnp.float32),
+                jnp.dot(c.astype(x.dtype), x,
+                        preferred_element_type=jnp.float32))
+
+    return jax.lax.map(rows, block[:, :n], batch_size=DENSE_TILE_R)
+
+
+def _gram_dense_kernel(alpha_ref, blk_ref, xt_ref, x_ref, a_ref, b_ref,
+                       lhs_ref, *, implicit: bool):
+    """One (row tile, source tile) step: the tile's ``K`` transposed
+    table rows, weighted per block row on the VPU into one stacked
+    ``[TILE_R·K, TU]`` operand, then ONE product against the table tile
+    — the tile is the MXU's stationary operand for every row of the row
+    tile — accumulated in float32 in the output blocks, which stay in
+    VMEM across the source axis."""
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        a_ref[:] = jnp.zeros_like(a_ref)
+        b_ref[:] = jnp.zeros_like(b_ref)
+
+    k = xt_ref.shape[0]
+    gdt = x_ref.dtype
+    w, c = dense_weights(blk_ref[:], alpha_ref[0], implicit)
+    xt = xt_ref[:]                                    # [K, TU]
+    for r in range(DENSE_TILE_R):
+        # Sublane broadcast of the row's weights over the K table rows,
+        # rounded to the gram dtype as the gathered kernel rounds ``fw``.
+        lhs_ref[r * k:(r + 1) * k, :] = xt * w[r:r + 1, :].astype(gdt)
+    x = x_ref[:]                                      # [TU, K]
+    a_ref[:] += jnp.dot(lhs_ref[:], x, preferred_element_type=jnp.float32)
+    b_ref[:] += jnp.dot(c.astype(gdt), x,
+                        preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("implicit", "interpret"))
+def fused_gram_dense_pallas(block: jax.Array, x: jax.Array, alpha, *,
+                            implicit: bool, interpret: bool = False
+                            ) -> Tuple[jax.Array, jax.Array]:
+    """``A [J,K,K]``, ``b [J,K]`` of ``J`` dense rows over the whole
+    table ``x [N, K]`` (already in the gram dtype).
+
+    ``block [J, W]`` with ``W ≥ N``; columns from ``N`` on must be NaN.
+    A block that prep padded (``dense_block_width`` columns, a multiple
+    of ``DENSE_TILE_R`` rows) is taken as it is; any other is padded
+    here with absent slots.  The table is zero-padded to the block's
+    width so an absent slot meets a zero, never an over-read."""
+    j, width = block.shape
+    n, k = x.shape
+    tu = dense_src_tile(width, k)
+    wp = -(-width // tu) * tu
+    jp = -(-j // DENSE_TILE_R) * DENSE_TILE_R
+    if (jp, wp) != (j, width):
+        block = jnp.pad(block, ((0, jp - j), (0, wp - width)),
+                        constant_values=jnp.nan)
+    xp = jnp.pad(x, ((0, wp - n), (0, 0)))
+    kernel = functools.partial(_gram_dense_kernel, implicit=implicit)
+    a, b = pl.pallas_call(
+        kernel,
+        grid=(jp // DENSE_TILE_R, wp // tu),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((DENSE_TILE_R, tu), lambda i, t: (i, t)),
+            pl.BlockSpec((k, tu), lambda i, t: (0, t)),
+            pl.BlockSpec((tu, k), lambda i, t: (t, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((DENSE_TILE_R * k, k), lambda i, t: (i, 0)),
+            pl.BlockSpec((DENSE_TILE_R, k), lambda i, t: (i, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((jp * k, k), jnp.float32),
+            jax.ShapeDtypeStruct((jp, k), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((DENSE_TILE_R * k, tu), x.dtype)],
+        interpret=interpret,
+    )(jnp.asarray(alpha, jnp.float32).reshape(1), block, xp.T, xp)
+    return a.reshape(jp, k, k)[:j], b[:j]
+
+
+def fused_gram_dense(block: jax.Array, x: jax.Array, alpha, *,
+                     implicit: bool, use_pallas: bool
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """Dispatch as the loop decides it: the kernel (compiled on a TPU,
+    interpreted elsewhere) or the XLA twin."""
+    if use_pallas:
+        return fused_gram_dense_pallas(block, x, alpha, implicit=implicit,
+                                       interpret=not pallas_supported())
+    return fused_gram_dense_xla(block, x, alpha, implicit=implicit)
 
 
 # ---------------------------------------------------------------------------

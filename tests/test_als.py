@@ -387,3 +387,128 @@ def test_host_layout_rows_sublane_aligned():
     granule = math.lcm(LEN_ALIGN, 3)
     for b in (*inp2.user_buckets, *inp2.item_buckets):
         assert b[1].shape[0] % granule == 0, (b[0], b[1].shape)
+
+
+# ---------------------------------------------------------------------------
+# Dense rows: a whole train with them against the same train planned
+# all-sparse, and the counter that says how often the dense path engages.
+# ---------------------------------------------------------------------------
+
+def _dense_toy(seed=0, n_users=60, n_items=40):
+    """Whole-number ratings, no repeated pair, a dense head on both
+    sides over a sparse rest (``_toy``'s ratings are not values a dense
+    block holds exactly, so they plan all-sparse)."""
+    rng = np.random.default_rng(seed)
+    mask = rng.random((n_users, n_items)) < 0.1
+    mask[:6] |= rng.random((6, n_items)) < 0.8
+    mask[:, :5] |= rng.random((n_users, 5)) < 0.8
+    users, items = np.nonzero(mask)
+    return (users.astype(np.int32), items.astype(np.int32),
+            rng.integers(1, 6, len(users)).astype(np.float32))
+
+
+@pytest.mark.parametrize("use_pallas", [None, True],
+                         ids=["xla-twin", "interpreter"])
+@pytest.mark.parametrize("implicit", [False, True],
+                         ids=["explicit", "implicit"])
+def test_train_with_dense_rows_matches_all_sparse_plan(implicit, use_pallas):
+    from predictionio_tpu.models.als import (
+        _prepare_als_inputs_device, train_als_prepared,
+    )
+
+    users, items, ratings = _dense_toy()
+    cfg = ALSConfig(rank=4, iterations=3, reg=0.05, seed=11,
+                    gram_dtype="float32", implicit=implicit, alpha=0.5,
+                    split_above=16, use_pallas=use_pallas)
+    dense = _prepare_als_inputs_device(users, items, ratings, 60, 40, cfg)
+    sparse = _prepare_als_inputs_device(users, items, ratings, 60, 40, cfg,
+                                        dense=False)
+    assert "dense" in {b[0] for b in dense.user_buckets}
+    assert "dense" in {b[0] for b in dense.item_buckets}
+    assert "dense" not in {b[0] for b in
+                           sparse.user_buckets + sparse.item_buckets}
+    m_dense = train_als_prepared(dense, cfg)
+    m_sparse = train_als_prepared(sparse, cfg)
+    np.testing.assert_allclose(np.asarray(m_dense.user_factors),
+                               np.asarray(m_sparse.user_factors),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(m_dense.item_factors),
+                               np.asarray(m_sparse.item_factors),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("device_prep", [True, False],
+                         ids=["device-prep", "host-prep"])
+def test_gram_ratings_counter_adds_up_to_ratings_times_sweeps(device_prep):
+    from predictionio_tpu.models.als import (
+        prepare_als_inputs, train_als_prepared,
+    )
+    from predictionio_tpu.obs import get_registry
+
+    users, items, ratings = _dense_toy(seed=3)
+    cfg = ALSConfig(rank=4, iterations=3, reg=0.05, seed=11,
+                    device_prep=device_prep, split_above=16)
+    inputs = prepare_als_inputs(users, items, ratings, 60, 40, cfg)
+    counter = get_registry().counter(
+        "pio_als_gram_ratings_total", "", ("side", "path"))
+
+    def read():
+        return {(s, p): counter.value(side=s, path=p)
+                for s in ("user", "item") for p in ("dense", "gathered")}
+
+    before = read()
+    train_als_prepared(inputs, cfg)
+    grown = {k: v - before[k] for k, v in read().items()}
+    for side in ("user", "item"):
+        assert grown[side, "dense"] + grown[side, "gathered"] \
+            == len(users) * cfg.iterations
+        # device prep on this matrix plans dense rows; host prep none
+        assert (grown[side, "dense"] > 0) == device_prep
+
+
+def test_benchmark_reads_the_dense_share_and_the_dense_kernel():
+    """The two per-layer metrics this kind brought, through the readers
+    the benchmark already had: the counter's dense share, and the dense
+    kernel's device time under a name ``als_gram_roofline``'s pattern
+    also matches; nothing on a program that has neither."""
+    import types
+
+    from benchmark import manifest, prom, trace_reduce
+    from benchmark.readers import op_ms_per_unit, prom_ratio
+    from predictionio_tpu.models.als import (
+        prepare_als_inputs, train_als_prepared,
+    )
+
+    users, items, ratings = _dense_toy(seed=5)
+    cfg = ALSConfig(rank=4, iterations=2, reg=0.05, seed=11,
+                    device_prep=True, split_above=16)
+    inputs = prepare_als_inputs(users, items, ratings, 60, 40, cfg)
+    before = prom.snapshot()
+    train_als_prepared(inputs, cfg)
+    ctx = {"before": before, "after": prom.snapshot()}
+    share = prom_ratio.read(ctx, **manifest.layer_metric_spec(
+        "als_dense_rating_pct")["args"])
+    (du, gu), (di, gi) = inputs.gram_ratings
+    assert du > 0 and di > 0
+    assert share == pytest.approx(100.0 * (du + di) / (du + gu + di + gi))
+    assert prom_ratio.read({"before": {}, "after": {}},
+                           **manifest.layer_metric_spec(
+                               "als_dense_rating_pct")["args"]) is None
+
+    spec = manifest.layer_metric_spec("als_dense_gram_ms")
+    window = types.SimpleNamespace(extras={"sweeps": 5})
+    reduced = {"op_s": {"fused_gram_dense_pallas": 0.5,
+                        "fused_gram_vector_pallas": 0.1, "fusion": 2.0}}
+    assert op_ms_per_unit.read({"trace": reduced, "window": window},
+                               **spec["args"]) == pytest.approx(100.0)
+    assert op_ms_per_unit.read(
+        {"trace": {"op_s": {"fused_gram_vector_pallas": 0.1}},
+         "window": window}, **spec["args"]) is None
+    gram = manifest.layer_metric_spec("als_gram_roofline")["args"]["pattern"]
+    assert trace_reduce.kernel_seconds(reduced, gram) == pytest.approx(0.6)
+    for name in ("als_dense_rating_pct", "als_dense_gram_ms"):
+        (entry,) = [m for m in manifest.load()["per_layer"]
+                    if m["name"] == name]
+        assert entry["workloads"] == ["als-netflix-r64.retrain"]
+        assert (entry["layer"], entry["moves"]) == ("ALS kernels",
+                                                    "rating_iters_per_s")

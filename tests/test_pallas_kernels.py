@@ -259,3 +259,143 @@ def test_fused_topk_dispatcher_cpu_falls_back_to_chunked():
     # k=0 / k>n edge behavior mirrors the facade contract
     s0, i0 = fused_topk(jnp.asarray(q), jnp.asarray(items), 0)
     assert s0.shape == (3, 0) and i0.shape == (3, 0)
+
+
+# ---------------------------------------------------------------------------
+# Dense normal equations: the masked product over the whole factor table
+# against its XLA twin and against the gathered path on the same ratings.
+# ---------------------------------------------------------------------------
+
+def _dense_case(n_src, rows, seed=0, k=8, with_zero=True):
+    """The same ratings twice: as padded gathered rows (``_gram_pieces``'
+    input) and as a dense block, NaN where a row has no rating."""
+    from predictionio_tpu.ops.pallas_kernels import DENSE_BLOCK_DTYPE
+
+    rng = np.random.default_rng(seed)
+    x = rng.standard_normal((n_src, k)).astype(np.float32)
+    length = max(2, n_src // 3)
+    idx = np.stack([rng.choice(n_src, length, replace=False)
+                    for _ in range(rows)]).astype(np.int32)
+    vals = rng.integers(0 if with_zero else 1, 6,
+                        (rows, length)).astype(np.float32)
+    if with_zero:
+        vals[:, 0] = 0.0           # a real rating of 0.0 in every row
+    mask = rng.random((rows, length)) < 0.8
+    mask[:, 0] = True
+    block = np.full((rows, n_src), np.nan, np.float32)
+    for r in range(rows):
+        block[r, idx[r][mask[r]]] = vals[r][mask[r]]
+    return (jnp.asarray(x), jnp.asarray(idx), jnp.asarray(vals),
+            jnp.asarray(mask), jnp.asarray(block).astype(DENSE_BLOCK_DTYPE))
+
+
+@pytest.mark.parametrize("implicit", [False, True],
+                         ids=["explicit", "implicit"])
+@pytest.mark.parametrize("n_src,rows", [
+    (256, 16),     # whole tiles
+    (300, 5),      # source length no tile multiple, row tile with padding
+    (2500, 19),    # two source tiles, two row tiles, both padded
+], ids=["whole-tiles", "ragged-one-tile", "ragged-two-tiles"])
+def test_dense_gram_matches_twin_and_gathered_path(n_src, rows, implicit):
+    from predictionio_tpu.models.als import _gram_pieces
+    from predictionio_tpu.ops.pallas_kernels import (
+        fused_gram_dense_pallas, fused_gram_dense_xla,
+    )
+
+    x, idx, vals, mask, block = _dense_case(n_src, rows, seed=n_src)
+    alpha = jnp.float32(0.7)
+    a_k, b_k = fused_gram_dense_pallas(block, x, alpha, implicit=implicit,
+                                       interpret=True)
+    a_x, b_x = fused_gram_dense_xla(block, x, alpha, implicit=implicit)
+    assert a_k.shape == (rows, x.shape[1], x.shape[1])
+    np.testing.assert_allclose(np.asarray(a_k), np.asarray(a_x),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(b_k), np.asarray(b_x),
+                               rtol=1e-4, atol=1e-4)
+    a_g, b_g, deg = _gram_pieces(idx, vals, mask, x, alpha, implicit, True,
+                                 jnp.float32)
+    np.testing.assert_allclose(np.asarray(a_k), np.asarray(a_g),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(b_k), np.asarray(b_g),
+                               rtol=1e-4, atol=1e-4)
+    assert np.array_equal(np.asarray(deg), np.asarray(mask).sum(1))
+
+
+@pytest.mark.parametrize("implicit", [False, True],
+                         ids=["explicit", "implicit"])
+def test_dense_weights_are_the_gathered_paths_bit_for_bit(implicit):
+    """``w`` and ``c`` from a block value equal what ``_gram_pieces``
+    derives from the rating itself, after the kernels' rounding to the
+    gram dtype — a rating of 0.0 included, an absent slot zero in both."""
+    from predictionio_tpu.ops.pallas_kernels import (
+        DENSE_BLOCK_DTYPE, dense_weights,
+    )
+
+    vals = jnp.asarray([0.0, 0.5, 1.0, 3.0, 4.5, 5.0, -2.0, 96.0],
+                       jnp.float32)
+    alpha = jnp.float32(0.3)
+    if implicit:
+        w = alpha * jnp.abs(vals)
+        c = (1.0 + w) * (vals > 0).astype(jnp.float32)
+    else:
+        w, c = jnp.ones_like(vals), vals
+    block = jnp.concatenate([vals, jnp.asarray([jnp.nan])]
+                            ).astype(DENSE_BLOCK_DTYPE)
+    w_d, c_d = dense_weights(block, alpha, implicit)
+    for got, want in ((w_d, w), (c_d, c)):
+        got, want = (np.asarray(v.astype(jnp.bfloat16).astype(jnp.float32))
+                     for v in (got, want))
+        assert np.array_equal(got[:-1], want)
+        assert got[-1] == 0.0
+
+
+def test_dense_gram_rounds_the_weighted_table_to_the_gram_dtype():
+    """bf16 operands: ``w·x`` is rounded to bf16 before the product, as
+    the gathered kernel rounds ``fw``; a numpy model of that rounding is
+    the oracle (XLA on the CPU may keep excess precision elsewhere)."""
+    from predictionio_tpu.ops.pallas_kernels import fused_gram_dense_pallas
+
+    x, idx, vals, mask, block = _dense_case(384, 4, seed=9)
+    xb = x.astype(jnp.bfloat16)
+    alpha = jnp.float32(0.7)
+    a_k, _ = fused_gram_dense_pallas(block, xb, alpha, implicit=True,
+                                     interpret=True)
+    xf = np.asarray(xb.astype(jnp.float32))
+    want = np.zeros((4, 8, 8))
+    for r in range(4):
+        w = np.float32(0.7) * np.abs(np.asarray(vals[r])) * np.asarray(mask[r])
+        wb = np.asarray(jnp.asarray(w).astype(jnp.bfloat16)
+                        .astype(jnp.float32))
+        f = xf[np.asarray(idx[r])]
+        fw = np.asarray(jnp.asarray(f * wb[:, None]).astype(jnp.bfloat16)
+                        .astype(jnp.float32))
+        want[r] = fw.T.astype(np.float64) @ f
+    np.testing.assert_allclose(np.asarray(a_k), want, rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("rank,n_src,width,tile", [
+    (64, 17_770, 18_432, 2048), (64, 480_189, 481_280, 2048),
+    (128, 480_189, 481_280, 1024), (8, 15, 128, 128),
+], ids=["netflix-items", "netflix-users", "rank128", "toy"])
+def test_dense_tiles_and_block_width(rank, n_src, width, tile):
+    from predictionio_tpu.ops.pallas_kernels import (
+        dense_block_width, dense_src_tile,
+    )
+
+    assert dense_block_width(n_src) == width
+    assert dense_src_tile(width, rank) == tile
+    assert width % tile == 0
+
+
+def test_dense_row_density_follows_the_rank_and_the_table():
+    from predictionio_tpu.ops.pallas_kernels import dense_row_density
+
+    n = 17_770
+    assert 0.0 < dense_row_density(64, n) < 0.1
+    assert dense_row_density(128, n) == pytest.approx(
+        4 * dense_row_density(64, n))
+    assert dense_row_density(32, n) == pytest.approx(
+        dense_row_density(64, n) / 4)
+    assert dense_row_density(256, n) == float("inf")
+    # a table too large for the fast gather: rows go dense far earlier
+    assert dense_row_density(64, 480_189) < dense_row_density(64, n) / 4

@@ -3,10 +3,11 @@
 
 events in the store -> ``pio train`` -> ``pio deploy`` -> ``/queries.json``
 at the repo's north-star width: explicit ALS-WR, rank 64, MovieLens-25M
-shape (162,541 users x 59,047 items x 25M ratings, the seeded generator of
-``bench.py``), plus every Pallas kernel of ``ops/pallas_kernels.py``
-compiled by Mosaic and checked against its XLA twin, and a 2.5M x 64
-corpus (640 MB on the chip) served through the retrieval facade.
+shape (162,541 users x 59,047 items x 25M ratings from ``synth_ratings``:
+uniform users, Zipf(1.25) items, half-star ratings, seeded), plus every
+Pallas kernel of ``ops/pallas_kernels.py`` compiled by Mosaic and checked
+against its XLA twin, and a 2.5M x 64 corpus (640 MB on the chip) served
+through the retrieval facade.
 
 One parent that never imports jax; children that each own the chip in
 turn, every one started with ``JAX_PLATFORMS=tpu`` so a missing chip is
@@ -94,7 +95,7 @@ def check(cond: bool, what: str) -> None:
 
 
 # --------------------------------------------------------------------------
-# data (bench.py's generator, seeded)
+# data (seeded)
 # --------------------------------------------------------------------------
 
 def synth_ratings(seed: int, n_users: int, n_items: int, n: int):
@@ -613,7 +614,7 @@ def run_kernels(args) -> int:
         worst_rel_err=f"{worst[False]:.2e}/{worst[True]:.2e}",
         tol="1e-4/5e-3")
 
-    # -- LU and GJ solvers against the Cholesky branch of _ridge.
+    # -- the LU solver against the Cholesky branch of _ridge.
     nb = sizes["lu_batch"]
     y = jnp.asarray(rng.standard_normal((nb, 2 * RANK, RANK)) / 8,
                     jnp.float32)
@@ -623,17 +624,15 @@ def run_kernels(args) -> int:
     reg = jnp.asarray(0.01 * rng.integers(1, 200, nb), jnp.float32)
     cholesky = jax.jit(lambda a, b, r: twin(_ridge)(a, b, r, "cholesky"))
     ref = cholesky(amat, bvec, reg)
-    twin_ms = median_ms(lambda: cholesky(amat, bvec, reg))
-    for name, fn in (("lu", pk.ridge_solve_lu_pallas),
-                     ("gj", pk.ridge_solve_gj_pallas)):
-        x = fn(amat, bvec, reg, interpret=interpret)
-        check(bool(jnp.isfinite(x).all()), f"{name}: non-finite output")
-        err = rel_err(x, ref)
-        check(err <= 1e-3, f"{name} solver off by {err:.2e} vs Cholesky")
-        say(name, batch=nb, rank=RANK, twin="_ridge(cholesky)",
-            worst_rel_err=f"{err:.2e}", tol="1e-3",
-            ms=median_ms(lambda: fn(amat, bvec, reg, interpret=interpret)),
-            twin_ms=twin_ms)
+    x = pk.ridge_solve_lu_pallas(amat, bvec, reg, interpret=interpret)
+    check(bool(jnp.isfinite(x).all()), "lu: non-finite output")
+    err = rel_err(x, ref)
+    check(err <= 1e-3, f"lu solver off by {err:.2e} vs Cholesky")
+    say("lu", batch=nb, rank=RANK, twin="_ridge(cholesky)",
+        worst_rel_err=f"{err:.2e}", tol="1e-3",
+        ms=median_ms(lambda: pk.ridge_solve_lu_pallas(
+            amat, bvec, reg, interpret=interpret)),
+        twin_ms=median_ms(lambda: cholesky(amat, bvec, reg)))
 
     # -- the large corpus: 2.5M x 64 float32 resident on the chip.
     n = sizes["corpus"]
@@ -917,7 +916,7 @@ def main(argv=None) -> int:
                 f"device_kind={d['kind']} devices={d['count']} "
                 f"wall_s={kern['wall_s']} compile_s={kern['compile_s']} "
                 f"(cache hits {kern['cache_hits']}/{kern['compiles']}) "
-                f"pallas={kern['pallas']}: gram, gram_dense, lu, gj, "
+                f"pallas={kern['pallas']}: gram, gram_dense, lu, "
                 f"fused_topk, pq_scan each within tolerance of its XLA twin")
         ok = True
     except SmokeFailure as e:

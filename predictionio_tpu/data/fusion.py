@@ -1,9 +1,9 @@
 """K-step fusion plan + the HBM-guided fusion/batch autotuner.
 
-BENCH_r06's attribution left one dominant residual: after the PR-5
-prefetched pipeline closed serialized H2D, the feeder-vs-realized gap is
-~99% ``device_wait`` — the per-step jit dispatch/sync cadence itself.
-The fix is to fuse K optimizer steps into ONE XLA dispatch
+Once the prefetched pipeline (``data/prefetch.py``) has taken serialized
+H2D off the step loop, what is left of the feeder-vs-realized gap is
+``device_wait`` — the per-step jit dispatch/sync cadence itself.  The
+fix is to fuse K optimizer steps into ONE XLA dispatch
 (``lax.scan`` over a K-batch superbatch staged by
 :class:`~predictionio_tpu.data.prefetch.DevicePrefetcher`), which this
 module configures and — in ``auto`` mode — tunes:

@@ -1,11 +1,9 @@
 """Overlapped input pipeline: background batch prep + device prefetch.
 
-BENCH_r05 measured realized training throughput far below what the feeder
-delivers (45.9% pipeline gap for two-tower, 87.0% for DLRM) and the PR-3
-attribution (`tools/attribute_gap.py`) pinned the serialized host work:
-every step paid tail-batch padding, dtype conversion, and the H2D
-transfer **between** device steps, on the main thread, after blocking on
-step N-1.  :class:`DevicePrefetcher` moves that whole stage off the step
+A plain step loop serializes host work with the device: every step pays
+tail-batch padding, dtype conversion, and the H2D transfer **between**
+device steps, on the main thread, after blocking on step N-1 (the
+``host_wait`` / ``h2d`` phases of ``obs/pipeline.py``).  :class:`DevicePrefetcher` moves that whole stage off the step
 loop:
 
 - a background **prep thread** pulls raw batches from the host iterator

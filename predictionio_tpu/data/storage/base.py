@@ -540,6 +540,7 @@ class Events(abc.ABC):
         Default implementation converts the iterator; columnar backends
         override with a zero-copy path.
         """
+        check_event_columns(columns)
         table = events_to_arrow(
             self.find(
                 app_id,
@@ -679,6 +680,17 @@ def events_to_arrow(events: Iterable[Event]) -> pa.Table:
     return pa.table(cols, schema=EVENT_ARROW_SCHEMA)
 
 
+def check_event_columns(columns: Optional[Sequence[str]]) -> None:
+    """``find_columnar``'s ``columns`` must name :data:`EVENT_ARROW_SCHEMA`
+    fields: an unknown one is the caller's error, said as such."""
+    known = {f.name for f in EVENT_ARROW_SCHEMA}
+    unknown = [c for c in columns or () if c not in known]
+    if unknown:
+        raise StorageError(
+            f"find_columnar: unknown column(s) {unknown}; "
+            f"the event schema has {sorted(known)}")
+
+
 def normalize_event_table(table: pa.Table) -> pa.Table:
     """Validate/complete a caller-supplied columnar event batch against
     :data:`EVENT_ARROW_SCHEMA` for :meth:`Events.insert_columnar`.
@@ -715,6 +727,16 @@ def normalize_event_table(table: pa.Table) -> pa.Table:
             return col
         return col.cast(typ)
 
+    # creation time as every row will carry it: given, else the server
+    # clock.  event_time_us (earlier in the schema) defaults to it.
+    import pyarrow.compute as pc
+
+    if "creation_time_us" in names:
+        creation = pc.fill_null(
+            table.column("creation_time_us").cast(pa.int64()), now_us)
+    else:
+        creation = pa.chunked_array([np.full(n, now_us, np.int64)])
+
     cols = []
     for field in EVENT_ARROW_SCHEMA:
         if field.name == "event_id":
@@ -725,40 +747,27 @@ def normalize_event_table(table: pa.Table) -> pa.Table:
             if field.name in names:
                 col = _conform(table.column(field.name), field.type)
                 if col.null_count:
-                    import pyarrow.compute as pc
-
                     if pa.types.is_dictionary(col.type):
                         col = col.cast(field.type)  # rare: nulls in dict col
                     col = pc.fill_null(col, "{}")
                 cols.append(col)
             else:
                 cols.append(pa.repeat(pa.scalar("{}", field.type), n))
-        elif field.name in names:
-            col = _conform(table.column(field.name), field.type)
-            if field.name in ("event_time_us", "creation_time_us") \
-                    and col.null_count:
-                # per-row default, same rule as the missing-column case:
-                # an event without an explicit time gets the server clock
-                import pyarrow.compute as pc
-
-                col = pc.fill_null(col, now_us)
-            cols.append(col)
         elif field.name == "creation_time_us":
-            cols.append(pa.array(np.full(n, now_us, np.int64)))
+            cols.append(creation)
         elif field.name == "event_time_us":
-            # defaults to creation time, whether that column was given;
-            # null creation rows take the server clock too (the null must
-            # not leak into event_time_us — sqlite's eventtime is NOT
-            # NULL and readers assume every Event has a time)
-            if "creation_time_us" in names:
-                import pyarrow.compute as pc
-
-                ct = pc.fill_null(
-                    table.column("creation_time_us").cast(pa.int64()),
-                    now_us)
+            # per row, the same rule whether the column is missing or the
+            # value null: the row's creation time (a null must not leak —
+            # sqlite's eventtime is NOT NULL and readers assume every
+            # Event has a time)
+            if field.name in names:
+                cols.append(pc.coalesce(
+                    _conform(table.column(field.name), field.type),
+                    creation))
             else:
-                ct = pa.array(np.full(n, now_us, np.int64))
-            cols.append(ct)
+                cols.append(creation)
+        elif field.name in names:
+            cols.append(_conform(table.column(field.name), field.type))
         else:
             cols.append(pa.nulls(n, field.type))
     fields = [pa.field(f.name, col.type, nullable=True)

@@ -395,6 +395,7 @@ class ParquetEvents(base.Events):
         ordered: bool = True,
         columns: Optional[Sequence[str]] = None,
     ) -> pa.Table:
+        base.check_event_columns(columns)
         return self._filtered(
             app_id, channel_id, start_time, until_time, entity_type, entity_id,
             event_names, target_entity_type, target_entity_id,

@@ -823,6 +823,7 @@ class SQLiteEvents(_Repo, base.Events):
         ``ordered=False`` drops the ORDER BY (training scans don't need
         time order and the sort is O(N log N) in sqlite).
         """
+        base.check_event_columns(columns)
         self._check_init(app_id, channel_id)
         where, params = self._where(
             app_id, channel_id, start_time, until_time, entity_type, entity_id,

@@ -47,9 +47,8 @@ from predictionio_tpu.ops.pallas_kernels import (
     fits_vmem,
     fused_gram_dense,
     fused_gram_vector_pallas,
-    gj_fits_vmem,
+    lanes_solve_fits_vmem,
     pallas_supported,
-    ridge_solve_gj_pallas,
     ridge_solve_lu_pallas,
 )
 from predictionio_tpu.ops.ragged import LEN_ALIGN, Padded, bucket_by_length
@@ -94,9 +93,9 @@ class ALSConfig:
     # numpy-oracle exactness).
     gram_dtype: str = "auto"
     # Normal-equation solver: "auto" = the Pallas shrinking-elimination
-    # kernel ("lu") on TPU — the XLA batched Cholesky was the single
-    # largest cost of an iteration and full Gauss-Jordan 1.4x slower
-    # than LU — Cholesky elsewhere.  "cholesky"/"gj"/"lu" force a path.
+    # kernel ("lu") on one TPU chip at a rank whose working set fits
+    # VMEM — the XLA batched Cholesky was the single largest cost of an
+    # iteration there — Cholesky elsewhere.  "cholesky"/"lu" force a path.
     solver: str = "auto"
     use_pallas: Optional[bool] = None  # None = auto (on for single-chip TPU)
     # HBM guard: cap the gathered [rows, L, K] block at this many floats;
@@ -267,19 +266,14 @@ def _ridge(a: jax.Array, b: jax.Array, reg_vec: jax.Array,
            solver: str = "cholesky") -> jax.Array:
     """Batched SPD solve ``(A + diag(reg)) x = b``.
 
-    ``gj`` = the Pallas Gauss-Jordan kernel — on v5e the XLA batched
-    Cholesky path is the single largest cost of an ALS iteration (its
-    K-step while-loop of small dynamic slices runs at ~10 GF/s), so the
-    dense-VPU elimination wins despite ~9x the nominal FLOPs.
+    ``lu`` = the Pallas shrinking-elimination kernel, one system per
+    vector lane; anything else (``_resolve_loop_statics`` admits only
+    ``cholesky``) = XLA's batched Cholesky, whose K-step while-loop of
+    small dynamic slices is the mesh path, the path above the kernel's
+    VMEM limit and the reference.
     """
     if solver == "lu":
-        # Shrinking elimination: ~K^3/3 FLOPs vs GJ's ~K^3; measured 1.4x
-        # faster at the full-scale solve count (23.5 vs 32.7 ms / 131k
-        # rank-64 systems on v5e).
         return ridge_solve_lu_pallas(a, b, reg_vec,
-                                     interpret=not pallas_supported())
-    if solver == "gj":
-        return ridge_solve_gj_pallas(a, b, reg_vec,
                                      interpret=not pallas_supported())
     k = a.shape[-1]
     eye = jnp.eye(k, dtype=a.dtype)
@@ -1351,6 +1345,10 @@ def _resolve_loop_statics(config: ALSConfig, user_buckets, item_buckets,
     emitted per EXPANDED chunk in :func:`_expand_chunks` order.
     """
     k = config.rank
+    if config.solver not in ("auto", "cholesky", "lu"):
+        raise ValueError(
+            f"solver={config.solver!r}: expected 'auto', 'cholesky' or "
+            "'lu'")
     # Mosaic kernels are opaque custom calls: GSPMD cannot partition them
     # ("Mosaic kernels cannot be automatically partitioned", raised while
     # lowering for a 4-chip v5e mesh), so the compiled kernels are the
@@ -1362,7 +1360,7 @@ def _resolve_loop_statics(config: ALSConfig, user_buckets, item_buckets,
     on_tpu = pallas_supported()
     one_chip = on_tpu and (sh is None or len(sh.device_set) == 1)
     if on_tpu and not one_chip and (
-            config.use_pallas or config.solver in ("lu", "gj")):
+            config.use_pallas or config.solver == "lu"):
         raise ValueError(
             f"use_pallas={config.use_pallas!r} / solver={config.solver!r} "
             f"on a {len(sh.device_set)}-device mesh: the compiled Pallas "
@@ -1393,10 +1391,11 @@ def _resolve_loop_statics(config: ALSConfig, user_buckets, item_buckets,
 
     solver = config.solver
     if solver == "auto":
-        # The elimination kernels target the VPU; on CPU meshes the XLA
+        # The elimination kernel targets the VPU; on CPU meshes the XLA
         # Cholesky is fine and interpret-mode Pallas would be slow.
         # High ranks overflow the kernel's VMEM working set — Cholesky.
-        solver = "lu" if one_chip and gj_fits_vmem(k) else "cholesky"
+        solver = "lu" if one_chip and lanes_solve_fits_vmem(k) \
+            else "cholesky"
 
     def side_meta(buckets, specs):
         kinds, flags = [], []

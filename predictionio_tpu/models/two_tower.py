@@ -252,8 +252,8 @@ _train_step_impl = functools.partial(
 
 # K-step fused dispatch (ISSUE 7): ONE XLA program runs K optimizer
 # steps via lax.scan over a K-stacked superbatch — the per-step
-# dispatch/sync cadence (BENCH_r06: ~99% of the residual pipeline gap
-# is device_wait) is paid once per K steps.  The whole superbatch is
+# dispatch/sync cadence (the ``device_wait`` phase of obs/pipeline.py)
+# is paid once per K steps.  The whole superbatch is
 # donated like the single-step batch.  Returns the carried state and
 # the per-step loss vector [K] — the divergence guard checks every slot
 # at the fusion boundary.

@@ -1,9 +1,9 @@
 """Training-pipeline probe: host-wait vs H2D vs device-step attribution.
 
-BENCH_r05 measured a 45.9% (two-tower) and 87.0% (DLRM) gap between raw
-feeder throughput and realized training examples/sec with no way to say
-which side of the pipeline stalls.  This probe decomposes every training
-iteration's wall time into named, separately-plotted components:
+Realized training examples/sec can sit far below raw feeder throughput
+with no way to say which side of the pipeline stalls.  This probe
+decomposes every training iteration's wall time into named,
+separately-plotted components:
 
 - ``host_wait``  — time blocked fetching the next batch (feeder / numpy)
 - ``h2d``        — time converting + transferring the batch to device
@@ -22,8 +22,7 @@ The device measurements use a one-step lag so the probe never reduces
 host/device overlap: after batch N+1 is staged, the loop must wait for
 step N's output anyway (it is the next step's input), so blocking there
 and timing the block attributes exactly the stall the pipeline already
-pays.  wall ≈ host_wait + h2d + device_wait + loop overhead, which is the
-decomposition ISSUE/BENCH needed.
+pays.  wall ≈ host_wait + h2d + device_wait + loop overhead.
 
 With the PR-5 prefetched input pipeline (``data/prefetch.py``), batch
 staging runs on a background thread and the transfer overlaps device
@@ -32,8 +31,8 @@ compute, so billing it to the step loop would be wrong twice over:
 ``host_wait`` and attributes the staging cost to the **overlap window**
 (``pio_train_h2d_overlap_ms`` + the timeline's ``h2dOverlapMs``) instead
 of the sync point.  The serialized ``h2d`` component of such steps is 0
-by construction; ``tools/attribute_gap.py`` keeps reading the same
-host-lane wall decomposition either way.
+by construction; the timeline's summary (``/timeline.json``,
+``/fleet.json``) keeps the same host-lane wall decomposition either way.
 
 jax is imported lazily inside the sync so this module (like all of obs)
 stays importable without an accelerator stack.
@@ -271,7 +270,7 @@ class PipelineProbe:
         ``steps`` is the optimizer-step count this ONE dispatch covers (a
         K-fused ``lax.scan`` window passes K): the steps counter advances
         by it, and the timeline record carries it so the per-dispatch
-        wall is attributable to K steps downstream (attribute_gap)."""
+        wall is attributable to K steps downstream."""
         self._pending = _sync_target(outputs)
         self._pending_t0 = time.perf_counter()
         if self._dispatch_ref is not None:

@@ -2,7 +2,7 @@
 
 Since ISSUE 8/13 most predict traffic is answered by APPROXIMATE
 retrieval rungs (``ivf``, ``ivf_pq``, ``pq_flat``) whose recall was
-measured exactly once, offline, at bench time.  A skewed delta-refresh,
+measured exactly once, offline, when the index was built.  A skewed delta-refresh,
 a truncated corpus sample, or a mis-tuned ``nprobe``/``rerank`` can rot
 recall for days while every latency SLO, score-drift gauge, and shadow
 overlap reads green — the results come back fast, well-scored, and
@@ -32,8 +32,9 @@ proved for score drift, pointed at the retrieval layer:
   narrow — widen ``PIO_IVF_NPROBE``)?  ``ivf`` misses are all
   cell-misses by construction (the in-cell scan is exact);
   ``pq_flat`` misses are all shortlist-saturation (every code row is
-  scanned).  ``tools/attribute_quality.py`` turns the two gauges into
-  the recommendation.
+  scanned).  The two gauges are on ``/metrics`` and in
+  ``/quality.json``'s ``recall`` block; turning them into the
+  recommendation is the operator's reading.
 - **Gate-wired.**  :meth:`RecallMonitor.augment_quality` folds a third
   verdict into ``/quality.json``'s promotion gate (after drift and
   shadow divergence) with the same asymmetric hysteresis (trip

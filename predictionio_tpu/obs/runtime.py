@@ -21,8 +21,8 @@ model of Google-Wide Profiling (Ren et al., IEEE Micro 2010):
 - :class:`StepTimeline` is a process-wide ring of per-step pipeline phase
   decompositions (host_wait / h2d / device_wait / device_step, fed by
   ``obs.pipeline.PipelineProbe``), served at ``/timeline.json``,
-  exportable as Chrome-trace JSON, and consumed by
-  ``tools/attribute_gap.py`` to attribute the feeder-vs-realized gap.
+  exportable as Chrome-trace JSON; its per-model summary is what the
+  fleet aggregator (``obs/fleet.py``) merges into ``/fleet.json``.
 
 Like the rest of ``obs``, importing this module never imports jax: all
 jax touches are lazy and degrade to no-ops when jax is absent — the
@@ -560,8 +560,8 @@ class StepTimeline:
             "examples": int(examples),
             # Optimizer steps this ONE record (= one dispatch) covers: a
             # K-fused lax.scan window writes K — the per-dispatch wall is
-            # attributable to K steps, and attribute_gap reads the mean
-            # fusion depth off the summary.
+            # attributable to K steps, and the summary's ``fuse_steps``
+            # is their mean per dispatch.
             "fusedSteps": max(int(fused_steps), 1),
         }
         # True dispatch / staging-end wall clocks (when known): the
@@ -590,7 +590,9 @@ class StepTimeline:
             return sorted({r["model"] for r in self._ring})
 
     def summary(self, model: Optional[str] = None) -> Dict[str, Any]:
-        """Aggregate phase totals/shares — the attribute_gap input.
+        """Aggregate phase totals/shares, served by
+        ``/timeline.json?format=summary`` and merged per instance into
+        ``/fleet.json`` (``obs/fleet.py``).
 
         ``phase_share`` is each host-lane phase's share of the summed
         host-lane wall (host_wait + h2d + device_wait): the decomposition
@@ -618,7 +620,7 @@ class StepTimeline:
             "model": model,
             # Optimizer steps vs dispatches: with K-step fusion one
             # record covers K steps, so the pair exposes the mean
-            # fusion depth attribute_gap reports.
+            # fusion depth.
             "steps": steps,
             "dispatches": len(items),
             "fuse_steps": round(steps / len(items), 2) if items else 0.0,

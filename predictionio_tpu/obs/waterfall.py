@@ -32,8 +32,8 @@ Three consumers, one collector:
   an exemplar trace id that resolves via ``/traces.json?request_id=``;
 - a ``waterfall`` event attached to the request's own span tree;
 - an opt-in wide-event JSONL (``PIO_REQUEST_LOG=path``): one
-  self-contained line per request for offline attribution
-  (``tools/attribute_serve.py``).
+  self-contained line per request for offline attribution (no tool in
+  the repo reads it since PR 30; the operator's ``jq`` does).
 
 Thread model: the handler thread owns the :class:`Waterfall` (contextvar
 ``begin_request``); the batcher thread stamps its stages through the

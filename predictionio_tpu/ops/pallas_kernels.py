@@ -34,7 +34,7 @@ __all__ = ["fused_gram_vector", "fused_gram_vector_pallas",
            "fused_gram_dense", "fused_gram_dense_pallas",
            "fused_gram_dense_xla", "dense_weights", "dense_block_width",
            "dense_row_density", "DENSE_TILE_R",
-           "ridge_solve_gj_pallas", "ridge_solve_lu_pallas", "gj_fits_vmem",
+           "ridge_solve_lu_pallas", "lanes_solve_fits_vmem",
            "fused_topk", "fused_topk_pallas", "fused_topk_tiles",
            "pq_scan", "pq_scan_pallas", "pq_scan_xla"]
 
@@ -371,21 +371,20 @@ def fused_gram_dense(block: jax.Array, x: jax.Array, alpha, *,
 
 
 # ---------------------------------------------------------------------------
-# Batched ridge solve via Gauss-Jordan elimination.
+# Batched ridge solve by elimination, one system per vector lane.
 #
 # XLA's batched Cholesky lowers to a K-step while-loop of small dynamic
-# slices — measured ~50 ms for 6040 rank-64 systems on v5e, i.e. ~10 GF/s.
-# Gauss-Jordan does ~9x the FLOPs of Cholesky but every step is a dense
-# [B, K, K] VPU op with no data-dependent control flow, which is the shape
-# the hardware actually likes.  No pivoting: A + lambda*diag is SPD with
-# lambda > 0 (ALS-WR always scales reg by degree >= 1).
+# slices.  Elimination without pivoting is dense [K, K, 128] VPU work
+# with no data-dependent control flow, which is the shape the hardware
+# likes.  No pivoting: A + lambda*diag is SPD with lambda > 0 (ALS-WR
+# always scales reg by degree >= 1).
 # ---------------------------------------------------------------------------
 
-GJ_LANES = 128  # systems per program — one per vector lane
+SOLVE_LANES = 128  # systems per program — one per vector lane
 
 
-def gj_fits_vmem(k: int) -> bool:
-    """Whether the lanes-solve kernels' per-program working set fits VMEM.
+def lanes_solve_fits_vmem(k: int) -> bool:
+    """Whether the lanes solve's per-program working set fits VMEM.
 
     The kernel holds the natural [128, k, k] input block (double-buffered)
     plus the lane-major [k, k, 128] scratch, all f32.  Budget ~12 MB of
@@ -393,7 +392,7 @@ def gj_fits_vmem(k: int) -> bool:
     the Cholesky path — the kernel would fail to compile where XLA's
     solver still works (round-2 advisor finding).
     """
-    return 5 * k * k * GJ_LANES * 4 <= 12 * 1024 * 1024
+    return 5 * k * k * SOLVE_LANES * 4 <= 12 * 1024 * 1024
 
 
 def _load_lane_major(a_ref, b_ref, reg_ref, m_ref, v_ref):
@@ -422,90 +421,25 @@ def _store_lane_major(x_ref, v_ref):
     x_ref[:] = jnp.transpose(v_ref[:].reshape(k, t), (1, 0))
 
 
-def _gj_kernel(a_ref, b_ref, reg_ref, x_ref, m_ref, v_ref):
-    """Solve (A + diag(reg)) x = b for GJ_LANES systems per program.
+def _lu_kernel(a_ref, b_ref, reg_ref, x_ref, m_ref, v_ref):
+    """Cholesky-free LDU solve for SOLVE_LANES SPD systems per program.
 
     Layout is the whole trick: systems live on the LANE dimension —
     ``m [K, K, 128]`` holds matrix element (r, c) of system t at
     ``m[r, c, t]``.  Row/column j of all 128 systems are then contiguous
     dynamic sublane slices (``m[pl.ds(j,1)]``, ``m[:, pl.ds(j,1)]``), the
-    pivot is a plain [1,1,128] lane vector, and the rank-1 elimination
-    update is a single lane-parallel FMA over [K,K,128] with no one-hot
-    masks materialized.  (A prior batch-on-sublanes formulation spent ~94%
-    of VPU issue on mask/select traffic — 18.7 ms for 6040 K=64 systems;
-    this layout removes all of it.)
+    pivot is a plain [1,1,128] lane vector, and the elimination update is
+    a lane-parallel FMA with no one-hot masks materialized.  Because
+    every system is confined to its own lane, a boundary block whose
+    tail lanes are Pallas OOB padding solves garbage there without
+    touching real lanes — the padded x rows are simply never written
+    back.
 
-    The "set row j to the normalized row" step is folded into the update:
-    ``m - (col - e_j) ⊗ row_n`` eliminates every other row and lands row j
-    on ``row_n`` in one expression (col's pivot entry becomes p-1).
-
-    Because every system is confined to its own lane, a boundary block
-    whose tail lanes are Pallas OOB padding solves garbage there without
-    touching real lanes — the padded x rows are simply never written back.
-    """
-    k = a_ref.shape[1]
-    _load_lane_major(a_ref, b_ref, reg_ref, m_ref, v_ref)
-    sub_iota = jax.lax.broadcasted_iota(jnp.int32, (k, 1, 1), 0)
-
-    def step(j, _):
-        row = m_ref[pl.ds(j, 1), :, :]                # [1, K, T] row j
-        col = m_ref[:, pl.ds(j, 1), :]                # [K, 1, T] col j
-        inv = 1.0 / m_ref[pl.ds(j, 1), pl.ds(j, 1), :]  # [1, 1, T] pivot
-        row_n = row * inv                             # [1, K, T]
-        bj = v_ref[pl.ds(j, 1), :, :] * inv           # [1, 1, T]
-        ej = (sub_iota == j).astype(jnp.float32)      # [K, 1, 1]
-        col_m = col - ej                              # pivot row → p-1
-        m_ref[:] = m_ref[:] - col_m * row_n           # lane-parallel FMA
-        v_ref[:] = v_ref[:] - col_m * bj
-        return 0
-
-    jax.lax.fori_loop(0, k, step, 0, unroll=False)
-    _store_lane_major(x_ref, v_ref)
-
-
-def _ridge_solve_lanes(kernel, a, b, reg, interpret: bool):
-    """Shared scaffolding for the systems-on-lanes solvers.
-
-    Inputs stay in their NATURAL layouts ([B,K,K], [B,K], [B]) — the
-    lane-major staging happens inside the kernel, so no relayout copies
-    are emitted between the gram build, this solve, and the factor
-    scatter.  A non-multiple-of-128 batch rides Pallas's auto-padded
-    boundary block (lane-isolated systems make the padding harmless).
-    """
-    bt, k = b.shape
-    x = pl.pallas_call(
-        kernel,
-        grid=(-(-bt // GJ_LANES),),
-        in_specs=[
-            pl.BlockSpec((GJ_LANES, k, k), lambda i: (i, 0, 0)),
-            pl.BlockSpec((GJ_LANES, k), lambda i: (i, 0)),
-            pl.BlockSpec((1, GJ_LANES), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((GJ_LANES, k), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bt, k), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((k, k, GJ_LANES), jnp.float32),
-                        pltpu.VMEM((k, 1, GJ_LANES), jnp.float32)],
-        interpret=interpret,
-    )(a.astype(jnp.float32), b.astype(jnp.float32),
-      reg.astype(jnp.float32).reshape(1, bt))
-    return x
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def ridge_solve_gj_pallas(a, b, reg, *, interpret: bool = False):
-    """Batched SPD solve ``(A + diag(reg)) x = b`` — [B,K,K],[B,K],[B]→[B,K]."""
-    return _ridge_solve_lanes(_gj_kernel, a, b, reg, interpret)
-
-
-def _lu_kernel(a_ref, b_ref, reg_ref, x_ref, m_ref, v_ref):
-    """Cholesky-free LDU solve for GJ_LANES SPD systems per program.
-
-    Same systems-on-lanes layout as the GJ kernel, but the elimination
-    SHRINKS: the Python-unrolled outer loop updates only the trailing
-    rows, in 8-row (sublane-granule) quanta so every slice stays
-    aligned — ~K³/3 FLOPs vs Gauss-Jordan's ~K³.  Back-substitution
-    runs K cheap [1, ·, T] steps on the upper-triangular remainder.
-    No pivoting: A + diag(reg) is SPD (ALS-WR reg ≥ λ).
+    The elimination SHRINKS: the Python-unrolled outer loop updates only
+    the trailing rows, in 8-row (sublane-granule) quanta so every slice
+    stays aligned — ~K³/3 FLOPs.  Back-substitution runs K cheap
+    [1, ·, T] steps on the upper-triangular remainder.  No pivoting:
+    A + diag(reg) is SPD (ALS-WR reg ≥ λ).
     """
     k = a_ref.shape[1]
     _load_lane_major(a_ref, b_ref, reg_ref, m_ref, v_ref)
@@ -552,8 +486,31 @@ def _lu_kernel(a_ref, b_ref, reg_ref, x_ref, m_ref, v_ref):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ridge_solve_lu_pallas(a: jax.Array, b: jax.Array, reg: jax.Array,
                           *, interpret: bool = False) -> jax.Array:
-    """Batched SPD solve via shrinking elimination — [B,K,K],[B,K],[B]→[B,K]."""
-    return _ridge_solve_lanes(_lu_kernel, a, b, reg, interpret)
+    """Batched SPD solve ``(A + diag(reg)) x = b`` via shrinking
+    elimination — [B,K,K],[B,K],[B]→[B,K].
+
+    Inputs stay in their NATURAL layouts — the lane-major staging happens
+    inside the kernel, so no relayout copies are emitted between the gram
+    build, this solve, and the factor scatter.  A non-multiple-of-128
+    batch rides Pallas's auto-padded boundary block (lane-isolated
+    systems make the padding harmless).
+    """
+    bt, k = b.shape
+    return pl.pallas_call(
+        _lu_kernel,
+        grid=(-(-bt // SOLVE_LANES),),
+        in_specs=[
+            pl.BlockSpec((SOLVE_LANES, k, k), lambda i: (i, 0, 0)),
+            pl.BlockSpec((SOLVE_LANES, k), lambda i: (i, 0)),
+            pl.BlockSpec((1, SOLVE_LANES), lambda i: (0, i)),
+        ],
+        out_specs=pl.BlockSpec((SOLVE_LANES, k), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((bt, k), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((k, k, SOLVE_LANES), jnp.float32),
+                        pltpu.VMEM((k, 1, SOLVE_LANES), jnp.float32)],
+        interpret=interpret,
+    )(a.astype(jnp.float32), b.astype(jnp.float32),
+      reg.astype(jnp.float32).reshape(1, bt))
 
 
 def fused_gram_vector(f: jax.Array, w: jax.Array, c: jax.Array,

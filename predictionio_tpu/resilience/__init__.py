@@ -15,7 +15,7 @@ every network hop (SDK → event/engine servers → RemoteClient):
 - :mod:`predictionio_tpu.resilience.faults` — env-driven fault injection
   (``PIO_FAULTS="storage.create:error:0.3,storage.find:delay:200ms"``)
   hooked into the storage base layer, the JSON-RPC framing, and the HTTP
-  handlers; used by tests and ``bench_serving.py``.
+  handlers; used by tests.
 - :mod:`predictionio_tpu.resilience.spill` — storage-outage spill
   journal: a durable append-only JSONL file the event server degrades
   into (202 + ``Retry-After``) plus the background replay worker that

@@ -29,8 +29,8 @@ Instrumented points:
 - ``http.event`` / ``http.engine`` — the HTTP handlers.
 
 Injected errors raise :class:`FaultInjected` (a ``ConnectionError``), so
-they travel the same except-paths a real dead backend would.  Tests and
-``bench_serving.py`` can bypass the env with :func:`install`.
+they travel the same except-paths a real dead backend would.  Tests
+can bypass the env with :func:`install`.
 """
 
 from __future__ import annotations

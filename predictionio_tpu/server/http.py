@@ -110,7 +110,8 @@ def timeline_payload(params: Dict[str, List[str]]) -> Dict[str, Any]:
     timeline.  ``?model=`` filters, ``?n=`` bounds the record count, and
     ``?format=chrome`` returns Chrome-trace JSON (chrome://tracing /
     Perfetto); ``?format=summary`` returns only the per-model phase
-    aggregation that ``tools/attribute_gap.py`` consumes."""
+    aggregation, which the fleet aggregator (``obs/fleet.py``) merges
+    into ``/fleet.json``."""
     from predictionio_tpu.obs.runtime import get_timeline
 
     tl = get_timeline()
@@ -278,9 +279,9 @@ class BaseHandler(BaseHTTPRequestHandler):
                                               ms, body, params) or {})
             for k, v in handler_headers.items():
                 extra.setdefault(k, v)
-            # The server's own read+handle wall time: clients (and the
-            # serving bench) use it to attribute client-vs-server latency
-            # drift and to ATTEST deadline compliance — a 200 whose
+            # The server's own read+handle wall time: clients use it to
+            # attribute client-vs-server latency drift and to ATTEST
+            # deadline compliance — a 200 whose
             # X-PIO-Server-Ms is inside the sent budget was served in
             # time by the server's clock, whatever transport queueing
             # added around it.

@@ -25,6 +25,7 @@ from __future__ import annotations
 import importlib
 import logging
 import os
+import re
 import threading
 from typing import Dict, Iterable, List, Optional, Sequence
 
@@ -58,9 +59,25 @@ class ServerPlugin:
         pass
 
 
-def _sanitize(s: str) -> str:
-    """Strip CR/LF so a plugin-supplied value cannot inject headers."""
-    return str(s).replace("\r", " ").replace("\n", " ")
+# An HTTP header name is a token (RFC 9110 §5.1): anything else (empty,
+# a ':' or a space inside) would come out as a malformed header line.
+_HEADER_NAME = re.compile(r"[!#$%&'*+.^_`|~0-9A-Za-z-]+")
+
+
+def _sanitize(headers: Dict[str, str], plugin) -> Dict[str, str]:
+    """What of a plugin's headers may go on the wire: names that are HTTP
+    tokens (the others dropped, with a log line), values with CR/LF
+    blanked so that none can start a header line of its own."""
+    out = {}
+    for name, value in headers.items():
+        name = str(name)
+        if not _HEADER_NAME.fullmatch(name):
+            logger.warning("plugin %s: dropped response header with "
+                           "invalid name %r", getattr(plugin, "name", plugin),
+                           name)
+            continue
+        out[name] = str(value).replace("\r", " ").replace("\n", " ")
+    return out
 
 
 class MetricsPlugin(ServerPlugin):
@@ -155,8 +172,7 @@ class PluginManager:
             try:
                 h = p.on_request(route, status, ms)
                 if h:
-                    headers.update({_sanitize(k): _sanitize(v)
-                                    for k, v in h.items()})
+                    headers.update(_sanitize(h, p))
             except Exception:
                 logger.exception("plugin %s on_request failed",
                                  getattr(p, "name", p))

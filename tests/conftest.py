@@ -53,3 +53,39 @@ def pio_home(tmp_path, monkeypatch):
     import gc
 
     gc.collect()
+
+
+@pytest.fixture()
+def kill9_after():
+    """Start ``python -c source *argv``, read the integers it prints one a
+    line until ``reached(n)``, then ``kill -9`` it while it still runs.
+    Returns the last integer read.  The child has a time limit of its
+    own (60 s), whatever the test around it does."""
+    import signal
+    import subprocess
+    import sys
+    import threading
+
+    def run(source, argv, reached):
+        child = subprocess.Popen(
+            [sys.executable, "-c", source, *map(str, argv)],
+            env={**os.environ, "PYTHONPATH": os.pathsep.join(sys.path)},
+            stdout=subprocess.PIPE, text=True)
+        limit = threading.Timer(60.0, child.kill)
+        limit.start()
+        try:
+            seen = None
+            for line in child.stdout:
+                seen = int(line)
+                if reached(seen):
+                    break
+            assert child.poll() is None, "the child ended before the kill"
+            os.kill(child.pid, signal.SIGKILL)
+            child.wait(timeout=30)
+        finally:
+            limit.cancel()
+            child.kill()
+            child.stdout.close()
+        return seen
+
+    return run

@@ -9,11 +9,13 @@ import urllib.request
 
 import pytest
 
+from predictionio_tpu.data.event import Event
 from predictionio_tpu.data.storage import (
     AccessKey,
     App,
     StorageUnavailable,
     get_storage,
+    reset_storage,
 )
 from predictionio_tpu.resilience import faults
 from predictionio_tpu.server.event_server import EventServer, max_batch_size
@@ -150,6 +152,48 @@ def test_batch_token_retry_dedups_row_by_row(pio_home):
         assert len(list(storage.get_events().find(app_id))) == 3
     finally:
         srv.stop()
+
+
+_KILL9_BATCHES, _KILL9_PER = 300, 8
+_KILL9_LOADER = """
+import sys
+from predictionio_tpu.data.event import Event
+from predictionio_tpu.data.storage import get_storage
+ev = get_storage().get_events()
+app_id, batches, per = (int(a) for a in sys.argv[1:4])
+for b in range(batches):
+    evs = [Event(event='view', entity_type='user', entity_id=f'ku{b}_{j}',
+                 target_entity_type='item', target_entity_id=f'ki{j}')
+           for j in range(per)]
+    ev.create_batch(evs, app_id, tokens=[f'kill{b}.{j}' for j in range(per)])
+    print(b, flush=True)
+"""
+
+
+def test_kill9_mid_stream_then_token_replay_loses_and_doubles_nothing(
+        pio_home, kill9_after):
+    """A REAL ``kill -9`` of a bulk loader between two of its batches,
+    then every batch re-sent with its tokens (the crashed one included):
+    the ids the tokens derive are the dedup keys, so the replay lands
+    exactly the missing rows."""
+    storage = get_storage()
+    app_id = storage.get_apps().insert(App(id=None, name="kill9"))
+    storage.get_events().init(app_id)
+    reset_storage()  # the child owns the store until it dies
+    seen = kill9_after(_KILL9_LOADER, (app_id, _KILL9_BATCHES, _KILL9_PER),
+                       lambda b: b >= 10)  # provably mid-stream
+    assert 10 <= seen < _KILL9_BATCHES - 1
+    events = get_storage().get_events()
+    landed = sum(1 for _ in events.find(app_id))
+    assert (seen + 1) * _KILL9_PER <= landed < _KILL9_BATCHES * _KILL9_PER
+    for b in range(_KILL9_BATCHES):
+        events.create_batch(
+            [Event(event="view", entity_type="user", entity_id=f"ku{b}_{j}",
+                   target_entity_type="item", target_entity_id=f"ki{j}")
+             for j in range(_KILL9_PER)],
+            app_id, tokens=[f"kill{b}.{j}" for j in range(_KILL9_PER)])
+    rows = [e.entity_id for e in events.find(app_id)]
+    assert len(rows) == len(set(rows)) == _KILL9_BATCHES * _KILL9_PER
 
 
 def test_bad_batch_token_rejected(pio_home):

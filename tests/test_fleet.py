@@ -602,6 +602,76 @@ class TestRolloutE2E:
             for s in servers:
                 s.stop()
 
+    def test_bad_candidate_burns_the_real_gate_and_the_rest_answer_on(
+            self, pio_home, tmp_path, monkeypatch):
+        """The halt above with nothing faked between the candidate and
+        the gate: a candidate that passes validation and fails every
+        predict is promoted to the canary only, its own 500s burn the
+        SLO the controller scrapes, the wave halts and rolls back, and
+        a client of each instance NOT yet promoted saw nothing but 200s
+        for the whole episode.  (The three servers share one process and
+        one registry, so the burn is process-wide; what is told apart
+        per instance is what each one's client received.)"""
+        from urllib.error import HTTPError
+
+        from predictionio_tpu.server import engine_server as es_mod
+
+        eng, variant, ctx, _, (i1,) = _trained_fleet_stack(1)
+        servers, urls = _fleet_servers(eng, variant, ctx.storage)
+        bad = run_train(eng, variant, ctx)
+        real_load = es_mod.load_models
+
+        class Unservable:
+            """No arrays for the finite check to refuse, no predict."""
+
+        monkeypatch.setattr(
+            es_mod, "load_models",
+            lambda e, inst, c=None: [Unservable()] if inst.id == bad
+            else real_load(e, inst, c))
+        stop = threading.Event()
+        seen = {u: {} for u in urls}
+
+        def drive(url):
+            k = 0
+            while not stop.is_set():
+                req = Request(
+                    url + "/queries.json",
+                    data=json.dumps({"user": f"u{k % 30}",
+                                     "num": 3}).encode(),
+                    headers={"Content-Type": "application/json"})
+                try:
+                    with urlopen(req, timeout=30) as resp:
+                        status = resp.status
+                except HTTPError as e:
+                    status = e.code
+                except OSError:
+                    status = -1
+                seen[url][status] = seen[url].get(status, 0) + 1
+                k += 1
+
+        drivers = [threading.Thread(target=drive, args=(u,), daemon=True)
+                   for u in urls]
+        try:
+            for t in drivers:
+                t.start()
+            ctl = RolloutController(
+                urls, _cfg(tmp_path, bake_s=30.0, poll_s=0.05))
+            state = ctl.run(bad)
+            assert state["status"] == "rolled_back"
+            assert state["promoted"] == state["rolledBack"] == [urls[0]]
+            assert "slo burn" in state["haltReason"]
+            for u in urls:
+                assert ctl.served_instance(u) == i1
+        finally:
+            stop.set()
+            for t in drivers:
+                t.join(timeout=30)
+            for s in servers:
+                s.stop()
+        assert seen[urls[0]].get(500, 0) > 0, seen  # the canary did burn
+        for u in urls[1:]:
+            assert set(seen[u]) == {200}, seen
+
     def test_dead_instance_and_409_skip_and_report(self, pio_home,
                                                    tmp_path):
         eng, variant, ctx, _, (i1,) = _trained_fleet_stack(1)

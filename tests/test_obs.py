@@ -7,10 +7,8 @@ TYPE-before-samples, cumulative ``le`` buckets, ``+Inf`` == ``_count``.
 test_servers.py imports it to validate live ``/metrics`` output.
 """
 
-import importlib.util
 import json
 import math
-import pathlib
 import re
 import threading
 
@@ -598,84 +596,6 @@ class TestPublishEvent:
         assert [s["name"] for s in doc["spans"]] == ["spill.append"]
 
 
-# -- attribute_gap tool ------------------------------------------------------
-
-def _load_attribute_gap():
-    path = (pathlib.Path(__file__).resolve().parents[1]
-            / "tools" / "attribute_gap.py")
-    spec = importlib.util.spec_from_file_location("attribute_gap", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-class TestAttributeGap:
-    BENCH = {
-        "tpu_era": {
-            "two_tower_examples_per_sec_per_chip": 1_000_000.0,
-            "two_tower_feeder_examples_per_sec": 800_000.0,
-            "two_tower_pipeline_examples_per_sec": 540_000.0,
-            "two_tower_pipeline_gap_pct": 46.0,
-            "dlrm_examples_per_sec_per_chip": 2_000_000.0,
-            "dlrm_feeder_examples_per_sec": 900_000.0,
-            "dlrm_pipeline_examples_per_sec": 260_000.0,
-            "dlrm_pipeline_gap_pct": 87.0,
-        },
-        "timeline": {
-            "two_tower": {"steps": 6, "examples": 100,
-                          "phase_ms": {"host_wait": 10, "h2d": 70,
-                                       "device_wait": 20,
-                                       "device_step": 25},
-                          "phase_share": {"host_wait": 0.1, "h2d": 0.7,
-                                          "device_wait": 0.2}},
-            "dlrm": {"steps": 6, "examples": 100,
-                     "phase_ms": {"host_wait": 65, "h2d": 20,
-                                  "device_wait": 15, "device_step": 10},
-                     "phase_share": {"host_wait": 0.65, "h2d": 0.2,
-                                     "device_wait": 0.15}},
-        },
-    }
-
-    def test_dominant_component_and_attack(self):
-        mod = _load_attribute_gap()
-        res = mod.attribute(self.BENCH)
-        assert res["two_tower"]["dominant"] == "h2d"
-        assert "buffer" in res["two_tower"]["attack"]
-        assert res["dlrm"]["dominant"] == "host_wait"
-        assert "feeder" in res["dlrm"]["attack"]
-
-    def test_render_prints_both_models_with_shares(self, capsys, tmp_path):
-        mod = _load_attribute_gap()
-        f = tmp_path / "round.json"
-        f.write_text(json.dumps(self.BENCH))
-        assert mod.main([str(f)]) == 0
-        out = capsys.readouterr().out
-        assert "two_tower" in out and "dlrm" in out
-        assert "dominant: h2d" in out and "dominant: host_wait" in out
-        assert "70.0%" in out  # the share of step time is printed
-
-    def test_external_timeline_overrides_and_server_shape(self, tmp_path):
-        mod = _load_attribute_gap()
-        # /timeline.json server shape: summaries under "models"
-        timeline = {"models": {
-            "two_tower": {"steps": 2,
-                          "phase_ms": {"host_wait": 5, "h2d": 1,
-                                       "device_wait": 94},
-                          "phase_share": {"host_wait": 0.05, "h2d": 0.01,
-                                          "device_wait": 0.94}}}}
-        res = mod.attribute(self.BENCH, timeline)
-        assert res["two_tower"]["dominant"] == "device_wait"
-        assert "fusion" in res["two_tower"]["attack"]
-        assert res["dlrm"] is None  # absent from the override
-
-    def test_no_data_exits_nonzero(self, capsys, tmp_path):
-        mod = _load_attribute_gap()
-        f = tmp_path / "round.json"
-        f.write_text(json.dumps({"tpu_era": {}}))
-        assert mod.main([str(f)]) == 1
-        assert "no timeline data" in capsys.readouterr().out
-
-
 # -- overlapped input pipeline (ISSUE 5): probe + timeline + HBM guard ------
 
 class _FakePrefetched:
@@ -825,85 +745,3 @@ class TestHbmHeadroomWarning:
         sampler.reset_peak()
         assert sampler.headroom_exceeded() is False
         assert sampler.headroom_exceeded(fraction=0.05) is True
-
-
-class TestAttributeGapCompare:
-    OLD = {
-        "tpu_era": {
-            "two_tower_pipeline_examples_per_sec": 500_000.0,
-            "two_tower_pipeline_gap_pct": 45.9,
-            "two_tower_feeder_examples_per_sec": 900_000.0,
-            "dlrm_pipeline_examples_per_sec": 120_000.0,
-            "dlrm_pipeline_gap_pct": 87.0,
-        },
-        "timeline": {
-            "two_tower": {"steps": 4,
-                          "phase_ms": {"host_wait": 10, "h2d": 70,
-                                       "device_wait": 20},
-                          "phase_share": {"host_wait": 0.1, "h2d": 0.7,
-                                          "device_wait": 0.2}},
-        },
-    }
-    NEW = {
-        "tpu_era": {
-            "two_tower_pipeline_examples_per_sec": 800_000.0,
-            "two_tower_pipeline_gap_pct": 12.0,
-            "two_tower_feeder_examples_per_sec": 900_000.0,
-            "dlrm_pipeline_examples_per_sec": 300_000.0,
-            "dlrm_pipeline_gap_pct": 40.0,
-        },
-        "timeline": {
-            "two_tower": {"steps": 4,
-                          "phase_ms": {"host_wait": 10, "h2d": 2,
-                                       "device_wait": 88,
-                                       "h2d_overlap": 60},
-                          "phase_share": {"host_wait": 0.1, "h2d": 0.02,
-                                          "device_wait": 0.88}},
-        },
-    }
-
-    def test_gap_delta_and_dominant_shift(self):
-        mod = _load_attribute_gap()
-        res = mod.compare(self.OLD, self.NEW)
-        tt = res["two_tower"]
-        assert tt["gap_delta_pct"] == pytest.approx(-33.9)
-        assert tt["realized_speedup"] == pytest.approx(1.6)
-        assert tt["dominant_shift"] == ("h2d", "device_wait")
-        # dlrm has gap numbers but no timeline in either round:
-        # compared on gaps alone, no dominant shift
-        assert res["dlrm"]["gap_delta_pct"] == pytest.approx(-47.0)
-        assert "dominant_shift" not in res["dlrm"]
-
-    def test_render_and_cli_exit_code(self, capsys, tmp_path):
-        mod = _load_attribute_gap()
-        old_f = tmp_path / "old.json"
-        new_f = tmp_path / "new.json"
-        old_f.write_text(json.dumps(self.OLD))
-        new_f.write_text(json.dumps(self.NEW))
-        assert mod.main(["--compare", str(old_f), str(new_f)]) == 0
-        out = capsys.readouterr().out
-        assert "45.9% -> 12.0% (-33.9 pts)" in out
-        assert "dominant component shifted: h2d" in out
-        assert "87.0% -> 40.0% (-47.0 pts)" in out
-
-    def test_driver_capture_with_truncated_tail_unwraps(self, tmp_path):
-        mod = _load_attribute_gap()
-        # a driver round whose tail was truncated mid-JSON (as committed
-        # BENCH_r05.json is): the tpu_era block is still rescued
-        inner = json.dumps(self.OLD)
-        # leading garbage + the object body minus its opening brace: no
-        # line parses whole, so the brace-scan rescue must kick in
-        wrapped = {"n": 5, "cmd": "python bench.py", "rc": 0,
-                   "tail": 'g": {"x": 1}}, ' + inner[1:]}
-        f = tmp_path / "r.json"
-        f.write_text(json.dumps(wrapped))
-        doc = mod.load_json(str(f))
-        assert doc["tpu_era"]["two_tower_pipeline_gap_pct"] == 45.9
-
-    def test_compare_nothing_usable_exits_nonzero(self, tmp_path):
-        mod = _load_attribute_gap()
-        a = tmp_path / "a.json"
-        b = tmp_path / "b.json"
-        a.write_text(json.dumps({"tpu_era": {}}))
-        b.write_text(json.dumps({"tpu_era": {}}))
-        assert mod.main(["--compare", str(a), str(b)]) == 1

@@ -47,25 +47,8 @@ def test_dispatcher_cpu_path():
     np.testing.assert_allclose(np.asarray(a), np.asarray(a2), rtol=1e-6)
 
 
-def test_gj_ridge_solve_matches_numpy():
-    """Gauss-Jordan batched solve == numpy direct solve (interpret mode)."""
-    from predictionio_tpu.ops.pallas_kernels import ridge_solve_gj_pallas
-
-    rng = np.random.default_rng(3)
-    B, K = 5, 8
-    y = rng.standard_normal((B, K + 3, K)).astype(np.float32)
-    a = np.einsum("blk,blm->bkm", y, y)
-    b = rng.standard_normal((B, K)).astype(np.float32)
-    reg = np.abs(rng.standard_normal(B)).astype(np.float32) + 0.5
-    x = ridge_solve_gj_pallas(jnp.asarray(a), jnp.asarray(b),
-                              jnp.asarray(reg), interpret=True)
-    want = np.stack([np.linalg.solve(a[i] + reg[i] * np.eye(K), b[i])
-                     for i in range(B)])
-    np.testing.assert_allclose(np.asarray(x), want, rtol=2e-4, atol=2e-4)
-
-
-def test_gj_solver_in_train_als():
-    """solver="gj" end-to-end (interpret) == cholesky path."""
+def test_lu_solver_in_train_als():
+    """solver="lu" end-to-end (interpret) == cholesky path."""
     from predictionio_tpu.models.als import ALSConfig, train_als
 
     rng = np.random.default_rng(5)
@@ -75,26 +58,51 @@ def test_gj_solver_in_train_als():
     base = dict(rank=4, iterations=2, reg=0.1, seed=2, gram_dtype="float32")
     m_ch = train_als(users, items, ratings, 12, 9,
                      ALSConfig(**base, solver="cholesky"))
-    m_gj = train_als(users, items, ratings, 12, 9,
-                     ALSConfig(**base, solver="gj"))
+    m_lu = train_als(users, items, ratings, 12, 9,
+                     ALSConfig(**base, solver="lu"))
     np.testing.assert_allclose(np.asarray(m_ch.user_factors),
-                               np.asarray(m_gj.user_factors),
+                               np.asarray(m_lu.user_factors),
                                rtol=1e-3, atol=1e-3)
 
 
-def test_ridge_solve_lu_matches_oracle():
-    """Shrinking-elimination solver (the TPU auto path) vs numpy."""
-    import numpy as np
-    import jax.numpy as jnp
+@pytest.mark.parametrize("solver", ["gj", "LU", "lu ", ""])
+def test_unknown_solver_is_refused(solver):
+    """Anything but auto / cholesky / lu used to train with Cholesky
+    without a word."""
+    from predictionio_tpu.models.als import ALSConfig, train_als
 
+    with pytest.raises(ValueError, match="'auto', 'cholesky' or 'lu'"):
+        train_als(np.array([0, 1]), np.array([0, 1]),
+                  np.array([1.0, 2.0], np.float32), 2, 2,
+                  ALSConfig(rank=2, iterations=1, solver=solver))
+
+
+# (B, K, rows of the factor whose Gram matrix is A, added to A's diagonal,
+#  least ridge): A = y^T y + diag * I, reg uniform in [least, least + 1).
+_LU_CASES = {
+    "b67_k32": (67, 32, 32, 2.0, 0.1),
+    # the retired Gauss-Jordan kernel's case: a Gram matrix of K + 3 rows
+    "b5_k8_gram": (5, 8, 11, 0.0, 0.5),
+    "k1": (3, 1, 4, 0.0, 0.1),
+    # one system past a full block of 128 lanes, at the chip's rank
+    "b129_k64": (129, 64, 64, 2.0, 0.1),
+    # no ridge at all: the elimination alone on a well-conditioned system
+    "reg0": (9, 16, 64, 1.0, 0.0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LU_CASES))
+def test_ridge_solve_lu_matches_oracle(case):
+    """Shrinking-elimination solver (the TPU auto path) vs numpy."""
     from predictionio_tpu.ops.pallas_kernels import ridge_solve_lu_pallas
 
+    B, K, rows, diag, least = _LU_CASES[case]
     rng = np.random.default_rng(3)
-    B, K = 67, 32
-    M = rng.standard_normal((B, K, K)).astype(np.float32)
-    A = M @ M.transpose(0, 2, 1) + 2 * np.eye(K, dtype=np.float32)
+    y = rng.standard_normal((B, rows, K)).astype(np.float32)
+    A = np.einsum("blk,blm->bkm", y, y) + diag * np.eye(K, dtype=np.float32)
     b = rng.standard_normal((B, K)).astype(np.float32)
-    reg = rng.random(B).astype(np.float32) + 0.1
+    reg = (rng.random(B).astype(np.float32) + least if least
+           else np.zeros(B, np.float32))
     x = np.asarray(ridge_solve_lu_pallas(
         jnp.asarray(A), jnp.asarray(b), jnp.asarray(reg), interpret=True))
     ref = np.stack([np.linalg.solve(A[i] + reg[i] * np.eye(K), b[i])

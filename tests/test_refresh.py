@@ -815,6 +815,92 @@ class TestServerPromotionE2E:
         finally:
             srv.stop()
 
+    def test_promotion_under_a_live_drive_answers_every_query_in_time(
+            self, ctx):
+        """The loop with both servers live: a delta POSTed to the event
+        server, one refresh cycle promoting through the canary window
+        while clients keep querying with a deadline.  Every query across
+        train, swap and canary is a 200, none of them one the server
+        itself attests as late, and what is served afterwards is as
+        fresh as the delta."""
+        from urllib.error import HTTPError
+
+        from predictionio_tpu.data.storage import AccessKey
+        from predictionio_tpu.server import EventServer
+
+        app_id = _mk_app(ctx)
+        _seed_clique_views(ctx, app_id)
+        eng, variant = _tt()
+        run_train(eng, variant, ctx)
+        srv, base = self._server(ctx, eng, variant)
+        key = ctx.storage.get_access_keys().insert(
+            AccessKey(key="", app_id=app_id))
+        evsrv = EventServer(storage=ctx.storage, host="127.0.0.1", port=0)
+        evsrv.start()
+        stop = threading.Event()
+        outcomes = []  # (status, the server's own remaining budget)
+
+        def drive(i):
+            k = i
+            while not stop.is_set():
+                req = Request(
+                    base + "/queries.json", method="POST",
+                    data=json.dumps({"user": f"u{k % 10}",
+                                     "num": 3}).encode(),
+                    headers={"Content-Type": "application/json",
+                             "X-PIO-Deadline-Ms": "20000"})
+                try:
+                    with urlopen(req, timeout=30) as resp:
+                        outcomes.append((resp.status, float(
+                            resp.headers["X-PIO-Deadline-Remaining-Ms"])))
+                except HTTPError as e:
+                    outcomes.append((e.code, None))
+                k += 1
+
+        drivers = [threading.Thread(target=drive, args=(i,), daemon=True)
+                   for i in range(3)]
+        try:
+            _, body = _http(base, "GET", "/")
+            gen1, wm1 = body["modelGeneration"], body["dataWatermark"]
+            delta = [{"event": "view", "entityType": "user",
+                      "entityId": f"u{u}", "targetEntityType": "item",
+                      "targetEntityId": "i9"} for u in (0, 2, 99)]
+            req = Request(
+                f"http://127.0.0.1:{evsrv.port}/batch/events.json"
+                f"?accessKey={key}", data=json.dumps(delta).encode(),
+                method="POST", headers={"Content-Type": "application/json"})
+            with urlopen(req, timeout=10) as resp:
+                assert [r["status"] for r in json.loads(resp.read())] \
+                    == [201] * 3
+            latest = ctx.storage.get_events().latest_event_time(app_id)
+            for t in drivers:
+                t.start()
+            d = RefreshDaemon(
+                eng, variant, ctx,
+                config=RefreshConfig(interval_s=0.01, eval_tolerance=10.0),
+                promoter=HttpPromoter(base, canary_window_s=0.5,
+                                      canary_poll_s=0.05))
+            out = d.run_once()
+            assert out["promotion"] == "promoted"
+            before = len(outcomes)
+            deadline = time.monotonic() + 10.0
+            while len(outcomes) < before + 6 and time.monotonic() < deadline:
+                time.sleep(0.01)  # a few answers from the new generation
+            _, body = _http(base, "GET", "/")
+            assert body["modelGeneration"] == gen1 + 1
+            assert body["dataWatermark"] > wm1
+            assert staleness_s(latest, dt.datetime.fromisoformat(
+                body["dataWatermark"])) == 0.0
+        finally:
+            stop.set()
+            for t in drivers:
+                t.join(timeout=30)
+            evsrv.stop()
+            srv.stop()
+        assert len(outcomes) >= 12
+        assert {s for s, _ in outcomes} == {200}, outcomes
+        assert min(rem for _, rem in outcomes) >= 0.0
+
     def test_divergent_refresh_is_rejected_old_generation_serves(
             self, ctx, monkeypatch):
         """Injected divergent refresh: the staged-reload gate rejects the

@@ -583,6 +583,33 @@ class TestServerPluginSeam:
         assert "pio_plugin_requests_total" in samples
         assert "pio_event_requests_total" in samples
 
+    def test_plugin_header_with_a_name_that_is_no_token_is_dropped(
+            self, caplog):
+        """CR/LF were blanked already; a name that is empty or holds a
+        ':' or a space still came out as a malformed header line, on the
+        Python path (``send_header``) and the native one
+        (``header_block``)."""
+        from predictionio_tpu.server.plugins import (
+            PluginManager, ServerPlugin,
+        )
+
+        class Sloppy(ServerPlugin):
+            name = "sloppy"
+
+            def on_request(self, route, status, ms):
+                return {"": "empty", "X-A: b": "colon", "X B": "space",
+                        "X-Bad\r\nX-Evil": "crlf", "X-Good_1.a": "kept\r\n"}
+
+        pm = PluginManager([Sloppy()])
+        with caplog.at_level("WARNING",
+                             logger="predictionio_tpu.server.plugins"):
+            assert pm.on_request("GET /", 200, 1.0) == {"X-Good_1.a": "kept  "}
+        dropped = [r.getMessage() for r in caplog.records
+                   if "invalid name" in r.getMessage()]
+        assert pm.header_block("GET /", 200, 1.0) == "X-Good_1.a: kept  \r\n"
+        assert len(dropped) == 4 and all("sloppy" in m for m in dropped)
+        assert any("'X-A: b'" in m for m in dropped)
+
     def test_plugin_failure_does_not_break_requests(self, pio_home,
                                                     monkeypatch):
         import urllib.request

@@ -583,6 +583,56 @@ class TestEngineServerIntegration:
         finally:
             srv.stop()
 
+    def test_faulted_drive_sheds_504_and_never_serves_a_late_200(
+            self, trained, monkeypatch):
+        """Concurrent clients, a quarter of them on a budget the
+        injected handler delay overruns: what comes back is a 200 the
+        server attests as in time (remaining budget >= 0) or a 504,
+        through the real batcher and transport, never a late 200."""
+        from predictionio_tpu.resilience import faults
+        from predictionio_tpu.server import EngineServer
+
+        monkeypatch.setenv("PIO_RESULT_CACHE", "0")
+        eng, variant, storage, _ = trained
+        srv = EngineServer(eng, variant, storage, host="127.0.0.1", port=0)
+        srv.start()
+        url = f"http://127.0.0.1:{srv.port}/queries.json"
+        outcomes = []  # (status, budget sent, the server's remaining ms)
+
+        def client(i):
+            for k in range(10):
+                budget = 15 if (i + k) % 4 == 0 else 5000
+                req = urllib.request.Request(
+                    url, method="POST",
+                    data=json.dumps({"user": f"u{(i + k) % 8}",
+                                     "num": 2}).encode(),
+                    headers={"Content-Type": "application/json",
+                             "X-PIO-Deadline-Ms": str(budget)})
+                try:
+                    with urllib.request.urlopen(req, timeout=30) as resp:
+                        status, headers = resp.status, resp.headers
+                except urllib.error.HTTPError as e:
+                    status, headers = e.code, e.headers
+                outcomes.append((status, budget, float(
+                    headers["X-PIO-Deadline-Remaining-Ms"])))
+
+        _post(url, {"user": "u0", "num": 2})  # the first query's warm-up
+        faults.install("http.engine:delay:40ms")
+        try:
+            threads = [threading.Thread(target=client, args=(i,))
+                       for i in range(4)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+        finally:
+            faults.clear()
+            srv.stop()
+        assert len(outcomes) == 40
+        assert all(rem >= 0 for s, _, rem in outcomes if s == 200), outcomes
+        assert sorted((s, b) for s, b, _ in outcomes) \
+            == [(200, 5000)] * 30 + [(504, 15)] * 10, outcomes
+
     def test_admission_full_answers_429_with_retry_after(self, trained):
         from predictionio_tpu.server import EngineServer
 

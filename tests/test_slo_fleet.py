@@ -36,7 +36,6 @@ from predictionio_tpu.obs.fleet import (
 )
 from predictionio_tpu.obs.slo import SLOConfig, SLOEngine
 from predictionio_tpu.obs.waterfall import (
-    WALL_STAGES,
     Waterfall,
     begin_request,
     current_waterfall,
@@ -652,57 +651,6 @@ class TestFleetMerge:
             "pio_query_requests_total"] == 150.0   # no dip
         rows = {r["instance"]: r for r in doc["instances"]}
         assert rows["http://b"]["stale"] is True
-
-
-# --------------------------------------------------------------------------
-# tools/attribute_serve.py
-# --------------------------------------------------------------------------
-
-class TestAttributeServe:
-    def _tool(self):
-        import sys
-        from pathlib import Path
-        sys.path.insert(0, str(
-            Path(__file__).resolve().parents[1] / "tools"))
-        import attribute_serve
-        return attribute_serve
-
-    def test_metrics_exposition_names_dominant_stage(self, pio_home):
-        t = self._tool()
-        text = ('pio_serve_stage_ms_sum{stage="queue_wait"} 900\n'
-                'pio_serve_stage_ms_count{stage="queue_wait"} 10\n'
-                'pio_serve_stage_ms_sum{stage="dispatch"} 100\n'
-                'pio_serve_stage_ms_count{stage="dispatch"} 10\n')
-        res = t.attribute_metrics(t.parse_metrics(text))
-        assert res["dominant"] == "queue_wait"
-        assert "scale out" in res["attack"]
-
-    def test_retrieval_dominating_dispatch_redirects_the_attack(
-            self, pio_home):
-        t = self._tool()
-        rows = [{"stages": {"dispatch": 100.0, "retrieval": 80.0,
-                            "bind": 1.0}, "totalMs": 101.0}] * 4
-        res = t.attribute_log(rows)
-        assert res["dominant"] == "dispatch"
-        assert res["retrieval_share_of_dispatch"] == pytest.approx(0.8)
-        assert "rung" in res["attack"]
-
-    def test_wide_event_log_reconciliation(self, pio_home):
-        t = self._tool()
-        wall = 10.0 * len(WALL_STAGES)
-        attested = wall - 10.0  # serialize lies outside the header
-        rows = [{"stages": {s: 10.0 for s in WALL_STAGES},
-                 "totalMs": wall + 2.0, "serverMs": attested + 1.0}
-                for _ in range(9)]
-        res = t.attribute_log(rows)
-        rec = res["reconciliation"]
-        assert rec["stage_sum_p50_ms"] == pytest.approx(wall)
-        assert rec["total_p50_ms"] == pytest.approx(wall + 2.0)
-        assert 0.9 <= rec["ratio"] <= 1.1
-        # the attested comparison drops serialize (outside the header)
-        assert rec["attested_stage_sum_p50_ms"] == pytest.approx(attested)
-        assert rec["server_attested_p50_ms"] == pytest.approx(attested + 1.0)
-        assert 0.9 <= rec["attested_ratio"] <= 1.1
 
 
 # --------------------------------------------------------------------------

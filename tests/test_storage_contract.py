@@ -426,6 +426,40 @@ class TestEventsContract:
         assert n == 2
         assert all(e.event_time is not None for e in ev.find(APP))
 
+    def test_insert_columnar_null_event_time_is_the_rows_creation_time(
+            self, events_backend):
+        """A null ``event_time_us`` takes its own row's creation time, as a
+        missing column does, not the server clock at insert."""
+        import pyarrow as pa
+
+        ev = events_backend
+        ev.init(APP)
+        old = 1_600_000_000_000_000  # 2020: far from any "now"
+        ev.insert_columnar(
+            pa.table({"event": ["x", "y", "z"], "entity_type": ["u"] * 3,
+                      "entity_id": ["1", "2", "3"],
+                      "event_time_us": pa.array([old + 7, None, None]),
+                      "creation_time_us": pa.array([old, old + 1, None])}),
+            APP)
+        got = {e.entity_id: e for e in ev.find(APP)}
+        assert got["1"].event_time == got["1"].creation_time \
+            + dt.timedelta(microseconds=7)
+        assert got["2"].event_time == got["2"].creation_time
+        assert got["2"].creation_time.year == 2020
+        # no creation time either: both are the one server-clock reading
+        assert got["3"].event_time == got["3"].creation_time
+        assert got["3"].creation_time.year > 2020
+
+    def test_find_columnar_refuses_an_unknown_column(self, events_backend):
+        ev = events_backend
+        ev.init(APP)
+        ev.insert(_mk("rate", "u1", "2026-01-01T00:00:00", target="i1"), APP)
+        with pytest.raises(StorageError, match="unknown column.*'rating'"):
+            ev.find_columnar(APP, columns=["entity_id", "rating"])
+        with pytest.raises(StorageError, match="unknown column"):
+            ev.find_columnar(APP, columns=["entity_id", "rating"],
+                             ordered=False, event_names=["none-such"])
+
     def test_aggregate_properties(self, events_backend):
         ev = events_backend
         ev.init(APP)

@@ -495,6 +495,64 @@ def test_reload_under_total_storage_outage_serves_last_good(pio_home):
     assert body["lastReload"]["result"] == "failed"
 
 
+def test_reload_mid_drive_under_storage_outage_answers_every_query(pio_home):
+    """The outage above with clients on the wire: concurrent HTTP
+    predicts keep their 200s while a ``POST /reload`` lands among them
+    and fails closed, and the generation they are served from does not
+    move."""
+    import threading
+    import urllib.error
+    import urllib.request
+
+    from predictionio_tpu.data.storage import get_storage
+
+    srv, *_ = _trained_server(get_storage())
+    srv.start()
+    base = f"http://127.0.0.1:{srv.port}"
+    gen0 = srv._generation
+    stop = threading.Event()
+    statuses = []
+
+    def post(path, payload):
+        req = urllib.request.Request(
+            base + path, data=json.dumps(payload).encode(), method="POST",
+            headers={"Content-Type": "application/json"})
+        try:
+            with urllib.request.urlopen(req, timeout=30) as resp:
+                return resp.status
+        except urllib.error.HTTPError as e:
+            return e.code
+
+    def drive(i):
+        k = i
+        while not stop.is_set():
+            statuses.append(post("/queries.json",
+                                 {"user": f"u{k % 30}", "num": 3}))
+            k += 1
+
+    drivers = [threading.Thread(target=drive, args=(i,), daemon=True)
+               for i in range(3)]
+    faults.install("storage.find:error:1.0")
+    try:
+        for t in drivers:
+            t.start()
+        reloads = []
+        for _ in range(3):
+            seen = len(statuses)
+            reloads.append(post("/reload", {}))
+            while len(statuses) < seen + 6:  # predicts after each reload
+                stop.wait(0.005)
+    finally:
+        stop.set()
+        for t in drivers:
+            t.join(timeout=30)
+        faults.clear()
+        srv.stop()
+    assert reloads == [503, 503, 503]
+    assert srv._generation == gen0
+    assert len(statuses) >= 18 and set(statuses) == {200}, statuses
+
+
 def test_reload_swaps_and_rollback_restores_previous_generation(pio_home):
     from predictionio_tpu.data.storage import get_storage
     from predictionio_tpu.workflow.core_workflow import run_train

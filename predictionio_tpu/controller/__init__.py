@@ -21,6 +21,7 @@ from predictionio_tpu.controller.base import (
     model_from_bytes,
     model_to_bytes,
 )
+from predictionio_tpu.controller.columns import ItemScoreColumns
 from predictionio_tpu.controller.engine import (
     Engine,
     EngineParams,
@@ -57,6 +58,7 @@ __all__ = [
     "Evaluation",
     "FirstServing",
     "IdentityPreparator",
+    "ItemScoreColumns",
     "Metric",
     "MetricEvaluatorResult",
     "OptionAverageMetric",

@@ -373,6 +373,13 @@ class BiMap(Generic[K]):
         inv._rev = self._fwd
         return inv
 
+    def keys_of(self, values: Iterable[Any]) -> List[K]:
+        """The key of each value, in order: ``[inverse[v] for v in
+        values]`` in one pass over the reverse dict (a cohort's item ids
+        back to item strings, no ``inverse`` map made for the call)."""
+        rev = self._rev
+        return [rev[v] for v in values]
+
     def to_numpy_keys(self) -> np.ndarray:
         """Keys ordered by their int value — decode table for device ids."""
         items = sorted(self._fwd.items(), key=lambda kv: kv[1])

@@ -58,7 +58,7 @@ import os
 import threading
 import time
 import weakref
-from typing import Any, Dict, Iterator, Optional, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -87,7 +87,7 @@ from predictionio_tpu.retrieval.pq import (
 logger = logging.getLogger(__name__)
 
 __all__ = ["Retriever", "Plan", "cached_retriever", "arm_on_create",
-           "iter_hits",
+           "iter_hits", "hit_columns",
            "build_train_index", "build_train_pq", "IVFIndex",
            "PQCodebook", "build_ivf", "build_pq",
            "corpus_fingerprint", "K_MENU"]
@@ -665,8 +665,10 @@ def _pow2_pad(q: np.ndarray) -> np.ndarray:
 
 def iter_hits(scores_row, ids_row, num: int) -> Iterator[Tuple[int, float]]:
     """(item_id, score) pairs of one result row, sentinel-padding
-    skipped, at most ``num`` — the one loop every template's
-    result-building shares."""
+    skipped, at most ``num``.  The rule of what a row answers, for the
+    single-row callers that filter as they go; a cohort's rows leave
+    through :func:`hit_columns`, which is this rule a cohort at a
+    time."""
     taken = 0
     for s, i in zip(scores_row, ids_row):
         if taken >= num:
@@ -675,6 +677,33 @@ def iter_hits(scores_row, ids_row, num: int) -> Iterator[Tuple[int, float]]:
             continue
         yield int(i), float(s)
         taken += 1
+
+
+def hit_columns(scores: np.ndarray, ids: np.ndarray, nums: Sequence[int]
+                ) -> List[Tuple[List[int], List[float]]]:
+    """Every row of a cohort's ``scores[B, K]``, ``ids[B, K]`` as
+    ``(item ids, scores)`` Python lists: row ``r`` holds what
+    ``iter_hits(scores[r], ids[r], nums[r])`` yields, as two columns.
+
+    One ``tolist`` an array for the whole cohort (it widens a float32
+    as ``float`` does) and one vector mask for the padding: a row with
+    none in its first ``nums[r]`` columns is a slice, and only a row
+    that has some is walked by :func:`iter_hits`."""
+    if not len(nums):
+        return []
+    width = min(max(max(nums), 0), scores.shape[1])
+    if not width:
+        return [([], []) for _ in nums]
+    s, i = scores[:, :width], ids[:, :width]
+    padding = (i < 0) | (s <= _NEG_SENTINEL)
+    out = [(ir, sr) if n >= width else (ir[:max(n, 0)], sr[:max(n, 0)])
+           for ir, sr, n in zip(i.tolist(), s.tolist(), nums)]
+    if padding.any():
+        first = np.where(padding.any(axis=1), padding.argmax(axis=1), width)
+        for r in np.flatnonzero(first < np.minimum(nums, width)):
+            hits = list(iter_hits(scores[r], ids[r], nums[r]))
+            out[r] = ([h[0] for h in hits], [h[1] for h in hits])
+    return out
 
 
 # -- per-model retriever cache ----------------------------------------------

@@ -25,6 +25,7 @@ from typing import Any, Dict, List, Optional, Tuple
 from urllib.parse import urlparse
 
 from predictionio_tpu.controller import Engine, EngineVariant, RuntimeContext
+from predictionio_tpu.controller.columns import dispatch_tally
 from predictionio_tpu.controller.params import bind_params
 from predictionio_tpu.data.storage import (
     Storage,
@@ -87,11 +88,12 @@ _DC_FIELDS: Dict[type, Tuple[str, ...]] = {}
 def _dc_to_json(obj: Any) -> Any:
     """Shallow-recursive dataclass→dict for predicted results.
 
-    ``dataclasses.asdict`` was 32% of the serving hot path — its generic
-    deep-copy walks every value through ``_asdict_inner``.  This cached-
-    field walk keeps asdict's JSON-visible contract (dataclasses nested
-    in lists/tuples/dict values convert; tuples serialize as arrays)
-    without the deep copies of leaf values.
+    Keeps ``dataclasses.asdict``'s JSON-visible contract (dataclasses
+    nested in lists/tuples/dict values convert; tuples serialize as
+    arrays) by a cached-field walk, without asdict's deep copy of every
+    leaf value.  A value that has its own JSON form (``pio_json``: a
+    template's :class:`~predictionio_tpu.controller.ItemScoreColumns`)
+    is asked for it and not walked.
     """
     fields = _DC_FIELDS.get(type(obj))
     if fields is None:
@@ -101,6 +103,9 @@ def _dc_to_json(obj: Any) -> Any:
 
 
 def _val_to_json(v: Any) -> Any:
+    render = getattr(type(v), "pio_json", None)
+    if render is not None:
+        return render(v)
     if dataclasses.is_dataclass(v) and not isinstance(v, type):
         return _dc_to_json(v)
     if isinstance(v, (list, tuple)):
@@ -108,6 +113,26 @@ def _val_to_json(v: Any) -> Any:
     if isinstance(v, dict):
         return {k: _val_to_json(x) for k, x in v.items()}
     return v
+
+
+def _by_query(answers: List[List[Tuple[int, Any]]], n: int
+              ) -> List[List[Any]]:
+    """``out[i]``: every algorithm's prediction for query ``i``, from
+    each algorithm's ``batch_predict`` pairs.  Pairs that answer every
+    index in order (no cold member was moved to the front) are read as
+    they stand; any others go through a dict."""
+    in_order = list(range(n))
+    per_algo = []
+    for pairs in answers:
+        pairs = list(pairs)
+        if [i for i, _ in pairs] == in_order:
+            per_algo.append([p for _, p in pairs])
+        else:
+            by_index = dict(pairs)
+            per_algo.append([by_index[i] for i in in_order])
+    if not per_algo:   # an engine.json with no algorithm
+        return [[] for _ in in_order]
+    return [list(ps) for ps in zip(*per_algo)]
 
 
 class QueryError(ValueError):
@@ -130,6 +155,18 @@ class _QueryMetrics:
             "pio_deadline_shed_total",
             "Requests shed with 504 because their deadline expired.",
             ("server",))
+        self.items = self.registry.counter(
+            "pio_dispatch_items_total",
+            "Items of batched dispatches' answers: form=columns left as "
+            "JSON rendered from the template's two columns, form=objects "
+            "had an ItemScore object built on the way.", ("form",))
+
+    def count_items(self, columns: int, objects: int) -> None:
+        """One dispatch's answered items, by the form they left in."""
+        if columns:
+            self.items.inc(columns, form="columns")
+        if objects:
+            self.items.inc(objects, form="objects")
 
     def record(self, ms: float, ok: bool) -> None:
         self.requests.inc()
@@ -655,14 +692,16 @@ class EngineServer:
                 cache = getattr(m, "state_cache", None)
                 if cache is not None:
                     held.enter_context(cache.transaction())
-            per_algo = [dict(a.batch_predict(m, indexed))
-                        for a, m in zip(algorithms, models)]
+            tally = dispatch_tally()
+            tally.columns = tally.objects = 0
+            predictions = _by_query(
+                [a.batch_predict(m, indexed)
+                 for a, m in zip(algorithms, models)], len(indexed))
             with dispatch_stage("dispatch.serve", "serve"):
-                return [
-                    self._result_to_json(
-                        serving.serve(q, [pa[i] for pa in per_algo]))
-                    for i, q in indexed
-                ], generation
+                out = [self._result_to_json(serving.serve(q, ps))
+                       for q, ps in zip(queries, predictions)]
+            self.stats.count_items(tally.columns, tally.objects)
+            return out, generation
 
     def query_batch(self, query_jsons: List[Any]) -> List[Any]:
         """Batched predict (native frontend, ``pio batchpredict``): the

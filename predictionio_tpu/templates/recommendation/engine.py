@@ -33,6 +33,7 @@ from predictionio_tpu.controller import (
     DataSource,
     Engine,
     FirstServing,
+    ItemScoreColumns,
     Preparator,
     RuntimeContext,
     WarmStartFallback,
@@ -53,7 +54,7 @@ from predictionio_tpu.retrieval import (
     build_train_index,
     build_train_pq,
     cached_retriever,
-    iter_hits,
+    hit_columns,
 )
 
 __all__ = [
@@ -87,7 +88,9 @@ class ItemScore:
 
 @dataclasses.dataclass
 class PredictedResult:
-    itemScores: List[ItemScore]  # noqa: N815 — reference JSON field name
+    # From ``batch_predict`` an ItemScoreColumns: a list of ItemScore to
+    # whoever reads it, two columns to the JSON.
+    itemScores: Sequence[ItemScore]  # noqa: N815 — reference JSON field name
 
 
 # -- training data ----------------------------------------------------------
@@ -991,6 +994,13 @@ class ALSAlgorithm(Algorithm):
         trained users, so fold-in costs one extra query row, not a
         second dispatch.  Users with no usable events still answer the
         cold-start empty result.
+
+        The cohort's answers leave as columns: the facade's two arrays
+        become per-row Python lists in one pass
+        (:func:`~predictionio_tpu.retrieval.hit_columns`), the item ids
+        their strings in another, and each ``itemScores`` is an
+        :class:`~predictionio_tpu.controller.ItemScoreColumns` over
+        them; no ``ItemScore`` exists until somebody reads one.
         """
         with dispatch_stage("predict.lookup", "lookup"):
             known = [(i, q) for i, q in queries
@@ -1024,12 +1034,14 @@ class ALSAlgorithm(Algorithm):
                 if len(qmat_parts) > 1 else qmat_parts[0]
         scores, ids, _info = model.retriever().topk(qmat, num)
         with dispatch_stage("predict.assemble", "assemble"):
-            inv = model.item_index.inverse
-            for row, (i, q) in enumerate(answerable):
-                out.append((i, PredictedResult(itemScores=[
-                    ItemScore(item=inv[ii], score=ss)
-                    for ii, ss in iter_hits(scores[row], ids[row], q.num)
-                ])))
+            keys_of = model.item_index.keys_of
+            columns = hit_columns(scores, ids,
+                                  [q.num for _, q in answerable])
+            out.extend(
+                (i, PredictedResult(itemScores=ItemScoreColumns(
+                    keys_of(item_ids), item_scores, ItemScore)))
+                for (i, _), (item_ids, item_scores)
+                in zip(answerable, columns))
         return out
 
 

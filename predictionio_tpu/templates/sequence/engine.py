@@ -40,6 +40,7 @@ from predictionio_tpu.controller import (
     DataSource,
     Engine,
     FirstServing,
+    ItemScoreColumns,
     Preparator,
     RuntimeContext,
 )
@@ -74,7 +75,9 @@ class ItemScore:
 
 @dataclasses.dataclass
 class PredictedResult:
-    itemScores: List[ItemScore]  # noqa: N815 — reference JSON field name
+    # From ``batch_predict`` an ItemScoreColumns: a list of ItemScore to
+    # whoever reads it, two columns to the JSON.
+    itemScores: Sequence[ItemScore]  # noqa: N815 — reference JSON field name
 
 
 @dataclasses.dataclass
@@ -353,8 +356,11 @@ class SequenceAlgorithm(Algorithm):
         against its user's cached state, in arrival order (two turns of
         one user share a segment; the second sees the first).  The state
         moves only if the whole transaction does: inside the engine
-        server that is the dispatch, here the call."""
+        server that is the dispatch, here the call.  The answers leave
+        as :class:`~predictionio_tpu.controller.ItemScoreColumns`: no
+        ``ItemScore`` exists until somebody reads one."""
         from predictionio_tpu.models.lfm2 import Turn
+        from predictionio_tpu.retrieval import hit_columns
 
         runtime = model.runtime()
         cache = runtime.cache
@@ -381,11 +387,22 @@ class SequenceAlgorithm(Algorithm):
                     turns.append(Turn(key, items, max(int(q.num), 1)))
             answers = runtime.extend(turns, prefill)
             with dispatch_stage("predict.assemble", "assemble"):
-                inv = model.item_index.inverse
-                return [(i, PredictedResult(itemScores=[
-                    ItemScore(item=inv[int(ii)], score=float(ss))
-                    for ss, ii in zip(scores[:q.num], ids[:q.num])]))
-                    for (i, q), (scores, ids) in zip(queries, answers)]
+                # A turn's answer is as long as its own ``num`` (none
+                # for a user with no event): one block, short rows
+                # padded as the retrieval facade pads.
+                k = max(len(s) for s, _ in answers)
+                scores = np.full((len(answers), k), -np.inf, np.float32)
+                ids = np.full((len(answers), k), -1, np.int32)
+                for row, (s, i) in enumerate(answers):
+                    scores[row, :len(s)] = s
+                    ids[row, :len(i)] = i
+                keys_of = model.item_index.keys_of
+                columns = hit_columns(scores, ids,
+                                      [q.num for _, q in queries])
+                return [(i, PredictedResult(itemScores=ItemScoreColumns(
+                    keys_of(item_ids), item_scores, ItemScore)))
+                    for (i, _), (item_ids, item_scores)
+                    in zip(queries, columns)]
 
 
 def engine() -> Engine:

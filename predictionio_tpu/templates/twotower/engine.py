@@ -27,6 +27,7 @@ from predictionio_tpu.controller import (
     Engine,
     FirstServing,
     IdentityPreparator,
+    ItemScoreColumns,
     RuntimeContext,
     WarmStartFallback,
 )
@@ -45,7 +46,7 @@ from predictionio_tpu.retrieval import (
     build_train_index,
     build_train_pq,
     cached_retriever,
-    iter_hits,
+    hit_columns,
 )
 
 __all__ = [
@@ -69,7 +70,9 @@ class ItemScore:
 
 @dataclasses.dataclass
 class PredictedResult:
-    itemScores: List[ItemScore]  # noqa: N815
+    # From ``batch_predict`` an ItemScoreColumns: a list of ItemScore to
+    # whoever reads it, two columns to the JSON.
+    itemScores: Sequence[ItemScore]  # noqa: N815
 
 
 @dataclasses.dataclass
@@ -381,7 +384,9 @@ class TwoTowerAlgorithm(Algorithm):
         All routing (host fast path, mesh-sharded / chunked device
         scoring, the train-time IVF index, pow2 batch + K-menu compile
         discipline) lives in :mod:`predictionio_tpu.retrieval` — this
-        template only maps ids.
+        template only maps ids, a cohort at a time: the answers leave as
+        :class:`~predictionio_tpu.controller.ItemScoreColumns`, and no
+        ``ItemScore`` exists until somebody reads one.
         """
         known = [(i, q) for i, q in queries
                  if model.user_index.get(q.user) is not None]
@@ -393,11 +398,12 @@ class TwoTowerAlgorithm(Algorithm):
         idxs = np.asarray([model.user_index[q.user] for _, q in known])
         scores, ids, _info = model.retriever().topk(
             model.user_vecs[idxs], num)
-        inv = model.item_index.inverse
-        for row, (i, q) in enumerate(known):
-            out.append((i, PredictedResult(itemScores=[
-                ItemScore(item=inv[ii], score=ss)
-                for ii, ss in iter_hits(scores[row], ids[row], q.num)])))
+        keys_of = model.item_index.keys_of
+        columns = hit_columns(scores, ids, [q.num for _, q in known])
+        out.extend(
+            (i, PredictedResult(itemScores=ItemScoreColumns(
+                keys_of(item_ids), item_scores, ItemScore)))
+            for (i, _), (item_ids, item_scores) in zip(known, columns))
         return out
 
 

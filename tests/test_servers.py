@@ -482,7 +482,7 @@ def test_dc_to_json_matches_asdict_on_wire():
     (tuples become JSON arrays either way)."""
     import dataclasses
     import json
-    from typing import Dict, List, Tuple
+    from typing import Any, Dict, List, Tuple
 
     from predictionio_tpu.server.engine_server import _dc_to_json
 
@@ -502,6 +502,31 @@ def test_dc_to_json_matches_asdict_on_wire():
               n=Inner(4), s="z")
     assert json.dumps(_dc_to_json(o), sort_keys=True) == \
         json.dumps(dataclasses.asdict(o), sort_keys=True)
+
+    # A value with a JSON form of its own (a template's item columns) is
+    # asked for it wherever it sits: as a field, in a list, as a dict
+    # value.  The wire is the list of dataclasses it stands for.
+    from predictionio_tpu.controller import ItemScoreColumns
+
+    @dataclasses.dataclass
+    class Hit:
+        item: str
+        score: float
+
+    @dataclasses.dataclass
+    class Shelf:
+        top: Any
+        rows: List[Any]
+        by_name: Dict[str, Any]
+
+    def shelf(hits):
+        return Shelf(top=hits(), rows=[hits(), hits()],
+                     by_name={"k": hits()})
+
+    columns = shelf(lambda: ItemScoreColumns(["a", "b"], [1.0, 0.5], Hit))
+    plain = shelf(lambda: [Hit("a", 1.0), Hit("b", 0.5)])
+    assert json.dumps(_dc_to_json(columns)) == \
+        json.dumps(dataclasses.asdict(plain))
 
 
 class TestServerPluginSeam:

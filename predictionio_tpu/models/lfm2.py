@@ -20,10 +20,10 @@ users against each user's cached state:
   the users' fixed slots; new ``k``/``v`` rows written into the users'
   pages, attention over the pages block by block with an online
   softmax), and scores the vocabulary at each turn's last event.
-* :class:`SequenceRuntime` — packs turns into token buckets
-  (:func:`predictionio_tpu.ops.ragged.pack_turns`), plans slots and
-  pages with the :class:`~predictionio_tpu.serving.state_cache.StateCache`,
-  and runs the programs; a turn longer than a bucket is taken in chunks.
+* :class:`LFM2Step` — what
+  :class:`~predictionio_tpu.models.seq_runtime.SequenceRuntime` (turns ->
+  dispatches) asks of a backbone: the program of a shape, a dispatch's
+  arrays, the reading of what comes back.
 
 Weights and stored state are bfloat16; the residual stream, the router,
 norms, softmax and every accumulation are float32.  Weight layout: where
@@ -37,26 +37,26 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import gc
 import math
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from predictionio_tpu.obs import dispatch_stage, get_registry
+from predictionio_tpu.models import seq_runtime
+from predictionio_tpu.models.seq_runtime import K_MENU, Turn
+from predictionio_tpu.obs import get_registry
 from predictionio_tpu.ops.pallas_kernels import pallas_supported
-from predictionio_tpu.ops.ragged import TurnPack, pack_turns
+from predictionio_tpu.ops.ragged import TurnPack
 
 __all__ = ["LFM2Config", "init_params", "extend_step", "SequenceRuntime",
-           "Turn", "TOKEN_BUCKETS", "READ_BUCKETS", "K_MENU"]
+           "LFM2Step", "Turn", "TOKEN_BUCKETS", "READ_BUCKETS", "K_MENU"]
 
-# Shapes a program is compiled for: new tokens in a dispatch, turns that
-# read an answer, and the answer's width.
+# Shapes a program is compiled for: new tokens in a dispatch and turns
+# that read an answer (the answer's widths: ``seq_runtime.K_MENU``).
 TOKEN_BUCKETS = (64, 256, 1024)
 READ_BUCKETS = (8, 64)
-K_MENU = (16, 128, 1024)
 # Pages the attention loop takes per step (keys = this x page size).
 PAGES_PER_BLOCK = 4
 ROUTER_EPS = 1e-6
@@ -438,7 +438,7 @@ def extend_step(params: Dict[str, Any], state: Dict[str, Any],
     assignments of each expert [n_moe, E]).
 
     ``state``: ``conv`` [n_conv, slots, 2, d] and ``h_last`` [slots, d]
-    (fixed slots; a user reads one slot and writes its twin), ``k`` and
+    (fixed slots; a user reads one slot and writes another), ``k`` and
     ``v``: a pool per attention layer.  ``batch`` (int32): ``tokens``,
     ``tok_seg`` (-1 = padding), ``tok_pos``, ``tok_idx`` (index within
     the segment), ``tok_row`` (pool row the token's k/v go to) [T];
@@ -490,10 +490,6 @@ def extend_step(params: Dict[str, Any], state: Dict[str, Any],
     return new_state, scores, ids, loads
 
 
-# One upload and one download a dispatch: every hand-over between the
-# batcher's thread and the runtime lets the server's handler threads take
-# the interpreter, so the int32 arrays of a batch travel as one vector
-# and the answers come back as one.
 _TOKEN_KEYS = ("tokens", "tok_seg", "tok_pos", "tok_idx", "tok_row",
                "seg_read", "seg_write", "seg_last")
 _PAGE_KEYS = ("ids", "seg", "base")
@@ -540,62 +536,17 @@ def extend_packed(params, state, vec, *, cfg: LFM2Config, page_size: int,
         loads.astype(jnp.int32).reshape(-1)])
 
 
-# -- the runtime: turns -> dispatches ---------------------------------------
+# -- the runtime's side ------------------------------------------------------
 
-@dataclasses.dataclass
-class Turn:
-    """One query's part of a dispatch: the user's new item ids (oldest
-    first; may be empty) and how many answers it wants."""
+class LFM2Step:
+    """This backbone as the sequence runtime drives it."""
 
-    key: Any
-    items: np.ndarray
-    num: int = 10
+    token_buckets = TOKEN_BUCKETS
+    read_buckets = READ_BUCKETS
 
-
-def _bucket(n: int, menu: Sequence[int]) -> int:
-    for b in menu:
-        if n <= b:
-            return b
-    raise ValueError(f"{n} is over the largest bucket {menu[-1]}")
-
-
-def _settle_heap() -> None:
-    """After a program's first run.  Tracing and compiling leave about
-    100k objects that live as long as the runtime, beside the 170k of
-    the imports and the model, and a full pass of the cycle collector
-    over them stops every thread: 105 ms once in ~4,000 requests on the
-    chip (``PERF.md``, finding 8 of PR 28).  One pass now, on a call
-    that has just paid a compile, then ``gc.freeze`` keeps later passes
-    to what requests leave behind.  The unfreeze first lets the pass
-    after a reload take the model before."""
-    gc.unfreeze()
-    gc.collect()
-    gc.freeze()
-
-
-class SequenceRuntime:
-    """Runs turns against a :class:`StateCache`.  One per loaded model;
-    callers hold the cache's transaction around :meth:`extend` (the
-    engine server does, for a whole dispatch)."""
-
-    def __init__(self, cfg: LFM2Config, params: Dict[str, Any], cache):
+    def __init__(self, cfg: LFM2Config):
         self.cfg = cfg
-        self.params = params
-        self.cache = cache
-        # The program shapes (tests set smaller ones to split a turn
-        # over dispatches with a short history).
-        self.token_buckets = TOKEN_BUCKETS
-        self.read_buckets = READ_BUCKETS
-        self._programs: Dict[Tuple[int, int, int], Any] = {}
         reg = get_registry()
-        self._m_tokens = reg.counter(
-            "pio_seq_tokens_total",
-            "Events run through the sequence backbone, by kind: new (a "
-            "turn's own) or prefill (a history re-read after a miss).",
-            ("kind",))
-        self._m_dispatches = reg.counter(
-            "pio_seq_dispatches_total",
-            "Device programs the sequence runtime launched.")
         self._m_assign = reg.counter(
             "pio_moe_assignments_total",
             "Token-to-expert assignments, by expert layer.", ("layer",))
@@ -616,78 +567,22 @@ class SequenceRuntime:
             "Assignments of the busiest expert, summed over dispatches, "
             "by expert layer.", ("layer",))
 
-    def program(self, t: int, r: int, k: int):
-        key = (t, r, k)
-        fn = self._programs.get(key)
-        if fn is None:
-            fn = jax.jit(functools.partial(
-                extend_packed, cfg=self.cfg, page_size=self.cache.page_size,
-                k=k, t=t, r=r, p_len=self.cache.page_list_len),
-                donate_argnums=(1,))
-            self._programs[key] = fn
-        return fn
+    def program(self, cache, t: int, r: int, k: int):
+        return jax.jit(functools.partial(
+            extend_packed, cfg=self.cfg, page_size=cache.page_size,
+            k=k, t=t, r=r, p_len=cache.page_list_len),
+            donate_argnums=(1,))
 
-    def extend(self, turns: Sequence[Turn], prefill: Optional[Dict[Any, int]]
-               = None) -> List[Tuple[np.ndarray, np.ndarray]]:
-        """Apply ``turns`` in order and answer each: ``(scores, item
-        ids)`` of its top ``num``, at its last event (a turn with no
-        event answers from the user's state as it stands; a user with no
-        event at all gets empty arrays).  ``prefill[key]``: how many of
-        the key's first items are a re-read history, for the counters."""
-        k = min(_bucket(max([t.num for t in turns] + [1]), K_MENU),
-                self.cfg.vocab_size)
-        out: List[Tuple[np.ndarray, np.ndarray]] = [
-            (np.zeros(0, np.float32), np.zeros(0, np.int32))] * len(turns)
-        # A turn has an answer if its user has any event by then: in the
-        # cache, or brought by this or an earlier turn of the call.
-        known = set()
-        pending = []
-        for i, turn in enumerate(turns):
-            if len(turn.items) or turn.key in known \
-                    or self.cache.length(turn.key):
-                known.add(turn.key)
-                pending.append((i, turn.key,
-                                np.asarray(turn.items, np.int32)))
-        for pack in pack_turns(pending, max_tokens=self.token_buckets[-1],
-                               max_reads=self.read_buckets[-1],
-                               max_pages=self.cache.page_list_len,
-                               pages_of=self.cache.pages_after):
-            scores, ids = self._run(pack, k)
-            for row, i in enumerate(pack.read_turn):
-                n = turns[i].num
-                out[i] = (scores[row, :n], ids[row, :n])
-        n_pre = sum((prefill or {}).values())
-        n_new = sum(len(items) for _, _, items in pending) - n_pre
-        if n_pre:
-            self._m_tokens.inc(n_pre, kind="prefill")
-        if n_new:
-            self._m_tokens.inc(n_new, kind="new")
-        return out
+    def batch_vector(self, pack: TurnPack, plan, t: int, r: int, cache
+                     ) -> np.ndarray:
+        return pack_batch(_batch_arrays(pack, plan, t, r, cache))
 
-    def _run(self, pack: TurnPack, k: int
-             ) -> Tuple[np.ndarray, np.ndarray]:
-        cache = self.cache
-        t = _bucket(pack.n_tokens, self.token_buckets)
-        r = _bucket(len(pack.read_turn), self.read_buckets)
-        with dispatch_stage("seq.extend", "seq_extend"):
-            with dispatch_stage("seq.extend.h2d", "seq_h2d"):
-                plan = cache.plan(pack.seg_key, pack.seg_len)
-                batch = jax.device_put(pack_batch(
-                    _batch_arrays(pack, plan, t, r, cache)))
-            with dispatch_stage("seq.extend.launch", "seq_launch"):
-                first_run = (t, r, k) not in self._programs
-                fn = self.program(t, r, k)
-                _, out = cache.run(fn, self.params, batch)
-            with dispatch_stage("seq.extend.wait", "seq_wait"):
-                out = np.asarray(jax.device_get(out))
-            cache.stage(plan)
-        if first_run:
-            _settle_heap()
+    def read_out(self, out: np.ndarray, r: int, k: int, plan
+                 ) -> Tuple[np.ndarray, np.ndarray]:
         n = r * k
         scores = out[:n].view(np.float32).reshape(r, k)
         ids = out[n:2 * n].reshape(r, k)
         loads = out[2 * n:].reshape(self.cfg.n_moe, self.cfg.num_experts)
-        self._m_dispatches.inc()
         self._m_keys.inc(sum(int(n) * int(at) + int(n) * (int(n) + 1) // 2
                              for n, at in zip(plan.seg_len,
                                               plan.seg_start)))
@@ -698,6 +593,61 @@ class SequenceRuntime:
             self._m_slots.inc(loads.shape[1], layer=layer)
             self._m_load_max.inc(int(loads[j].max()), layer=layer)
         return scores, ids
+
+
+class SequenceRuntime(seq_runtime.SequenceRuntime):
+    """The sequence runtime over this backbone."""
+
+    def __init__(self, cfg: LFM2Config, params: Dict[str, Any], cache):
+        super().__init__(LFM2Step(cfg), params, cache)
+
+
+def state_layout(cfg: LFM2Config, page_size: int) -> Dict[str, Any]:
+    """What the :class:`~predictionio_tpu.serving.state_cache.StateCache`
+    holds for this model, all bfloat16: a slot is the conv layers' last
+    ``L - 1`` = 2 rows (layers stacked, ``[n_conv, slots, 2, d]``) and
+    the last hidden row; a page is ``page_size`` rows of keys and of
+    values an attention layer (a pool a layer, ``[1 + pages, page_size,
+    kv width]``)."""
+    dtype, d, w = jnp.bfloat16, cfg.hidden_size, cfg.kv_width
+
+    def allocate(n_slots: int, n_pages: int) -> Dict[str, Any]:
+        pool = (1 + n_pages, page_size, w)
+        return {"conv": jnp.zeros((cfg.n_conv, n_slots, 2, d), dtype),
+                "h_last": jnp.zeros((n_slots, d), dtype),
+                "k": [jnp.zeros(pool, dtype) for _ in range(cfg.n_attn)],
+                "v": [jnp.zeros(pool, dtype) for _ in range(cfg.n_attn)]}
+    return {"fixed_bytes": 2 * (2 * cfg.n_conv + 1) * d,
+            "paged_bytes": 2 * 2 * cfg.n_attn * page_size * w,
+            "allocate": allocate}
+
+
+def make_runtime(cfg: LFM2Config, params: Dict[str, Any], *,
+                 budget_bytes: int, max_users: int) -> SequenceRuntime:
+    """The device side of a loaded model: serving-precision weights and
+    the state cache within its budget, its write side as large as the
+    users one program can touch."""
+    from predictionio_tpu.serving.state_cache import PAGE_SIZE, StateCache
+
+    cache = StateCache(
+        state_layout(cfg, PAGE_SIZE), budget_bytes=budget_bytes,
+        max_users=max_users, write_slots=READ_BUCKETS[-1])
+    return SequenceRuntime(cfg, cast_for_serving(params), cache)
+
+
+def config_from_params(p, vocab_size: int) -> LFM2Config:
+    """The backbone's shape from the sequence template's algorithm
+    params."""
+    return LFM2Config(
+        vocab_size=vocab_size, hidden_size=p.hiddenSize,
+        intermediate_size=p.intermediateSize,
+        moe_intermediate_size=p.moeIntermediateSize,
+        num_experts=p.numExperts, num_experts_per_tok=p.numExpertsPerTok,
+        num_attention_heads=p.numAttentionHeads,
+        num_key_value_heads=p.numKeyValueHeads,
+        layer_types=tuple(p.layerTypes),
+        dense_ff=tuple(i < p.numDenseLayers
+                       for i in range(len(p.layerTypes))))
 
 
 def _batch_arrays(pack: TurnPack, plan, t: int, r: int, cache

@@ -679,7 +679,10 @@ class EngineServer:
         first ``batch_predict`` until ``serve`` has answered every
         member, so a dispatch that raises anywhere leaves every user's
         state as it was, and the batcher's member-by-member retry of a
-        failed cohort applies no event twice."""
+        failed cohort applies no event twice.  (That holds for as many
+        users a dispatch as the cache's write pool, which is the
+        scheduler's largest cohort; a larger ``query_batch`` call
+        commits device program by device program.)"""
         with self._swap_lock:
             algorithms, models, serving, generation = (
                 self._algorithms, self._models, self._serving,

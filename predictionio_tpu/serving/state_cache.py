@@ -1,29 +1,54 @@
-"""Per-user serving state of a sequence model, of two kinds in one
-manager, keyed by user (a loaded model owns its cache, so a cache IS a
-model generation's state: ``/reload`` frees the outgoing one's):
+"""Per-user serving state of a sequence model, of up to three kinds in
+one manager, keyed by user (a loaded model owns its cache, so a cache IS
+a model generation's state: ``/reload`` frees the outgoing one's):
 
-* a FIXED slot per user: the short-convolution layers' last rows and the
-  last hidden row (a few KB a layer; never grows), and
+* a FIXED slot per user, which never grows: the short-convolution
+  layers' last rows (a few KB a layer) or a linear-attention layer's
+  recurrent matrices (MBs a layer), and the last hidden row;
 * PAGED rows that grow with the user's history: the attention layers'
-  keys and values, ``PAGE_SIZE`` events a page.
+  keys and values, ``PAGE_SIZE`` events a page; and
+* INDEX rows that ride with the pages (the pooled keys a block selection
+  scores): allotted, evicted and rolled back with the page they sit in.
 
-Both live in device arrays the cache owns (``arrays``); a model's device
+All live in device arrays the cache owns (``arrays``); a model's device
 program reads and writes them at the slots and rows a :class:`Plan`
-names.  The cache knows shapes, not models.
+names.  The cache knows sizes, not models: a model hands it a ``layout``
+(the bytes of a slot and of a page by kind, and ``allocate(n_slots,
+n_pages)``, which makes the arrays as the model's programs index them).
 
 **Transactions.**  Every change belongs to a :meth:`StateCache.transaction`
 (re-entrant; the engine server holds one around a whole dispatch).  A
-program writes a user's fixed state into the slot's TWIN and new paged
-rows beyond the user's committed length, so until :meth:`commit` flips
-the twin and moves the length, every committed state is intact: a failed
-dispatch rolls back to exactly what was there.  Evictions made to find
-room are not undone (an evicted user is a later miss, which re-reads the
-history, never a wrong answer).
+program writes a user's fixed state into ANOTHER slot than the committed
+one and new paged rows beyond the user's committed length, so until
+:meth:`commit` makes the written slot the user's and moves the length,
+every committed state is intact: a failed dispatch rolls back to exactly
+what was there, for every kind.  The other slot comes from a POOL of
+``write_slots`` spare ones, as many as the users ONE device program can
+touch, so the write side is sized by the dispatch and not by the
+population (a slot is MBs where the state is a recurrent matrix); a
+commit hands the user's old slot back.  A transaction that touches more
+users than that (a bulk call of several programs) COMMITS IN PARTS: when
+the pool is dry, the users whose programs have run and who are not in
+the one being planned are committed there and then, which frees their
+old slots.  So a roll-back is exact for a call of up to ``write_slots``
+users (every cohort the scheduler forms), and program by program beyond:
+a failed program leaves every state whole, the earlier programs' users
+advanced, and no turn applied in part (a turn split over programs is in
+each of their plans).  Evictions made to find room are not undone (an
+evicted user is a later miss, which re-reads the history, never a wrong
+answer).
 
-**Budget.**  ``budget_bytes`` of device memory: ``max_users`` fixed slots
-(two twins each) and as many pages as the rest holds.  When slots or
-pages run out the least recently used user outside the open transaction
-is evicted (``pio_seq_state_total{result="evicted"}``).
+**Page lists.**  A dispatch names its users' pages either as ONE flat
+list of ``PAGE_LIST_LEN`` pages (few users with long histories fill it),
+or, with ``table_len``, through a page table a user that lives on the
+device (``arrays["table"]``, a row a user; the plan names the entries a
+dispatch's new pages add, and the program writes them).
+
+**Budget.**  ``budget_bytes`` of device memory: the fixed slots (a user
+each, the pool and two the cache keeps) and as many pages as the rest
+holds.  When rows or pages run out the least recently used user outside
+the open transaction is evicted
+(``pio_seq_state_total{result="evicted"}``).
 """
 
 from __future__ import annotations
@@ -32,7 +57,7 @@ import collections
 import contextlib
 import dataclasses
 import threading
-from typing import Any, Dict, Hashable, List, Sequence, Tuple
+from typing import Any, Dict, Hashable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -56,8 +81,8 @@ class StateCacheFull(RuntimeError):
 
 @dataclasses.dataclass
 class _Entry:
-    pair: int                 # slot pair; flat slots 2 + 2*pair (+ 1)
-    twin: int = 0             # which of the pair holds the committed state
+    row: int                  # the user's number: its row of the table
+    slot: Optional[int]       # the slot that holds the committed state
     length: int = 0           # committed events
     pages: List[int] = dataclasses.field(default_factory=list)
 
@@ -66,7 +91,8 @@ class _Entry:
 class _Staged:
     length: int
     pages: List[int]
-    wrote: bool = False       # a program has written the twin
+    slot: Optional[int] = None  # where the transaction writes the key
+    wrote: bool = False         # a program has written it
 
 
 @dataclasses.dataclass
@@ -80,6 +106,11 @@ class Plan:
     write_slot: List[int]
     seg_pages: List[List[int]]  # the user's pages once the rows are added
     page_size: int
+    # With a page table a user: each segment's row of it, and (row, index,
+    # pool page) of every page this plan handed out.
+    table_row: List[int] = dataclasses.field(default_factory=list)
+    new_pages: List[Tuple[int, int, int]] = dataclasses.field(
+        default_factory=list)
 
     def rows_of(self, tok_seg: np.ndarray, tok_pos: np.ndarray
                 ) -> np.ndarray:
@@ -108,29 +139,29 @@ class StateCache:
     SCRAP_SLOT = 1   # takes the writes of a program's padding
     SCRAP_PAGE = 0
 
-    def __init__(self, *, n_fixed_layers: int, n_paged_layers: int,
-                 width: int, paged_width: int, budget_bytes: int,
-                 max_users: int, page_size: int = PAGE_SIZE, registry=None):
-        import jax.numpy as jnp
-
-        self.n_fixed_layers = int(n_fixed_layers)
-        self.n_paged_layers = int(n_paged_layers)
-        self.width = int(width)
-        self.paged_width = int(paged_width)
+    def __init__(self, layout: Dict[str, Any], *, budget_bytes: int,
+                 max_users: int, write_slots: int,
+                 page_size: int = PAGE_SIZE, registry=None):
+        self.layout = layout
         self.page_size = int(page_size)
-        self.page_list_len = PAGE_LIST_LEN
         self.max_users = int(max_users)
-        self.dtype = jnp.bfloat16
-        self.slot_bytes = 2 * (2 * self.n_fixed_layers + 1) * self.width * 2
-        self.page_bytes = (2 * self.n_paged_layers * self.page_size
-                           * self.paged_width * 2)
-        fixed = (self.max_users + 1) * self.slot_bytes
+        self.write_slots = int(write_slots)
+        self.table_len = layout.get("table_len")
+        self.page_list_len = None if self.table_len else PAGE_LIST_LEN
+        self.n_slots = 2 + self.max_users + self.write_slots
+        self.slot_bytes = int(layout["fixed_bytes"])
+        self.kind_bytes = {"fixed": self.slot_bytes,
+                           "paged": int(layout.get("paged_bytes", 0)),
+                           "index": int(layout.get("index_bytes", 0))}
+        self.page_bytes = self.kind_bytes["paged"] + self.kind_bytes["index"]
+        fixed = self.n_slots * self.slot_bytes \
+            + 4 * (1 + self.max_users) * int(self.table_len or 0)
         self.n_pages = int((int(budget_bytes) - fixed - self.page_bytes)
                            // max(self.page_bytes, 1)) \
-            if self.n_paged_layers else 0
-        if self.n_paged_layers and self.n_pages < 1:
+            if self.page_bytes else 0
+        if self.page_bytes and self.n_pages < 1:
             raise ValueError(
-                f"a budget of {budget_bytes} bytes holds {self.max_users} "
+                f"a budget of {budget_bytes} bytes holds {self.n_slots} "
                 f"slots of {self.slot_bytes} bytes and no page of "
                 f"{self.page_bytes}")
         self._lock = threading.RLock()
@@ -140,8 +171,7 @@ class StateCache:
         self._staged: Dict[Hashable, _Staged] = {}
         self._txn_pages: List[int] = []
         self._txn_created: List[Hashable] = []
-        self._free_pairs = list(range(self.max_users - 1, -1, -1))
-        self._free_pages = list(range(self.n_pages, 0, -1))
+        self._fresh_lists()
         self.arrays: Dict[str, Any] = {}
         self._allocate()
         reg = registry or get_registry()
@@ -153,23 +183,27 @@ class StateCache:
             "pio_seq_state_users", "Users with state in the cache.")
         self._m_pages = reg.gauge(
             "pio_seq_state_pages_used", "Pages of paged state in use.")
+        self._m_bytes = reg.gauge(
+            "pio_seq_state_bytes",
+            "Device bytes of per-user state by kind: fixed (every slot), "
+            "paged and index (the pages in use).", ("kind",))
+        self._gauges()
+
+    def _fresh_lists(self) -> None:
+        self._free_rows = list(range(self.max_users - 1, -1, -1))
+        self._free_pages = list(range(self.n_pages, 0, -1))
+        self._free_slots = list(range(self.n_slots - 1, 1, -1))
 
     # -- device arrays -------------------------------------------------------
 
     def _allocate(self) -> None:
         import jax.numpy as jnp
 
-        slots = 2 + 2 * self.max_users
-        pool = (1 + self.n_pages, self.page_size, self.paged_width)
-        self.arrays = {
-            "conv": jnp.zeros((self.n_fixed_layers, slots, 2, self.width),
-                              self.dtype),
-            "h_last": jnp.zeros((slots, self.width), self.dtype),
-            "k": [jnp.zeros(pool, self.dtype)
-                  for _ in range(self.n_paged_layers)],
-            "v": [jnp.zeros(pool, self.dtype)
-                  for _ in range(self.n_paged_layers)],
-        }
+        self.arrays = dict(self.layout["allocate"](self.n_slots,
+                                                   self.n_pages))
+        if self.table_len:
+            self.arrays["table"] = jnp.zeros(
+                (1 + self.max_users, int(self.table_len)), jnp.int32)
 
     def run(self, fn, params, batch):
         """``fn(params, arrays, batch) -> (arrays, *rest)`` with the
@@ -206,8 +240,7 @@ class StateCache:
             self._staged.clear()
             self._txn_pages.clear()
             self._txn_created.clear()
-            self._free_pairs = list(range(self.max_users - 1, -1, -1))
-            self._free_pages = list(range(self.n_pages, 0, -1))
+            self._fresh_lists()
             self._gauges()
 
     def reset(self) -> None:
@@ -239,28 +272,55 @@ class StateCache:
 
     def commit(self) -> None:
         with self._lock:
-            for key, st in self._staged.items():
-                e = self._entries.get(key)
-                if e is None:
-                    continue
-                e.length, e.pages = st.length, st.pages
-                if st.wrote:
-                    e.twin ^= 1
-                self._entries.move_to_end(key)
+            self._commit(list(self._staged))
             self._staged.clear()
             self._txn_pages.clear()
             self._txn_created.clear()
             self._gauges()
+
+    def _commit(self, keys: Sequence[Hashable]) -> None:
+        """Make what is staged for ``keys`` theirs; a slot nobody's state
+        is in any more goes back to the pool."""
+        for key in keys:
+            st = self._staged.pop(key)
+            e = self._entries.get(key)
+            if e is None:
+                continue
+            e.length, e.pages = st.length, st.pages
+            if st.wrote:
+                self._release(e.slot)
+                e.slot = st.slot
+            else:
+                self._release(st.slot)
+            self._entries.move_to_end(key)
+
+    def _commit_early(self, keep) -> bool:
+        """The pool is dry: commit the users whose programs have run and
+        who are not in the plan being made (``keep``).  Whether any slot
+        came back."""
+        done = {key for key, st in self._staged.items()
+                if st.wrote and key not in keep}
+        self._commit(done)
+        # Their pages stay in ``_txn_pages``: a roll-back frees only
+        # those of it that no entry holds.
+        self._txn_created = [k for k in self._txn_created if k not in done]
+        return bool(self._free_slots)
+
+    def _release(self, slot: Optional[int]) -> None:
+        if slot is not None:
+            self._free_slots.append(slot)
 
     def rollback(self) -> None:
         with self._lock:
             live = {p for e in self._entries.values() for p in e.pages}
             self._free_pages.extend(p for p in self._txn_pages
                                     if p not in live)
+            for st in self._staged.values():
+                self._release(st.slot)
             for key in self._txn_created:
                 e = self._entries.pop(key, None)
                 if e is not None:
-                    self._free_pairs.append(e.pair)
+                    self._free_rows.append(e.row)
             self._staged.clear()
             self._txn_pages.clear()
             self._txn_created.clear()
@@ -287,9 +347,15 @@ class StateCache:
             return e.length if e is not None else 0
 
     @property
+    def max_pages(self) -> int:
+        """Pages one user's history can hold: what a dispatch's flat list,
+        or the user's page table, has room for."""
+        return int(self.table_len or self.page_list_len)
+
+    @property
     def max_events(self) -> int:
         """The longest history one dispatch can attend over."""
-        return self.page_list_len * self.page_size
+        return self.max_pages * self.page_size
 
     def pages_after(self, key: Hashable, n_new: int) -> int:
         return -(-(self.length(key) + int(n_new)) // self.page_size)
@@ -303,16 +369,18 @@ class StateCache:
                 return self.ZERO_SLOT
             st = self._staged.get(key)
             if st is not None and st.wrote:
-                return 2 + 2 * e.pair + (e.twin ^ 1)
+                return st.slot
             if e.length == 0:
                 return self.ZERO_SLOT
-            return 2 + 2 * e.pair + e.twin
+            return e.slot
 
     def plan(self, keys: Sequence[Hashable], seg_len: Sequence[int]
              ) -> Plan:
         """Slots and pages for a dispatch that adds ``seg_len[i]`` events
-        to ``keys[i]``; evicts least recently used users outside the
-        open transaction when slots or pages run short."""
+        to ``keys[i]``.  When the pool of slots is dry, the transaction's
+        earlier programs are committed; when rows or pages run short, the
+        least recently used users outside the open transaction are
+        evicted."""
         with self._lock:
             if self._depth == 0:
                 raise RuntimeError("plan() outside a transaction")
@@ -322,42 +390,53 @@ class StateCache:
             for key, n in zip(keys, plan.seg_len):
                 e = self._entries.get(key)
                 if e is None:
-                    if not self._free_pairs:
+                    if not self._free_rows:
                         self._evict_one(keep)
-                    e = self._entries[key] = _Entry(self._free_pairs.pop())
+                    row = self._free_rows.pop()
+                    e = self._entries[key] = _Entry(row, None)
                     self._txn_created.append(key)
                 st = self._staged.get(key)
                 if st is None:
                     st = _Staged(e.length, list(e.pages))
+                if st.slot is None:
+                    if not self._free_slots \
+                            and not self._commit_early(set(keys)):
+                        self._evict_one(keep)
+                    st.slot = self._free_slots.pop()
                 start = st.length
                 need = -(-(start + n) // self.page_size) - len(st.pages) \
-                    if self.n_paged_layers else 0
+                    if self.page_bytes else 0
                 pages = list(st.pages)
                 for _ in range(max(need, 0)):
                     if not self._free_pages:
                         self._evict_one(keep)
                     page = self._free_pages.pop()
                     self._txn_pages.append(page)
+                    plan.new_pages.append((1 + e.row, len(pages), page))
                     pages.append(page)
-                if len(pages) > self.page_list_len:
+                # The pages and the slot are the key's from now on, so
+                # that a later segment's eviction cannot hand them out
+                # again.
+                self._staged[key] = _Staged(st.length, pages, st.slot,
+                                            st.wrote)
+                if len(pages) > self.max_pages:
                     raise StateCacheFull(
                         f"{key!r} would hold {len(pages)} pages; a "
-                        f"dispatch attends over {self.page_list_len}")
+                        f"dispatch attends over {self.max_pages}")
                 plan.seg_start.append(start)
                 plan.read_slot.append(self.read_slot(key))
-                plan.write_slot.append(2 + 2 * e.pair + (e.twin ^ 1))
+                plan.write_slot.append(st.slot)
                 plan.seg_pages.append(pages)
-                # The pages are the key's from now on, so that a later
-                # segment's eviction cannot hand them out again.
-                self._staged[key] = _Staged(st.length, pages, st.wrote)
+                plan.table_row.append(1 + e.row)
             return plan
 
     def stage(self, plan: Plan) -> None:
         """The program of ``plan`` ran: its rows are there to commit."""
         with self._lock:
-            for key, start, n, pages in zip(plan.keys, plan.seg_start,
-                                            plan.seg_len, plan.seg_pages):
-                self._staged[key] = _Staged(start + n, pages, True)
+            for key, start, n, pages, slot in zip(
+                    plan.keys, plan.seg_start, plan.seg_len, plan.seg_pages,
+                    plan.write_slot):
+                self._staged[key] = _Staged(start + n, pages, slot, True)
 
     # -- eviction -------------------------------------------------------------
 
@@ -367,8 +446,9 @@ class StateCache:
                 self.evict(key)
                 return
         raise StateCacheFull(
-            f"{len(keep)} users of one dispatch need more than the "
-            f"cache's {self.max_users} slots and {self.n_pages} pages")
+            f"{len(keep)} users of one transaction need more than the "
+            f"cache's {self.max_users} users, {self.n_slots - 2} slots "
+            f"and {self.n_pages} pages")
 
     def evict(self, key: Hashable) -> bool:
         with self._lock:
@@ -389,11 +469,20 @@ class StateCache:
         if key in self._txn_created:
             self._txn_created.remove(key)
         self._free_pages.extend(pages)
-        self._free_pairs.append(e.pair)
+        self._free_rows.append(e.row)
+        self._release(e.slot)
+        if st is not None:
+            self._release(st.slot)
 
     def _gauges(self) -> None:
+        used = self.n_pages - len(self._free_pages)
         self._m_users.set(len(self._entries))
-        self._m_pages.set(self.n_pages - len(self._free_pages))
+        self._m_pages.set(used)
+        held = bool(self.arrays)
+        self._m_bytes.set(held * self.n_slots * self.kind_bytes["fixed"],
+                          kind="fixed")
+        for kind in ("paged", "index"):
+            self._m_bytes.set(used * self.kind_bytes[kind], kind=kind)
 
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:

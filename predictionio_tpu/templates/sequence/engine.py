@@ -1,8 +1,11 @@
 """Sequence template — "what next" from a user's event history.
 
-A user's item events, in time order, are a sequence; the model
-(:mod:`predictionio_tpu.models.lfm2`: gated short convolutions, grouped-
-query attention, routed experts) scores every item as the next one.
+A user's item events, in time order, are a sequence; the model scores
+every item as the next one.  The algorithm's ``backbone`` picks it:
+``lfm2`` (:mod:`predictionio_tpu.models.lfm2`: gated short convolutions,
+grouped-query attention, routed experts; the default) or ``sala``
+(:mod:`predictionio_tpu.models.sala`: block-selected sparse attention
+beside lightning linear attention).
 Serving keeps each user's state between queries in the
 :class:`~predictionio_tpu.serving.state_cache.StateCache`, so a query
 pays for the events it brings, not for the history behind them.
@@ -49,6 +52,26 @@ from predictionio_tpu.data.event import BiMap
 from predictionio_tpu.obs import dispatch_stage
 
 logger = logging.getLogger(__name__)
+
+# backbone -> (its module, its plain reference's module)
+BACKBONES = {
+    "lfm2": ("predictionio_tpu.models.lfm2",
+             "predictionio_tpu.models.lfm2_reference"),
+    "sala": ("predictionio_tpu.models.sala",
+             "predictionio_tpu.models.sala_reference"),
+}
+
+
+def _backbone(name: str):
+    import importlib
+
+    try:
+        module, reference = BACKBONES[name]
+    except KeyError:
+        raise ValueError(f"unknown backbone {name!r}; known: "
+                         f"{sorted(BACKBONES)}") from None
+    return importlib.import_module(module), importlib.import_module(
+        reference)
 
 __all__ = [
     "Query", "ItemScore", "PredictedResult", "Histories",
@@ -159,6 +182,7 @@ class SequencePreparator(Preparator):
 
 @dataclasses.dataclass(frozen=True)
 class SequenceAlgorithmParams(Params):
+    backbone: str = "lfm2"
     hiddenSize: int = 64  # noqa: N815
     intermediateSize: int = 128  # noqa: N815
     moeIntermediateSize: int = 32  # noqa: N815
@@ -169,6 +193,12 @@ class SequenceAlgorithmParams(Params):
     layerTypes: Sequence[str] = (  # noqa: N815
         "conv", "full_attention", "conv", "conv")
     numDenseLayers: int = 1  # noqa: N815
+    # The ``sala`` backbone: a mixer a layer, the heads' size, and what of
+    # the block selection's sizes differs from the published ones.
+    mixerTypes: Sequence[str] = (  # noqa: N815
+        "minicpm4", "lightning-attn", "lightning-attn", "lightning-attn")
+    headDim: int = 16  # noqa: N815
+    sparseConfig: Optional[Dict[str, int]] = None  # noqa: N815
     # Training (next-item cross-entropy over windows of the histories).
     steps: int = 200
     batchSize: int = 16  # noqa: N815
@@ -187,13 +217,14 @@ class SequenceModel:
     a reload loads a new object with an empty cache, and the server
     frees this one's."""
 
-    config: Any                       # models.lfm2.LFM2Config
+    config: Any                       # the backbone's config
     params: Dict[str, Any]            # host arrays
     item_index: BiMap
     app_name: Optional[str] = None
     event_names: Sequence[str] = ()
     state_budget_bytes: int = 64 << 20
     max_users: int = 1024
+    backbone: str = "lfm2"
 
     def __post_init__(self):
         self._init_transients()
@@ -220,17 +251,12 @@ class SequenceModel:
         """The device side, built on first use: serving-precision weights,
         the state cache within its budget, the compiled programs."""
         if self._runtime is None:
-            from predictionio_tpu.models import lfm2
-            from predictionio_tpu.serving.state_cache import StateCache
-
-            cfg = self.config
-            cache = StateCache(
-                n_fixed_layers=cfg.n_conv, n_paged_layers=cfg.n_attn,
-                width=cfg.hidden_size, paged_width=cfg.kv_width,
+            # A model pickled before there were two has no such field.
+            name = getattr(self, "backbone", "lfm2")
+            self._runtime = _backbone(name)[0].make_runtime(
+                self.config, self.params,
                 budget_bytes=self.state_budget_bytes,
                 max_users=self.max_users)
-            self._runtime = lfm2.SequenceRuntime(
-                cfg, lfm2.cast_for_serving(self.params), cache)
         return self._runtime
 
     @property
@@ -279,37 +305,26 @@ class SequenceAlgorithm(Algorithm):
 
     def train(self, ctx: RuntimeContext, pd: Histories) -> SequenceModel:
         """Next-item cross-entropy over random windows of the histories,
-        on the plain forward pass (tier-1 sizes: every expert runs on
-        every token)."""
+        on the backbone's plain forward pass (tier-1 sizes: every expert
+        runs on every token, the selection is a mask over all pairs)."""
         import jax
         import jax.numpy as jnp
         import optax
-
-        from predictionio_tpu.models import lfm2, lfm2_reference
 
         p: SequenceAlgorithmParams = self.params
         if pd.item_index is None or len(pd.items) == 0:
             raise ValueError("No item events found — check appName and "
                              "eventNames.")
-        cfg = lfm2.LFM2Config(
-            vocab_size=len(pd.item_index), hidden_size=p.hiddenSize,
-            intermediate_size=p.intermediateSize,
-            moe_intermediate_size=p.moeIntermediateSize,
-            num_experts=p.numExperts,
-            num_experts_per_tok=p.numExpertsPerTok,
-            num_attention_heads=p.numAttentionHeads,
-            num_key_value_heads=p.numKeyValueHeads,
-            layer_types=tuple(p.layerTypes),
-            dense_ff=tuple(i < p.numDenseLayers
-                           for i in range(len(p.layerTypes))))
+        backbone, reference = _backbone(p.backbone)
+        cfg = backbone.config_from_params(p, len(pd.item_index))
         seed = p.seed if p.seed is not None else ctx.seed
-        params = lfm2.init_params(cfg, jax.random.PRNGKey(seed),
-                                  jnp.float32)
+        params = backbone.init_params(cfg, jax.random.PRNGKey(seed),
+                                      jnp.float32)
         opt = optax.adam(p.learningRate)
         opt_state = opt.init(params)
 
         def loss_fn(params, tokens, mask):
-            logits = jax.vmap(lambda t: lfm2_reference.forward(
+            logits = jax.vmap(lambda t: reference.forward(
                 params, cfg, t))(tokens[:, :-1])
             nll = optax.softmax_cross_entropy_with_integer_labels(
                 logits, tokens[:, 1:])
@@ -346,7 +361,7 @@ class SequenceAlgorithm(Algorithm):
             config=cfg, params=_to_host(params), item_index=pd.item_index,
             app_name=pd.app_name, event_names=tuple(pd.event_names),
             state_budget_bytes=int(p.stateBudgetMB * (1 << 20)),
-            max_users=p.maxUsers)
+            max_users=p.maxUsers, backbone=p.backbone)
 
     def predict(self, model: SequenceModel, query: Query) -> PredictedResult:
         return self.batch_predict(model, [(0, query)])[0][1]
@@ -359,7 +374,7 @@ class SequenceAlgorithm(Algorithm):
         server that is the dispatch, here the call.  The answers leave
         as :class:`~predictionio_tpu.controller.ItemScoreColumns`: no
         ``ItemScore`` exists until somebody reads one."""
-        from predictionio_tpu.models.lfm2 import Turn
+        from predictionio_tpu.models.seq_runtime import Turn
         from predictionio_tpu.retrieval import hit_columns
 
         runtime = model.runtime()

@@ -17,7 +17,7 @@ import pytest
 from predictionio_tpu.controller import EngineVariant, RuntimeContext
 from predictionio_tpu.data.event import Event
 from predictionio_tpu.data.storage import App, get_storage
-from predictionio_tpu.models import lfm2
+from predictionio_tpu.models import lfm2, seq_runtime
 from predictionio_tpu.models import lfm2_reference as ref
 from predictionio_tpu.ops.ragged import pack_turns
 from predictionio_tpu.serving.result_cache import canonical_query
@@ -59,10 +59,9 @@ def want(params, history):
         return np.asarray(ref.forward(params, CFG, jnp.asarray(history)))
 
 
-def _cache(max_users=6, budget=1 << 19):
-    return StateCache(n_fixed_layers=CFG.n_conv, n_paged_layers=CFG.n_attn,
-                      width=CFG.hidden_size, paged_width=CFG.kv_width,
-                      budget_bytes=budget, max_users=max_users,
+def _cache(max_users=6, budget=1 << 19, write_slots=4):
+    return StateCache(lfm2.state_layout(CFG, PAGE), budget_bytes=budget,
+                      max_users=max_users, write_slots=write_slots,
                       page_size=PAGE)
 
 
@@ -271,7 +270,7 @@ def test_a_programs_first_run_settles_the_heap_once(params, history,
     _ask(rt, ("a", history[:3]))
     assert gc.get_freeze_count() > 0
     settled = []
-    monkeypatch.setattr(lfm2, "_settle_heap", lambda: settled.append(1))
+    monkeypatch.setattr(seq_runtime, "_settle_heap", lambda: settled.append(1))
     _ask(rt, ("a", history[3:5]))       # the same program
     assert settled == []
     _ask(rt, ("b", history[:12]))       # the larger bucket
@@ -309,8 +308,8 @@ def test_a_failed_dispatch_advances_nothing(fresh, history, want):
 def test_eviction_then_the_same_answer_from_a_refill(params, history, want):
     """Three users' histories do not fit the pages: the least recently
     used goes, and re-reading its history answers as its state did."""
-    cache = _cache(max_users=3, budget=None or (
-        4 * (2 * (2 * CFG.n_conv + 1) * CFG.hidden_size * 2)
+    cache = _cache(max_users=3, write_slots=3, budget=(
+        8 * ((2 * CFG.n_conv + 1) * CFG.hidden_size * 2)
         + 8 * (2 * CFG.n_attn * PAGE * CFG.kv_width * 2)))
     assert cache.n_pages == 7
     rt = _runtime(params, cache)
@@ -337,7 +336,7 @@ def test_eviction_then_the_same_answer_from_a_refill(params, history, want):
 
 # -- the state cache and the packing, by themselves -------------------------
 
-def test_state_cache_budget_twins_and_free():
+def test_state_cache_budget_write_pool_and_free():
     cache = _cache(max_users=4, budget=1 << 18)
     held = cache.bytes_in_use()
     assert held <= 1 << 18
@@ -356,10 +355,12 @@ def test_state_cache_budget_twins_and_free():
     with cache.transaction():
         plan = cache.plan(["u"], [2])
         assert plan.read_slot == [first] and plan.seg_start == [PAGE + 1]
-        assert plan.write_slot[0] == first ^ 1    # the twin
+        second = plan.write_slot[0]               # one of the pool
+        assert second not in (first, cache.read_slot("v"))
         assert len(plan.seg_pages[0]) == 2        # room left in page two
         cache.stage(plan)
-    assert cache.read_slot("u") == first ^ 1
+    assert cache.read_slot("u") == second
+    assert first in cache._free_slots             # the old one came back
     assert cache.snapshot()["pagesUsed"] == 3
     with pytest.raises(RuntimeError):
         cache.plan(["v"], [1])                    # outside a transaction

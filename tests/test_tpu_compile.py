@@ -1,4 +1,6 @@
-"""Mosaic's verdict on the ALS dense gram kernel at real widths, with no
+"""Mosaic's verdict on the kernels of the main paths at real widths (the
+ALS dense gram; the sequence engine's selected attention and lightning
+update), with no
 chip: libtpu compiles for a described v5e in the sandbox (PERF.md, PR 21).
 It proves compilation, not results.  The topology is described inside a
 fixture, by the one worker that runs this file; keep every such test
@@ -49,3 +51,50 @@ def test_dense_gram_kernel_compiles_for_v5e(one_chip, rank, rows, n_src,
         shape((), jnp.float32)).compile()
     # the name the benchmark's als_gram_roofline and als_dense_gram_ms read
     assert "fused_gram_dense_pallas" in compiled.as_text()
+
+
+# The block-selected / lightning backbone's kernels at the published
+# widths (32 lightning heads and 2 groups of 16 query heads of 128), in
+# the tile shapes of its programs: 8 events a tile for turns, 64 for
+# prefill chunks.
+
+@pytest.mark.parametrize("tiles,tq", [(96, 8), (24, 64), (80, 64)])
+def test_lightning_kernel_compiles_for_v5e(one_chip, tiles, tq):
+    from predictionio_tpu.ops import sala_kernels
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    qkv = shape((tiles, 32, tq, 128), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, k, v, state, rate, *per_tile:
+        sala_kernels._lightning_pallas(q, k, v, state, rate, *per_tile,
+                                       scale=128 ** -0.5, hb=8,
+                                       interpret=False)).lower(
+        qkv, qkv, qkv, shape((194, 32, 128, 128), jnp.float32),
+        shape((32,), jnp.float32),
+        *[shape((tiles,), jnp.int32)] * 4).compile()
+    # the name the benchmark's lightning_ms and lightning_roofline read
+    assert "sala_lightning" in compiled.as_text()
+
+
+@pytest.mark.parametrize("tiles,tq,u_max,pb", [
+    (96, 8, 336, 8), (24, 64, 640, 4), (80, 64, 640, 4)])
+def test_sparse_attention_kernel_compiles_for_v5e(one_chip, tiles, tq,
+                                                  u_max, pb):
+    from predictionio_tpu.ops import sala_kernels
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, meta, cnt, pages, pool:
+        sala_kernels._sparse_attention_pallas(
+            q, meta, cnt, pages, pool, page=128, block=64, topk=64, pb=pb,
+            interpret=False)).lower(
+        shape((tiles, 2, 16 * tq, 128), jnp.bfloat16),
+        shape((tiles, 2, tq, 128), jnp.int32), shape((tiles, 2), jnp.int32),
+        shape((tiles, 2, u_max), jnp.int32),
+        shape((24_000 * 128, 512), jnp.bfloat16)).compile()
+    # the name sparse_attn_ms and sparse_attn_roofline read
+    assert "sala_sparse_attention" in compiled.as_text()

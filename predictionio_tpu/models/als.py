@@ -47,6 +47,7 @@ from predictionio_tpu.ops.pallas_kernels import (
     fits_vmem,
     fused_gram_dense,
     fused_gram_vector_pallas,
+    gather_table_pack,
     lanes_solve_fits_vmem,
     pallas_supported,
     ridge_solve_lu_pallas,
@@ -199,6 +200,39 @@ def _resolve_gram_dtype(gram_dtype: str) -> str:
     return gram_dtype
 
 
+def _gather_packed(table: jax.Array, indices: jax.Array,
+                   pack: int) -> jax.Array:
+    """``table[indices]`` read through the view that lays ``pack`` rows
+    side by side in one 128-lane row: gather row ``index // pack`` of the
+    view, keep the part ``index % pack`` names.  The view is padded to
+    whole rows; an index is wrapped and clamped first, as ``table[...]``
+    does it, so the pad never reaches a result."""
+    n, k = table.shape
+    indices = jnp.clip(jnp.where(indices < 0, indices + n, indices), 0, n - 1)
+    if n % pack:
+        table = jnp.pad(table, ((0, -n % pack), (0, 0)))
+    wide = table.reshape(-1, pack * k)[indices // pack]   # [R, L, pack·K]
+    part = (indices % pack)[..., None]
+    rows = wide[..., :k]
+    for j in range(1, pack):
+        rows = jnp.where(part == j, wide[..., j * k:(j + 1) * k], rows)
+    return rows
+
+
+def _gather_rows(factors: jax.Array, indices: jax.Array,
+                 gram_dtype) -> jax.Array:
+    """``factors.astype(gram_dtype)[indices]``, bit for bit, in the form
+    that keeps the table the gather reads in the chip's fast memory
+    (``pallas_kernels.gather_table_pack``: XLA:TPU gathers from VMEM at a
+    fifth of what a row costs from HBM).  A table that lies there as it
+    is, or that no view brings there, is gathered as it is."""
+    table = factors.astype(gram_dtype)
+    pack = gather_table_pack(*table.shape, table.dtype.itemsize) or 1
+    if pack == 1:
+        return table[indices]
+    return _gather_packed(table, indices, pack)
+
+
 def _gram_pieces(
     indices: jax.Array,    # [R, L] int32 — other-side ids
     values: jax.Array,     # [R, L] f32
@@ -226,7 +260,7 @@ def _gram_pieces(
         # layout, so no relayout copy is emitted (the einsum path's dots
         # want L-minor and XLA copies the whole [R,L,K] block to get it:
         # 47.7 ms/iter at the ML-25M shape, round-3 phase profile).
-        f = factors.astype(gram_dtype)[indices]   # [R, L, K] gather
+        f = _gather_rows(factors, indices, gram_dtype)   # [R, L, K]
         a, b = fused_gram_vector_pallas(f, w, cvec,
                                         interpret=not pallas_supported())
     else:
@@ -239,7 +273,7 @@ def _gram_pieces(
         # alpha == 0) get an epsilon fold weight so the rhs survives the
         # division exactly; the epsilon perturbs A by ~1e-12 per entry —
         # far below the ridge.
-        f = factors.astype(gram_dtype)[indices]   # [R, L, K] gather
+        f = _gather_rows(factors, indices, gram_dtype)   # [R, L, K]
         sw = jnp.sqrt(w + jnp.where(cvec != 0.0, 1e-12, 0.0))
         g = f * sw[..., None].astype(gram_dtype)
         a = jax.lax.dot_general(g, g, (((1,), (1,)), ((0,), (0,))),
@@ -1178,12 +1212,26 @@ def train_als_prepared(inputs: ALSInputs, config: ALSConfig, *,
         "over the whole factor table) or gathered (factor rows by index).",
         ("side", "path"))
 
+    gather_ratings = get_registry().counter(
+        "pio_als_gather_ratings_total",
+        "Gathered ratings (pio_als_gram_ratings_total's path=gathered) by "
+        "side and by the form of the fetch, which follows the other side's "
+        "factor table: packed (a view that lays several rows in one "
+        "128-lane row brings the table into the chip's fast memory) or "
+        "plain (the table as it is).",
+        ("side", "form"))
+    gdt = jnp.dtype(statics["gram_dtype"])
+    forms = tuple(
+        "packed" if (gather_table_pack(n_src, k, gdt.itemsize) or 1) > 1
+        else "plain" for n_src in (itf.shape[0], uf.shape[0]))
+
     def sweeps(uf, itf, n):
-        for side, (dense, gathered) in zip(("user", "item"),
-                                           inputs.gram_ratings):
+        for side, form, (dense, gathered) in zip(("user", "item"), forms,
+                                                 inputs.gram_ratings):
             gram_ratings.inc(float(dense) * n, side=side, path="dense")
             gram_ratings.inc(float(gathered) * n, side=side,
                              path="gathered")
+            gather_ratings.inc(float(gathered) * n, side=side, form=form)
         if warm_exe is not None:
             return warm_exe(uf, itf, ubk, ibk, reg, alpha, jnp.int32(n))
         return _train_loop(

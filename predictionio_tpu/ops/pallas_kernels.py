@@ -33,7 +33,7 @@ __all__ = ["fused_gram_vector", "fused_gram_vector_pallas",
            "fused_gram_vector_xla", "pallas_supported",
            "fused_gram_dense", "fused_gram_dense_pallas",
            "fused_gram_dense_xla", "dense_weights", "dense_block_width",
-           "dense_row_density", "DENSE_TILE_R",
+           "dense_row_density", "gather_table_pack", "DENSE_TILE_R",
            "ridge_solve_lu_pallas", "lanes_solve_fits_vmem",
            "fused_topk", "fused_topk_pallas", "fused_topk_tiles",
            "pq_scan", "pq_scan_pallas", "pq_scan_xla"]
@@ -64,38 +64,75 @@ def fits_vmem(l: int, k: int) -> bool:
     return k <= 256
 
 
-# Where the dense product beats the gather: two rates, measured on one
-# TPU v5e at K = 64 (my chip runs, PR 29; PERF.md §5 retrain).
-# - A gathered factor row (XLA's gather, ``factors.astype(bf16)[indices]``)
-#   costs 2.17 ns where the table is als-netflix-r64's 17,770 items
-#   (2.3 MB in bf16) and 11.97 ns where it is its 480,189 users (61.5 MB),
-#   read from the cell's device trace, a sweep's ``fusion`` ops split at
-#   the side boundary.  Alone: 2.33-2.46 ns a row for tables of 17,770
-#   to 240,000 rows, 11.26 ns at 480,189 rows, sorted indices or not: the
-#   rate follows the table's bytes, with its step between 30.7 and
-#   61.5 MB.
+# Where the dense product beats the gather: the rates, measured on one
+# TPU v5e at K = 64 (a gathered row: my chip runs, PR 36, PERF.md §6; a
+# source row of the dense kernel: my chip runs, PR 29, PERF.md §5).
+# - XLA:TPU keeps a gather's operand in VMEM (memory space 1 of the
+#   compiled text) while its PHYSICAL bytes fit what the chip's 128 MiB
+#   leave beside 16 MiB of scoped memory: 112 MiB.  A row is laid out in
+#   whole 128-lane tiles, so a bf16 row of rank 32, 64 or 128 takes 256 B
+#   alike and the step lies at 458,752 rows for all three (229,376 in
+#   float32).  8,192 x 512 random rows alone: 2.32-2.63 ns a row from
+#   tables of 17,770 to 458,000 rows at K = 64, 11.30-11.50 from 460,000
+#   to 1,000,000; 11.18 at K = 32 and 10.75 at K = 128 from 480,189 rows
+#   (1.92 from 240,000); in float32 3.20 from 220,000 and 11.12 from
+#   240,000.  Compiled for a described v5e (no chip) the operand loses
+#   its place between 458,720 and 458,759 rows
+#   (tests/test_tpu_compile.py holds the mark to that).
+# - ``gather_table_pack``: a table of rank <= 64 wastes half its lanes
+#   or more, so the same rows laid ``128 // rank`` to a 128-lane row
+#   (``models/als.py::_gather_packed``) lie under the step up to 917,504
+#   rows at rank 64: alone 3.80 ns a row at 480,189 rows and 3.91 at
+#   900,000 (of which the pass that keeps each row's half is 1.9), 4.36
+#   at rank 32, 5.26-5.59 in float32; 12.79 at 1,000,000 rows, past the
+#   view's reach, where the table is gathered as it is.  In
+#   als-netflix-r64's loop a 128-lane row from VMEM costs 1.85 ns a slot
+#   and the pass 0.93; with the merged rows' padding and the sparse gram
+#   kernel's 0.6 ns, which a dense row does not pay, a rating at the
+#   margin costs 3.5.
+# - From HBM (no view under the step: Amazon 2014's 21M users; rank
+#   128) a row cost the loop 11.97 ns (the parent's trace, PR 29).  A
+#   table under the step as it is (als-netflix-r64's 17,770 items, every
+#   table of ML-25M) 2.17.
 # - A source row of the dense kernel costs 0.094 ns in the loop at a row
 #   tile of 16 (2,192 rows over 480,189 in 98.2 ms, 57,232 over 17,770 in
 #   99.0 ms) and 0.089-0.093 at the row tile of 32 it has now (3,488 rows
 #   in 149.3 ms, 23,488 in 38.7 ms: 92 TFLOP/s of the 98.5 an output 64
 #   wide leaves of the MXU); alone 0.098-0.104.  The rule keeps 0.094.
-_GATHER_NS_PER_ROW = (2.2, 12.0)     # table within / over the bytes below
-_GATHER_SMALL_TABLE_BYTES = 32 << 20
+_GATHER_FAST_TABLE_BYTES = 112 << 20
+_LANES = 128
+# ns a gathered row: the table in VMEM as it is, through the packed
+# view, in HBM
+_GATHER_NS_PER_ROW = (2.2, 3.5, 12.0)
 _DENSE_NS_PER_SRC_ROW_K64 = 0.094
+
+
+def gather_table_pack(n_rows: int, rank: int, itemsize: int) -> Optional[int]:
+    """How many factor rows share one row of the table a gather should
+    read so that the table lies in the chip's fast memory: 1 = the table
+    as it is, ``128 // rank`` = the packed view, None = neither fits (the
+    gather then reads the table as it is, at the slow rate)."""
+    for pack in (1, _LANES // rank):
+        lanes = -(-rank * pack // _LANES) * _LANES
+        if pack and -(-n_rows // pack) * lanes * itemsize \
+                <= _GATHER_FAST_TABLE_BYTES:
+            return pack
+    return None
 
 
 def dense_row_density(rank: int, n_src: int) -> float:
     """ρ*: the share of the ``n_src`` source rows a row must have rated
     for the masked product over the whole table to cost less than
     gathering its rows.  The product's cost a source row grows with K²;
-    the gather's cost a row follows the table's size (in the gram dtype,
-    bf16), not the rank.  Past rank 128 the dense kernel's float32
+    the gather's cost a row follows where the table (in the gram dtype,
+    bf16) lies, not the rank.  Past rank 128 the dense kernel's float32
     accumulators do not fit VMEM and no row is dense enough."""
     if rank > 128:
         return float("inf")
-    small = n_src * rank * 2 <= _GATHER_SMALL_TABLE_BYTES
-    return _DENSE_NS_PER_SRC_ROW_K64 * (rank / 64.0) ** 2 \
-        / _GATHER_NS_PER_ROW[0 if small else 1]
+    pack = gather_table_pack(n_src, rank, 2)
+    as_it_is, packed, from_hbm = _GATHER_NS_PER_ROW
+    rate = from_hbm if pack is None else as_it_is if pack == 1 else packed
+    return _DENSE_NS_PER_SRC_ROW_K64 * (rank / 64.0) ** 2 / rate
 
 
 def fused_gram_vector_xla(f: jax.Array, w: jax.Array, c: jax.Array

@@ -512,3 +512,142 @@ def test_benchmark_reads_the_dense_share_and_the_dense_kernel():
         assert entry["workloads"] == ["als-netflix-r64.retrain"]
         assert (entry["layer"], entry["moves"]) == ("ALS kernels",
                                                     "rating_iters_per_s")
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint16 if x.dtype.itemsize == 2
+                              else np.uint32)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("rank", [32, 64, 128])
+@pytest.mark.parametrize("n_rows", [480, 481], ids=["even", "odd"])
+def test_gather_rows_is_the_plain_gather_bit_for_bit(n_rows, rank, dtype,
+                                                     monkeypatch):
+    """Every form of the fetch returns ``table.astype(dtype)[idx]``: the
+    table as it is, and the packed view (rows 0 and the last, repeats,
+    and an odd row count, whose pad row must never reach a result)."""
+    import jax
+
+    from predictionio_tpu.models import als
+    from predictionio_tpu.ops import pallas_kernels
+
+    table = jax.random.normal(jax.random.PRNGKey(rank + n_rows),
+                              (n_rows, rank), jnp.float32)
+    rng = np.random.default_rng(n_rows)
+    idx = rng.integers(0, n_rows, (8, 24)).astype(np.int32)
+    idx[0, :6] = [0, n_rows - 1, n_rows - 1, 0, n_rows - 2, 1]
+    idx = jnp.asarray(idx)
+    want = _bits(table.astype(dtype)[idx])
+    assert np.array_equal(
+        _bits(als._gather_rows(table, idx, jnp.dtype(dtype))), want)
+    # a fast memory of 100 KB: this table is past the step as it is, and
+    # under it through the view wherever the rank leaves lanes to share
+    monkeypatch.setattr(pallas_kernels, "_GATHER_FAST_TABLE_BYTES", 100_000)
+    pack = pallas_kernels.gather_table_pack(
+        n_rows, rank, jnp.dtype(dtype).itemsize)
+    assert pack == {32: 4, 64: 2 if dtype == "bfloat16" else None,
+                    128: None}[rank]
+    assert np.array_equal(
+        _bits(als._gather_rows(table, idx, jnp.dtype(dtype))), want)
+    for pack in (2, 4):
+        if rank * pack <= 128:
+            got = als._gather_packed(table.astype(dtype), idx, pack)
+            assert got.shape == (8, 24, rank)
+            assert np.array_equal(_bits(got), want)
+
+
+def test_a_table_under_the_step_lowers_to_the_plain_gather(monkeypatch):
+    """A side step over a table that lies in the fast memory as it is
+    (als-netflix-r64's 17,770 items; every table of ML-25M) is the same
+    program text as with ``factors.astype(dtype)[indices]`` in the
+    function's place; past the step the text changes."""
+    import jax
+
+    from predictionio_tpu.models import als
+    from predictionio_tpu.ops import pallas_kernels
+
+    def lowered(n_src):
+        S = jax.ShapeDtypeStruct
+        r, l, k = 16, 8, 64
+        return als._side_step.lower(
+            S((r, l), jnp.int32), S((r, l), jnp.float32),
+            S((r, l), jnp.bool_), S((r,), jnp.int32),
+            S((40, k), jnp.float32), S((n_src, k), jnp.float32),
+            S((), jnp.float32), S((), jnp.float32), implicit=False,
+            use_pallas=False, gram_dtype="bfloat16").as_text()
+
+    new = {n: lowered(n) for n in (17_770, 480_189)}
+    assert pallas_kernels.gather_table_pack(17_770, 64, 2) == 1
+    assert pallas_kernels.gather_table_pack(480_189, 64, 2) == 2
+    monkeypatch.setattr(
+        als, "_gather_rows",
+        lambda factors, indices, dtype: factors.astype(dtype)[indices])
+    als._side_step.clear_cache()
+    try:
+        assert lowered(17_770) == new[17_770]
+        assert lowered(480_189) != new[480_189]
+    finally:
+        als._side_step.clear_cache()
+
+
+def test_gather_form_counter_follows_the_source_table(monkeypatch):
+    """``pio_als_gather_ratings_total`` splits the gathered ratings by the
+    form the other side's table takes, and the benchmark's
+    ``als_gather_under_step_pct`` reads the item side's share."""
+    from benchmark import manifest, prom
+    from benchmark.readers import prom_ratio
+    from predictionio_tpu.models import als
+    from predictionio_tpu.models.als import (
+        prepare_als_inputs, train_als_prepared,
+    )
+    from predictionio_tpu.ops import pallas_kernels
+
+    users, items, ratings = _dense_toy(seed=7)
+    cfg = ALSConfig(rank=4, iterations=2, reg=0.05, seed=11,
+                    device_prep=False, split_above=16)
+    inputs = prepare_als_inputs(users, items, ratings, 60, 40, cfg)
+    plain = train_als_prepared(inputs, cfg)
+    # a fast memory that holds the 40 items' table (rank 4 in float32:
+    # 128 lanes x 4 B a row) and not the 60 users' as it is
+    monkeypatch.setattr(pallas_kernels, "_GATHER_FAST_TABLE_BYTES",
+                        50 * 128 * 4)
+    packs = []
+    packed = als._gather_packed
+    monkeypatch.setattr(
+        als, "_gather_packed",
+        lambda table, idx, pack: packs.append((table.shape[0], pack))
+        or packed(table, idx, pack))
+    als._train_loop.clear_cache()       # traced anew under the small memory
+    spec = manifest.layer_metric_spec("als_gather_under_step_pct")
+    before = prom.snapshot()
+    try:
+        model = train_als_prepared(inputs, cfg)
+    finally:
+        als._train_loop.clear_cache()
+    assert packs and set(packs) == {(60, 32)}
+    # the same rows reach the same kernels: the same factors, bit for bit
+    assert np.array_equal(_bits(model.user_factors),
+                          _bits(plain.user_factors))
+    assert np.array_equal(_bits(model.item_factors),
+                          _bits(plain.item_factors))
+    ctx = {"before": before, "after": prom.snapshot()}
+    (_, gu), (_, gi) = inputs.gram_ratings      # host prep: all gathered
+    assert gu == gi == len(users)
+
+    def grown(side, form):
+        return prom.delta(ctx["before"], ctx["after"],
+                          "pio_als_gather_ratings_total",
+                          {"side": side, "form": form})
+
+    assert grown("user", "plain") == gu * cfg.iterations
+    assert grown("item", "packed") == gi * cfg.iterations
+    assert grown("user", "packed") == grown("item", "plain") == 0
+    assert prom_ratio.read(ctx, **spec["args"]) == pytest.approx(100.0)
+    assert prom_ratio.read({"before": {}, "after": {}},
+                           **spec["args"]) is None
+    (entry,) = [m for m in manifest.load()["per_layer"]
+                if m["name"] == "als_gather_under_step_pct"]
+    assert entry["workloads"] == ["als-netflix-r64.retrain"]
+    assert (entry["layer"], entry["moves"]) == ("fused ALS loop",
+                                                "rating_iters_per_s")

@@ -320,6 +320,45 @@ class TestDenseRule:
         assert dataclasses.asdict(with_src) == dataclasses.asdict(without)
         assert with_src.dense_rows == 0
 
+    def test_netflix_item_side_plans_fewer_dense_rows_than_the_budget_allows(
+            self):
+        """als-netflix-r64's items over its 480,189 users, on a chip of
+        16 GB: since the user table is gathered through the packed view at
+        nearly the fast rate, the density cuts the block, not the budget
+        (which allows 3,488 rows and, at the slow gather's 0.78%, got
+        them all); the users' side plans as it did."""
+        import json
+        import os
+
+        from benchmark import ratings
+        from predictionio_tpu.ops.pallas_kernels import (
+            DENSE_BLOCK_DTYPE, DENSE_TILE_R, dense_block_width,
+            dense_row_density,
+        )
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(
+                root, "benchmark", "configs", "als-netflix-r64.json")) as f:
+            user_deg, item_deg = ratings.degree_sequences(json.load(f))
+        n_users, n_items = len(user_deg), len(item_deg)
+        budget = int(0.2 * 15.75 * 2 ** 30)
+        row_bytes = dense_block_width(n_users) * np.dtype(
+            DENSE_BLOCK_DTYPE).itemsize
+        allowed = budget // row_bytes // DENSE_TILE_R * DENSE_TILE_R
+        assert allowed == 3488
+        items = _plan_from_degrees(item_deg, n_src=n_users,
+                                   dense_budget=budget)
+        d_rho = int(np.ceil(dense_row_density(64, n_users) * n_users))
+        assert items.dense_min == d_rho
+        assert int((item_deg >= d_rho).sum()) <= items.dense_rows \
+            < allowed // 2
+        assert items.dense_ratings == int(item_deg[item_deg >= d_rho].sum())
+        # most of the side's ratings are still worth a product
+        assert 0.5 < items.dense_ratings / item_deg.sum() < 0.8
+        users = _plan_from_degrees(user_deg, n_src=n_items,
+                                   dense_budget=budget)
+        assert (users.dense_min, users.dense_rows) == (760, 23488)
+
     def test_rank_moves_the_threshold(self):
         p64 = _plan_from_degrees(self.DEGREES, n_src=self.N_SRC,
                                  dense_budget=1 << 30)

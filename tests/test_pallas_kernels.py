@@ -396,7 +396,9 @@ def test_dense_tiles_and_block_width(rank, n_src, width, tile):
 
 
 def test_dense_row_density_follows_the_rank_and_the_table():
-    from predictionio_tpu.ops.pallas_kernels import dense_row_density
+    from predictionio_tpu.ops.pallas_kernels import (
+        dense_row_density, gather_table_pack,
+    )
 
     n = 17_770
     assert 0.0 < dense_row_density(64, n) < 0.1
@@ -405,5 +407,25 @@ def test_dense_row_density_follows_the_rank_and_the_table():
     assert dense_row_density(32, n) == pytest.approx(
         dense_row_density(64, n) / 4)
     assert dense_row_density(256, n) == float("inf")
-    # a table too large for the fast gather: rows go dense far earlier
-    assert dense_row_density(64, 480_189) < dense_row_density(64, n) / 4
+    # Which table gets which rate.  The step is the table's physical
+    # bytes, 128 lanes a row whatever the rank: 458,752 bf16 rows.
+    assert [gather_table_pack(rows, 64, 2) for rows in
+            (n, 440_000, 458_752, 458_753, 480_189, 917_504, 917_505,
+             21_000_000)] == [1, 1, 1, 2, 2, 2, None, None]
+    assert [gather_table_pack(458_753, rank, 2) for rank in
+            (16, 32, 50, 64, 65, 100, 128)] == [8, 4, 2, 2, None, None, None]
+    assert gather_table_pack(229_377, 64, 4) == 2       # float32 rows
+    assert gather_table_pack(1_600_000, 32, 2) == 4
+    # als-netflix-r64's users, 9% past the step: gathered through the
+    # packed view at under twice the rate of a table under it, so a row
+    # goes dense at over half that table's density, not at a fifth of it
+    small, packed = dense_row_density(64, n), dense_row_density(64, 480_189)
+    assert small / 2 < packed < small
+    assert dense_row_density(64, 458_752) == small
+    # past every view's reach (Amazon 2014's 21M users; at rank 128 no
+    # lane is left to share): the slow gather, rows go dense far earlier
+    slow = dense_row_density(64, 21_000_000)
+    assert slow < small / 4 and slow < packed / 3
+    assert dense_row_density(64, 917_505) == slow
+    assert dense_row_density(128, 480_189) == pytest.approx(4 * slow)
+    assert dense_row_density(128, 458_752) == pytest.approx(4 * small)

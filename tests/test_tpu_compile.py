@@ -98,3 +98,54 @@ def test_sparse_attention_kernel_compiles_for_v5e(one_chip, tiles, tq,
         shape((24_000 * 128, 512), jnp.bfloat16)).compile()
     # the name sparse_attn_ms and sparse_attn_roofline read
     assert "sala_sparse_attention" in compiled.as_text()
+
+
+# The ALS gather's step (PERF.md §6, PR 36): XLA:TPU keeps a gather's
+# operand in VMEM (memory space 1 of the compiled text) while its
+# physical bytes, 128 lanes a row, fit 112 MiB.  These hold the rule's
+# mark (``pallas_kernels.gather_table_pack``) to what the compiler does,
+# and the packed view to bringing als-netflix-r64's user table under it.
+
+def _gather_operands(compiled_text):
+    """(declaration of the table a gather reads) for every gather."""
+    import re
+
+    decl = dict(re.findall(
+        r"(%[\w.\-]+) = (\w+\[[\d,]*\]\{[^}]*\}) parameter", compiled_text))
+    return [decl[m.group(1)] for m in re.finditer(
+        r" gather\((%[\w.\-]+), ", compiled_text)]
+
+
+@pytest.mark.parametrize("rows_past_the_mark,form,fast", [
+    (0, "plain", True),            # 458,752 rows: the last that fit
+    (64, "plain", False),          # the plain gather's step
+    (64, "rows", True),            # ... which the packed view lies under
+    (21_437, "rows", True),        # als-netflix-r64's 480,189 users
+    (458_752, "rows", True),       # 917,504 rows: the view's own reach
+    (541_248, "rows", False),      # 1,000,000: past it, gathered as it is
+], ids=["at-the-mark", "past-it-plain", "past-it", "netflix-users",
+        "view-reach", "past-every-view"])
+def test_gather_operand_lies_in_vmem_where_the_rule_says(
+        one_chip, rows_past_the_mark, form, fast):
+    from predictionio_tpu.models import als
+    from predictionio_tpu.ops import pallas_kernels
+
+    rank, dtype = 64, jnp.dtype(jnp.bfloat16)
+    n_rows = pallas_kernels._GATHER_FAST_TABLE_BYTES // (128 * 2) \
+        + rows_past_the_mark
+    gather = als._gather_rows if form == "rows" else (
+        lambda table, idx, dt: table.astype(dt)[idx])
+    text = jax.jit(lambda table, idx: gather(table, idx, dtype)).lower(
+        jax.ShapeDtypeStruct((n_rows, rank), jnp.float32,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((2048, 512), jnp.int32,
+                             sharding=one_chip)).compile().as_text()
+    (table,) = _gather_operands(text)
+    assert ("S(1)" in table) == fast, table
+    if form == "rows":
+        pack = pallas_kernels.gather_table_pack(n_rows, rank, 2)
+        assert (pack is not None) == fast
+        # the operand is the view's shape exactly where the view engages
+        assert table.startswith(
+            f"bf16[{-(-n_rows // 2)},128]" if pack == 2
+            else f"bf16[{n_rows},64]")

@@ -1,4 +1,4 @@
-"""Per-user serving state of a sequence model, of up to three kinds in
+"""Per-user serving state of a sequence model, of up to four kinds in
 one manager, keyed by user (a loaded model owns its cache, so a cache IS
 a model generation's state: ``/reload`` frees the outgoing one's):
 
@@ -8,7 +8,13 @@ a model generation's state: ``/reload`` frees the outgoing one's):
 * PAGED rows that grow with the user's history: the attention layers'
   keys and values, ``PAGE_SIZE`` events a page; and
 * INDEX rows that ride with the pages (the pooled keys a block selection
-  scores): allotted, evicted and rolled back with the page they sit in.
+  scores): allotted, evicted and rolled back with the page they sit in;
+* WINDOW pages of a pool of their own (a layout with ``window_bytes``:
+  the keys and values of sliding-window attention layers), which a user
+  holds only while an event in them lies within ``window_events`` of the
+  user's last one: see **Window pages** below.  Beside them the paged
+  rows are the pool that grows with the history (the gauge calls it
+  ``full`` there).
 
 All live in device arrays the cache owns (``arrays``); a model's device
 program reads and writes them at the slots and rows a :class:`Plan`
@@ -44,9 +50,25 @@ or, with ``table_len``, through a page table a user that lives on the
 device (``arrays["table"]``, a row a user; the plan names the entries a
 dispatch's new pages add, and the program writes them).
 
+**Window pages.**  A query of a window layer reads itself and the
+``window_events - 1`` events before it, so of a user's window pages only
+those that hold one of the last ``window_events - 1`` events, or will
+hold the next, are ever read again: at most ``window_pages_per_user``.
+A page that has fallen behind goes back to the pool AT COMMIT, never
+before, so a rolled-back turn still finds the window it started from;
+only a page that the open transaction itself handed out (a long history
+read in several programs of one call) is taken back as soon as a later
+program of the transaction has passed it, since no committed state
+names it.  A user's window pages are a short list on the host
+(``Plan.seg_wpages``, the first at index ``Plan.seg_wbase`` of the
+history), beside the long page table of the pool that grows.  The
+window pool holds ``window_pages_per_user`` pages a user and what one
+program can add (a page a user of the write pool, and
+``window_spare_pages``); it is taken off the budget first.
+
 **Budget.**  ``budget_bytes`` of device memory: the fixed slots (a user
-each, the pool and two the cache keeps) and as many pages as the rest
-holds.  When rows or pages run out the least recently used user outside
+each, the pool and two the cache keeps), the window pool where the
+layout has one, and as many pages as the rest holds.  When rows or pages run out the least recently used user outside
 the open transaction is evicted
 (``pio_seq_state_total{result="evicted"}``).
 """
@@ -85,6 +107,10 @@ class _Entry:
     slot: Optional[int]       # the slot that holds the committed state
     length: int = 0           # committed events
     pages: List[int] = dataclasses.field(default_factory=list)
+    # Window pages, oldest first, and the index in the history of the
+    # first (a layout with a window pool).
+    wpages: List[int] = dataclasses.field(default_factory=list)
+    wbase: int = 0
 
 
 @dataclasses.dataclass
@@ -93,6 +119,8 @@ class _Staged:
     pages: List[int]
     slot: Optional[int] = None  # where the transaction writes the key
     wrote: bool = False         # a program has written it
+    wpages: List[int] = dataclasses.field(default_factory=list)
+    wbase: int = 0
 
 
 @dataclasses.dataclass
@@ -111,16 +139,32 @@ class Plan:
     table_row: List[int] = dataclasses.field(default_factory=list)
     new_pages: List[Tuple[int, int, int]] = dataclasses.field(
         default_factory=list)
+    # With a window pool: each segment's window pages as the program
+    # needs them (those the window behind its first new event reaches,
+    # and those its new events fill), and the index in the user's
+    # history of the first.
+    seg_wpages: List[List[int]] = dataclasses.field(default_factory=list)
+    seg_wbase: List[int] = dataclasses.field(default_factory=list)
 
     def rows_of(self, tok_seg: np.ndarray, tok_pos: np.ndarray
                 ) -> np.ndarray:
         """Pool row of each token's key and value."""
+        return self._rows(self.seg_pages, [0] * len(self.seg_pages),
+                          tok_seg, tok_pos)
+
+    def window_rows_of(self, tok_seg: np.ndarray, tok_pos: np.ndarray
+                       ) -> np.ndarray:
+        """Window-pool row of each token's key and value."""
+        return self._rows(self.seg_wpages, self.seg_wbase, tok_seg, tok_pos)
+
+    def _rows(self, seg_pages, seg_base, tok_seg, tok_pos) -> np.ndarray:
         rows = np.zeros(len(tok_seg), np.int64)
-        for s, pages in enumerate(self.seg_pages):
+        for s, (pages, base) in enumerate(zip(seg_pages, seg_base)):
             sel = tok_seg == s
             pos = tok_pos[sel]
-            rows[sel] = (np.asarray(pages, np.int64)[pos // self.page_size]
-                         * self.page_size + pos % self.page_size)
+            rows[sel] = (np.asarray(pages, np.int64)[
+                pos // self.page_size - base] * self.page_size
+                + pos % self.page_size)
         return rows
 
     def page_list(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -154,8 +198,23 @@ class StateCache:
                            "paged": int(layout.get("paged_bytes", 0)),
                            "index": int(layout.get("index_bytes", 0))}
         self.page_bytes = self.kind_bytes["paged"] + self.kind_bytes["index"]
+        # A window pool: its page's bytes, the window's reach in events,
+        # the most pages a committed user holds, the pool's size.
+        self.window_bytes = int(layout.get("window_bytes", 0))
+        self.window_events = int(layout.get("window_events", 0)) \
+            if self.window_bytes else 0
+        self.window_pages_per_user = self.n_wpages = 0
+        if self.window_bytes:
+            self.window_pages_per_user = \
+                -(-(self.window_events - 1) // self.page_size) + 1
+            self.n_wpages = (
+                self.max_users * self.window_pages_per_user
+                + self.write_slots
+                + int(layout.get("window_spare_pages", 0)))
         fixed = self.n_slots * self.slot_bytes \
             + 4 * (1 + self.max_users) * int(self.table_len or 0)
+        if self.window_bytes:
+            fixed += (1 + self.n_wpages) * self.window_bytes
         self.n_pages = int((int(budget_bytes) - fixed - self.page_bytes)
                            // max(self.page_bytes, 1)) \
             if self.page_bytes else 0
@@ -170,6 +229,7 @@ class StateCache:
             collections.OrderedDict()
         self._staged: Dict[Hashable, _Staged] = {}
         self._txn_pages: List[int] = []
+        self._txn_wpages: List[int] = []
         self._txn_created: List[Hashable] = []
         self._fresh_lists()
         self.arrays: Dict[str, Any] = {}
@@ -185,22 +245,31 @@ class StateCache:
             "pio_seq_state_pages_used", "Pages of paged state in use.")
         self._m_bytes = reg.gauge(
             "pio_seq_state_bytes",
-            "Device bytes of per-user state by kind: fixed (every slot), "
-            "paged and index (the pages in use).", ("kind",))
+            "Device bytes of per-user state by kind: fixed (every slot); "
+            "paged and index (the pages in use); or, of a model with a "
+            "window pool, window (its pages in use) and full (the pages "
+            "in use of the pool that grows with the history).", ("kind",))
+        self._m_wpages = reg.counter(
+            "pio_seq_window_pages_total",
+            "Window pages handed to users (taken) and taken back once "
+            "every event in them lay behind the user's window (released; "
+            "an evicted user's are not counted).", ("event",))
         self._gauges()
 
     def _fresh_lists(self) -> None:
         self._free_rows = list(range(self.max_users - 1, -1, -1))
         self._free_pages = list(range(self.n_pages, 0, -1))
         self._free_slots = list(range(self.n_slots - 1, 1, -1))
+        self._free_wpages = list(range(self.n_wpages, 0, -1))
 
     # -- device arrays -------------------------------------------------------
 
     def _allocate(self) -> None:
         import jax.numpy as jnp
 
-        self.arrays = dict(self.layout["allocate"](self.n_slots,
-                                                   self.n_pages))
+        sizes = (self.n_slots, self.n_pages) + (
+            (self.n_wpages,) if self.window_bytes else ())
+        self.arrays = dict(self.layout["allocate"](*sizes))
         if self.table_len:
             self.arrays["table"] = jnp.zeros(
                 (1 + self.max_users, int(self.table_len)), jnp.int32)
@@ -239,6 +308,7 @@ class StateCache:
             self._entries.clear()
             self._staged.clear()
             self._txn_pages.clear()
+            self._txn_wpages.clear()
             self._txn_created.clear()
             self._fresh_lists()
             self._gauges()
@@ -275,6 +345,7 @@ class StateCache:
             self._commit(list(self._staged))
             self._staged.clear()
             self._txn_pages.clear()
+            self._txn_wpages.clear()
             self._txn_created.clear()
             self._gauges()
 
@@ -287,6 +358,10 @@ class StateCache:
             if e is None:
                 continue
             e.length, e.pages = st.length, st.pages
+            if self.window_bytes:
+                # The window pages that fell behind go back only now.
+                self._release_window(set(e.wpages) - set(st.wpages))
+                e.wpages, e.wbase = st.wpages, st.wbase
             if st.wrote:
                 self._release(e.slot)
                 e.slot = st.slot
@@ -301,8 +376,8 @@ class StateCache:
         done = {key for key, st in self._staged.items()
                 if st.wrote and key not in keep}
         self._commit(done)
-        # Their pages stay in ``_txn_pages``: a roll-back frees only
-        # those of it that no entry holds.
+        # Their pages stay in ``_txn_pages`` (and ``_txn_wpages``): a
+        # roll-back frees only those of them that no entry holds.
         self._txn_created = [k for k in self._txn_created if k not in done]
         return bool(self._free_slots)
 
@@ -310,11 +385,24 @@ class StateCache:
         if slot is not None:
             self._free_slots.append(slot)
 
+    def _release_window(self, pages) -> None:
+        """Window pages every event of which lies behind their user's
+        window go back to the pool."""
+        if pages:
+            self._free_wpages.extend(pages)
+            self._txn_wpages = [p for p in self._txn_wpages
+                                if p not in pages]
+            self._m_wpages.inc(len(pages), event="released")
+
     def rollback(self) -> None:
         with self._lock:
             live = {p for e in self._entries.values() for p in e.pages}
             self._free_pages.extend(p for p in self._txn_pages
                                     if p not in live)
+            if self._txn_wpages:
+                held = {p for e in self._entries.values() for p in e.wpages}
+                self._free_wpages.extend(p for p in self._txn_wpages
+                                         if p not in held)
             for st in self._staged.values():
                 self._release(st.slot)
             for key in self._txn_created:
@@ -323,6 +411,7 @@ class StateCache:
                     self._free_rows.append(e.row)
             self._staged.clear()
             self._txn_pages.clear()
+            self._txn_wpages.clear()
             self._txn_created.clear()
             self._gauges()
 
@@ -374,6 +463,12 @@ class StateCache:
                 return self.ZERO_SLOT
             return e.slot
 
+    def table_row(self, key: Hashable) -> int:
+        """The key's row of the device page table (row 0 is nobody's)."""
+        with self._lock:
+            e = self._entries.get(key)
+            return 1 + e.row if e is not None else 0
+
     def plan(self, keys: Sequence[Hashable], seg_len: Sequence[int]
              ) -> Plan:
         """Slots and pages for a dispatch that adds ``seg_len[i]`` events
@@ -397,7 +492,8 @@ class StateCache:
                     self._txn_created.append(key)
                 st = self._staged.get(key)
                 if st is None:
-                    st = _Staged(e.length, list(e.pages))
+                    st = _Staged(e.length, list(e.pages),
+                                 wpages=list(e.wpages), wbase=e.wbase)
                 if st.slot is None:
                     if not self._free_slots \
                             and not self._commit_early(set(keys)):
@@ -414,11 +510,18 @@ class StateCache:
                     self._txn_pages.append(page)
                     plan.new_pages.append((1 + e.row, len(pages), page))
                     pages.append(page)
+                wpages = list(st.wpages)
+                if self.window_bytes:
+                    reach = -(-(start + n) // self.page_size)
+                    for _ in range(reach - st.wbase - len(wpages)):
+                        wpages.append(self._take_window_page(keep, keys))
+                    plan.seg_wpages.append(wpages)
+                    plan.seg_wbase.append(st.wbase)
                 # The pages and the slot are the key's from now on, so
                 # that a later segment's eviction cannot hand them out
                 # again.
                 self._staged[key] = _Staged(st.length, pages, st.slot,
-                                            st.wrote)
+                                            st.wrote, wpages, st.wbase)
                 if len(pages) > self.max_pages:
                     raise StateCacheFull(
                         f"{key!r} would hold {len(pages)} pages; a "
@@ -430,13 +533,40 @@ class StateCache:
                 plan.table_row.append(1 + e.row)
             return plan
 
+    def _take_window_page(self, keep, keys) -> int:
+        """A window page for the plan being made: from the pool; else by
+        committing the transaction's earlier programs, whose users then
+        give back what fell behind their windows; else by an eviction."""
+        if not self._free_wpages:
+            self._commit_early(set(keys))
+        while not self._free_wpages:
+            self._evict_one(keep)
+        page = self._free_wpages.pop()
+        self._txn_wpages.append(page)
+        self._m_wpages.inc(event="taken")
+        return page
+
     def stage(self, plan: Plan) -> None:
-        """The program of ``plan`` ran: its rows are there to commit."""
+        """The program of ``plan`` ran: its rows are there to commit.  Of
+        a user's window pages the transaction keeps those the NEXT event's
+        window reaches; of the others, those it handed out itself go back
+        to the pool now (no committed state names them), the committed
+        ones when it commits."""
         with self._lock:
-            for key, start, n, pages, slot in zip(
+            for i, (key, start, n, pages, slot) in enumerate(zip(
                     plan.keys, plan.seg_start, plan.seg_len, plan.seg_pages,
-                    plan.write_slot):
-                self._staged[key] = _Staged(start + n, pages, slot, True)
+                    plan.write_slot)):
+                st = _Staged(start + n, pages, slot, True)
+                if self.window_bytes:
+                    wpages, wbase = plan.seg_wpages[i], plan.seg_wbase[i]
+                    first = max(start + n - (self.window_events - 1), 0) \
+                        // self.page_size
+                    drop = max(min(first - wbase, len(wpages)), 0)
+                    committed = set(self._entries[key].wpages)
+                    self._release_window(
+                        set(wpages[:drop]) - committed)
+                    st.wpages, st.wbase = wpages[drop:], wbase + drop
+                self._staged[key] = st
 
     # -- eviction -------------------------------------------------------------
 
@@ -466,6 +596,9 @@ class StateCache:
         st = self._staged.pop(key, None)
         pages = set(e.pages) | set(st.pages if st is not None else ())
         self._txn_pages = [p for p in self._txn_pages if p not in pages]
+        wpages = set(e.wpages) | set(st.wpages if st is not None else ())
+        self._txn_wpages = [p for p in self._txn_wpages if p not in wpages]
+        self._free_wpages.extend(wpages)
         if key in self._txn_created:
             self._txn_created.remove(key)
         self._free_pages.extend(pages)
@@ -481,12 +614,23 @@ class StateCache:
         held = bool(self.arrays)
         self._m_bytes.set(held * self.n_slots * self.kind_bytes["fixed"],
                           kind="fixed")
+        if self.window_bytes:
+            self._m_bytes.set(used * self.page_bytes, kind="full")
+            self._m_bytes.set(
+                (self.n_wpages - len(self._free_wpages)) * self.window_bytes,
+                kind="window")
+            return
         for kind in ("paged", "index"):
             self._m_bytes.set(used * self.kind_bytes[kind], kind=kind)
 
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
-            return {"users": len(self._entries),
-                    "pagesUsed": self.n_pages - len(self._free_pages),
-                    "pages": self.n_pages, "slots": self.max_users,
-                    "bytes": self.bytes_in_use()}
+            out = {"users": len(self._entries),
+                   "pagesUsed": self.n_pages - len(self._free_pages),
+                   "pages": self.n_pages, "slots": self.max_users,
+                   "bytes": self.bytes_in_use()}
+            if self.window_bytes:
+                out["windowPagesUsed"] = \
+                    self.n_wpages - len(self._free_wpages)
+                out["windowPages"] = self.n_wpages
+            return out

@@ -5,7 +5,10 @@ every item as the next one.  The algorithm's ``backbone`` picks it:
 ``lfm2`` (:mod:`predictionio_tpu.models.lfm2`: gated short convolutions,
 grouped-query attention, routed experts; the default) or ``sala``
 (:mod:`predictionio_tpu.models.sala`: block-selected sparse attention
-beside lightning linear attention).
+beside lightning linear attention) or ``sambay``
+(:mod:`predictionio_tpu.models.sambay`: Mamba and sliding-window layers
+under one full-attention cache that the later layers read, with gated
+memory units).
 Serving keeps each user's state between queries in the
 :class:`~predictionio_tpu.serving.state_cache.StateCache`, so a query
 pays for the events it brings, not for the history behind them.
@@ -59,6 +62,8 @@ BACKBONES = {
              "predictionio_tpu.models.lfm2_reference"),
     "sala": ("predictionio_tpu.models.sala",
              "predictionio_tpu.models.sala_reference"),
+    "sambay": ("predictionio_tpu.models.sambay",
+               "predictionio_tpu.models.sambay_reference"),
 }
 
 
@@ -199,6 +204,13 @@ class SequenceAlgorithmParams(Params):
         "minicpm4", "lightning-attn", "lightning-attn", "lightning-attn")
     headDim: int = 16  # noqa: N815
     sparseConfig: Optional[Dict[str, int]] = None  # noqa: N815
+    # The ``sambay`` backbone: its depth (a multiple of 4; the layer
+    # pattern follows from it), the window layers' reach, and what of the
+    # Mamba mixer's sizes (d_state, d_conv, expand, dt_rank) differs from
+    # the modelling code's defaults.
+    numHiddenLayers: int = 8  # noqa: N815
+    slidingWindow: int = 16  # noqa: N815
+    ssmConfig: Optional[Dict[str, int]] = None  # noqa: N815
     # Training (next-item cross-entropy over windows of the histories).
     steps: int = 200
     batchSize: int = 16  # noqa: N815

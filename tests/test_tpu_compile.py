@@ -100,6 +100,56 @@ def test_sparse_attention_kernel_compiles_for_v5e(one_chip, tiles, tq,
     assert "sala_sparse_attention" in compiled.as_text()
 
 
+# The Mamba / sliding-window / shared-cache backbone's kernels at the
+# published widths (E 5,120, N 16; 10 kv-head pairs of 128 lanes, a page
+# row of 2,560), in the tile shapes of its programs: 8 events a tile for
+# turns, 64 for prefill chunks, a read row's 16 query rows over a table
+# of 320 pages.
+
+@pytest.mark.parametrize("tiles,tq", [(97, 8), (81, 64)])
+def test_selective_scan_kernel_compiles_for_v5e(one_chip, tiles, tq):
+    from predictionio_tpu.ops import sambay_kernels
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    rows, cols = shape((tiles, tq, 5120)), shape((tiles, 16, tq))
+    compiled = jax.jit(
+        lambda x, delta, bt, ct, a, d, state, *per_tile:
+        sambay_kernels._scan_pallas(
+            x, delta, bt, ct, a, d, state, *per_tile,
+            eb=sambay_kernels.SCAN_BLOCK, interpret=False)).lower(
+        rows, rows, cols, cols, shape((16, 5120)), shape((1, 5120)),
+        shape((130, 16, 5120)),
+        *[shape((tiles,), jnp.int32)] * 4).compile()
+    # the name the benchmark's ssm_scan_ms and ssm_scan_roofline read
+    assert "sambay_selective_scan" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name,tiles,rows,u_max,window,pages", [
+    ("sambay_window_attention", 97, 32, 8, 512, 394),
+    ("sambay_window_attention", 81, 256, 8, 512, 394),
+    ("sambay_shared_attention", 64, 16, 320, 0, 6401)])
+def test_paged_attention_kernel_compiles_for_v5e(one_chip, name, tiles, rows,
+                                                 u_max, window, pages):
+    from predictionio_tpu.ops import sambay_kernels
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, qpos, cnt, lists, pool:
+        sambay_kernels._attention_pallas(
+            q, qpos, cnt, lists, pool, page=128, window=window, pb=4,
+            name=name, interpret=False)).lower(
+        shape((tiles, 10, rows, 128), jnp.bfloat16),
+        shape((tiles, rows), jnp.int32), shape((tiles,), jnp.int32),
+        shape((tiles, u_max), jnp.int32),
+        shape((pages * 128, 2560), jnp.bfloat16)).compile()
+    # the names window_attn_ms / shared_attn_ms and their rooflines read
+    assert name in compiled.as_text()
+
+
 # The ALS gather's step (PERF.md §6, PR 36): XLA:TPU keeps a gather's
 # operand in VMEM (memory space 1 of the compiled text) while its
 # physical bytes, 128 lanes a row, fit 112 MiB.  These hold the rule's

@@ -472,9 +472,23 @@ def _lu_kernel(a_ref, b_ref, reg_ref, x_ref, m_ref, v_ref):
     touching real lanes — the padded x rows are simply never written
     back.
 
-    The elimination SHRINKS: the Python-unrolled outer loop updates only
-    the trailing rows, in 8-row (sublane-granule) quanta so every slice
-    stays aligned — ~K³/3 FLOPs.  Back-substitution runs K cheap
+    The elimination SHRINKS in rows and columns together: under the
+    pivots of block ``jb`` only the trailing sub-matrix ``m[jb:, jb:]`` is
+    rewritten, in 8-row and 8-column (sublane-granule) quanta so every
+    slice stays aligned.  Nothing left of column ``jb`` in a trailing row
+    is read again by anything (later pivots read columns >= their own
+    block, back-substitution reads ``m[0:j, j]``), so at K = 64 a program
+    rewrites 13,056 register tiles where rows at their full width took
+    18,432: ~K³/3 multiply-subtracts (the full-width rows were ~K³/2),
+    twice what a factorisation that also used A's symmetry would do.
+    This one does not: the multiplier of row r
+    under pivot j is read from COLUMN j (``m[r, j]``), so BOTH triangles
+    of A are read and must hold the symmetric matrix, as the gram kernels
+    hand it over.  (A form that takes the multiplier from the pivot row,
+    rewrites only tiles at or right of a row's diagonal tile, 7,680 a
+    program, and reads the upper triangle alone halves the program's time
+    again but its loop lowered 11-17 s slower inside the benchmark's
+    process in three chip calls of four: PERF.md §6 PR 39.)  Back-substitution runs K cheap
     [1, ·, T] steps on the upper-triangular remainder.  No pivoting:
     A + diag(reg) is SPD (ALS-WR reg ≥ λ).
     """
@@ -484,8 +498,9 @@ def _lu_kernel(a_ref, b_ref, reg_ref, x_ref, m_ref, v_ref):
 
     # Forward elimination, block-quantized shrinkage.  Unrolled at BLOCK
     # granularity with a fori_loop over the 8 pivots inside: each pivot's
-    # update spans the aligned sub-matrix from its own block down (rows
-    # above the pivot inside the block are masked out of the multiplier).
+    # update spans the aligned sub-matrix from its own block down and
+    # rightwards (rows above the pivot inside the block are masked out of
+    # the multiplier).
     # The fully-unrolled form emitted ~6 Mosaic ops per pivot and cost
     # 0.83 s of kernel lowering PER DISTINCT BATCH SIZE — with ~34 chunk
     # batch sizes in the fused ALS loop that was most of its 37 s
@@ -496,14 +511,15 @@ def _lu_kernel(a_ref, b_ref, reg_ref, x_ref, m_ref, v_ref):
 
         def fwd(j, _):
             inv = 1.0 / m_ref[pl.ds(j, 1), pl.ds(j, 1), :]    # [1,1,T]
-            row_n = m_ref[pl.ds(j, 1), :, :] * inv            # [1,K,T]
+            row_n = m_ref[pl.ds(j, 1), pl.ds(jb, rows), :] * inv  # [1,rows,T]
             bj = v_ref[pl.ds(j, 1), :, :] * inv               # [1,1,T]
             col = m_ref[pl.ds(jb, rows), pl.ds(j, 1), :]      # [rows,1,T]
             # Rows <= j inside the block must not change: zero their
             # multiplier (cheap [rows,1,1] iota mask, not a [K,K] mask).
             sub_iota = jax.lax.broadcasted_iota(jnp.int32, (rows, 1, 1), 0)
             col = jnp.where(sub_iota + jb > j, col, 0.0)
-            m_ref[pl.ds(jb, rows)] = m_ref[pl.ds(jb, rows)] - col * row_n
+            m_ref[pl.ds(jb, rows), pl.ds(jb, rows)] = (
+                m_ref[pl.ds(jb, rows), pl.ds(jb, rows)] - col * row_n)
             v_ref[pl.ds(jb, rows)] = v_ref[pl.ds(jb, rows)] - col * bj
             return 0
 
@@ -524,7 +540,8 @@ def _lu_kernel(a_ref, b_ref, reg_ref, x_ref, m_ref, v_ref):
 def ridge_solve_lu_pallas(a: jax.Array, b: jax.Array, reg: jax.Array,
                           *, interpret: bool = False) -> jax.Array:
     """Batched SPD solve ``(A + diag(reg)) x = b`` via shrinking
-    elimination — [B,K,K],[B,K],[B]→[B,K].
+    elimination — [B,K,K],[B,K],[B]→[B,K].  ``A`` is read whole: both
+    triangles must hold the symmetric matrix.
 
     Inputs stay in their NATURAL layouts — the lane-major staging happens
     inside the kernel, so no relayout copies are emitted between the gram

@@ -437,6 +437,37 @@ def test_train_with_dense_rows_matches_all_sparse_plan(implicit, use_pallas):
                                rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("dense,kinds", [
+    (True, {"plain", "dense"}), (False, {"plain", "merged"})],
+    ids=["dense-rows", "merged-rows"])
+@pytest.mark.parametrize("implicit", [False, True],
+                         ids=["explicit", "implicit"])
+def test_lanes_solve_trains_as_cholesky_at_the_templates_rank(
+        implicit, dense, kinds):
+    """Rank 10 (the templates' default, no multiple of the solve's granule
+    of 8) through the three call sites of ``_ridge``: plain buckets, dense
+    rows and merged partial rows."""
+    from predictionio_tpu.models.als import (
+        _prepare_als_inputs_device, train_als_prepared,
+    )
+
+    users, items, ratings = _dense_toy()
+    models = {}
+    for solver in ("cholesky", "lu"):
+        cfg = ALSConfig(rank=10, iterations=3, reg=0.065, seed=11,
+                        gram_dtype="float32", implicit=implicit, alpha=0.5,
+                        split_above=16, solver=solver)
+        inputs = _prepare_als_inputs_device(users, items, ratings, 60, 40,
+                                            cfg, dense=dense)
+        assert {b[0] for b in inputs.user_buckets} == kinds
+        models[solver] = train_als_prepared(inputs, cfg)
+    for side in ("user_factors", "item_factors"):
+        np.testing.assert_allclose(
+            np.asarray(getattr(models["lu"], side)),
+            np.asarray(getattr(models["cholesky"], side)),
+            rtol=1e-4, atol=1e-4)
+
+
 @pytest.mark.parametrize("device_prep", [True, False],
                          ids=["device-prep", "host-prep"])
 def test_gram_ratings_counter_adds_up_to_ratings_times_sweeps(device_prep):

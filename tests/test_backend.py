@@ -218,6 +218,12 @@ F32, BF16, U8 = jnp.float32, jnp.bfloat16, jnp.uint8
      [((64, 1160, 64), BF16), ((64, 1160), F32), ((64, 1160), F32)]),
     ("lu", partial(pk.ridge_solve_lu_pallas, interpret=False),
      [((6040, 64, 64), F32), ((6040, 64), F32), ((6040,), F32)]),
+    # the templates' default rank (a last block of 2 rows and 2 columns),
+    # and als-netflix-r64's largest user-side batch, its dense rows
+    ("lu-k10", partial(pk.ridge_solve_lu_pallas, interpret=False),
+     [((6040, 10, 10), F32), ((6040, 10), F32), ((6040,), F32)]),
+    ("lu-netflix-users", partial(pk.ridge_solve_lu_pallas, interpret=False),
+     [((23488, 64, 64), F32), ((23488, 64), F32), ((23488,), F32)]),
     # serving shapes: a 2.5M x 64 corpus, menu k, B=1 and B=64
     ("topk-b1", partial(pk.fused_topk_pallas, k=10, n_valid=2_499_990,
                         interpret=False),

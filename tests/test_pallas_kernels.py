@@ -78,7 +78,8 @@ def test_unknown_solver_is_refused(solver):
 
 
 # (B, K, rows of the factor whose Gram matrix is A, added to A's diagonal,
-#  least ridge): A = y^T y + diag * I, reg uniform in [least, least + 1).
+#  least ridge[, width of the ridge's range]): A = y^T y + diag * I, reg
+#  uniform in [least, least + width), width 1 unless given.
 _LU_CASES = {
     "b67_k32": (67, 32, 32, 2.0, 0.1),
     # the retired Gauss-Jordan kernel's case: a Gram matrix of K + 3 rows
@@ -88,7 +89,33 @@ _LU_CASES = {
     "b129_k64": (129, 64, 64, 2.0, 0.1),
     # no ridge at all: the elimination alone on a well-conditioned system
     "reg0": (9, 16, 64, 1.0, 0.0),
+    # the templates' default rank: one whole granule of 8 and a last block
+    # of 2 rows and 2 columns
+    "k10": (33, 10, 12, 0.0, 0.1),
+    "k20": (17, 20, 25, 0.0, 0.1),
+    # the last rank lanes_solve_fits_vmem admits
+    "k70": (5, 70, 80, 0.0, 0.1),
+    # two full blocks of 128 lanes and one system
+    "b257_k64": (257, 64, 64, 2.0, 0.1),
+    # the retrain cell's lightest users under ALS-WR: ONE rating, so A is
+    # one row's outer product plus 0.065 on the diagonal, the
+    # worst-conditioned system the sweep solves
+    "b16_k64_deg1": (16, 64, 1, 0.0, 0.065, 0.0),
 }
+
+
+def _lu_case(case):
+    B, K, rows, diag, least, width = (*_LU_CASES[case], 1.0)[:6]
+    rng = np.random.default_rng(3)
+    y = rng.standard_normal((B, rows, K)).astype(np.float32)
+    A = np.einsum("blk,blm->bkm", y, y) + diag * np.eye(K, dtype=np.float32)
+    b = rng.standard_normal((B, K)).astype(np.float32)
+    reg = ((least + width * rng.random(B)).astype(np.float32) if least
+           else np.zeros(B, np.float32))
+    ref = np.stack([np.linalg.solve(A[i].astype(np.float64)
+                                    + np.float64(reg[i]) * np.eye(K), b[i])
+                    for i in range(B)])
+    return A, b, reg, ref
 
 
 @pytest.mark.parametrize("case", sorted(_LU_CASES))
@@ -96,17 +123,9 @@ def test_ridge_solve_lu_matches_oracle(case):
     """Shrinking-elimination solver (the TPU auto path) vs numpy."""
     from predictionio_tpu.ops.pallas_kernels import ridge_solve_lu_pallas
 
-    B, K, rows, diag, least = _LU_CASES[case]
-    rng = np.random.default_rng(3)
-    y = rng.standard_normal((B, rows, K)).astype(np.float32)
-    A = np.einsum("blk,blm->bkm", y, y) + diag * np.eye(K, dtype=np.float32)
-    b = rng.standard_normal((B, K)).astype(np.float32)
-    reg = (rng.random(B).astype(np.float32) + least if least
-           else np.zeros(B, np.float32))
+    A, b, reg, ref = _lu_case(case)
     x = np.asarray(ridge_solve_lu_pallas(
         jnp.asarray(A), jnp.asarray(b), jnp.asarray(reg), interpret=True))
-    ref = np.stack([np.linalg.solve(A[i] + reg[i] * np.eye(K), b[i])
-                    for i in range(B)])
     np.testing.assert_allclose(x, ref, rtol=2e-4, atol=2e-4)
 
 

@@ -1,6 +1,6 @@
 """Mosaic's verdict on the kernels of the main paths at real widths (the
-ALS dense gram; the sequence engine's selected attention and lightning
-update), with no
+ALS dense gram and lanes solve; the sequence engine's selected attention
+and lightning update), with no
 chip: libtpu compiles for a described v5e in the sandbox (PERF.md, PR 21).
 It proves compilation, not results.  The topology is described inside a
 fixture, by the one worker that runs this file; keep every such test
@@ -51,6 +51,29 @@ def test_dense_gram_kernel_compiles_for_v5e(one_chip, rank, rows, n_src,
         shape((), jnp.float32)).compile()
     # the name the benchmark's als_gram_roofline and als_dense_gram_ms read
     assert "fused_gram_dense_pallas" in compiled.as_text()
+
+
+# The lanes solve in the shapes its slices differ by: als-netflix-r64's
+# largest user-side batch (its dense rows) at the cell's rank, the
+# templates' default rank 10 (a last block of 2 rows and 2 columns) and
+# the last rank lanes_solve_fits_vmem admits, which has to fit VMEM.
+
+@pytest.mark.parametrize("batch,rank", [(23_488, 64), (6_040, 10),
+                                        (6_040, 70)])
+def test_lanes_solve_kernel_compiles_for_v5e(one_chip, batch, rank):
+    from predictionio_tpu.ops.pallas_kernels import (
+        lanes_solve_fits_vmem, ridge_solve_lu_pallas,
+    )
+
+    def shape(dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    assert lanes_solve_fits_vmem(rank)
+    compiled = ridge_solve_lu_pallas.lower(
+        shape((batch, rank, rank)), shape((batch, rank)),
+        shape((batch,))).compile()
+    # the name the benchmark's als_solve_roofline reads
+    assert "ridge_solve_lu_pallas" in compiled.as_text()
 
 
 # The block-selected / lightning backbone's kernels at the published

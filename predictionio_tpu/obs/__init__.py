@@ -14,6 +14,9 @@ Three parts, one process-wide state:
 - :mod:`predictionio_tpu.obs.runtime` — runtime introspection below the
   request/training layer: XLA compile tracking, device-memory telemetry,
   the per-step timeline ring, and trace-ring event publication.
+- :mod:`predictionio_tpu.obs.host` — the host's own ledger: threads by
+  role from ``schedstat``, the cycle collector's pauses, the cgroup's
+  CPU throttling, read when the registry renders.
 - :mod:`predictionio_tpu.obs.profiler` — on-demand bounded
   ``jax.profiler`` capture behind ``POST /admin/profile`` and
   ``pio profile``.
@@ -114,28 +117,39 @@ def phase(name: str, **attrs) -> span:
     The workflow's named phases (datasource / prepare / train / persist,
     and the ``prep.*`` / ``train.*`` phases inside ALS prep and train)
     show up in the trace tree, as ``pio_train_phase_ms{phase=...}``
-    series, and on a profiler capture's host timeline — crashed phases
-    too, the runs most worth seeing.
-    (The metric name is a literal by design — tools/lint_metrics.py
+    series with the CPU twin ``pio_train_phase_cpu_ms`` (what the
+    phase's own thread ran of that wall), and on a profiler capture's
+    host timeline — crashed phases too, the runs most worth seeing.
+    (The metric names are literals by design — tools/lint_metrics.py
     keeps every registered name statically checkable.)
     """
-    hist = get_registry().histogram(
+    reg = get_registry()
+    hist = reg.histogram(
         "pio_train_phase_ms", "Workflow phase duration by phase name.",
         ("phase",))
+    cpu_hist = reg.histogram(
+        "pio_train_phase_cpu_ms",
+        "CPU time the phase's own thread ran, by phase name.", ("phase",))
     return span(name, hist=hist, labels={"phase": name}, annotate=True,
-                **attrs)
+                cpu_hist=cpu_hist, **attrs)
 
 
 def dispatch_stage(name: str, stage: str, **attrs) -> span:
     """:func:`phase`'s serving twin: one host stage of a batched
     dispatch (bind, supplement, lookup, h2d, launch, wait, assemble,
-    serve) as span ``name``, ``pio_dispatch_stage_ms{stage}`` and a
-    ``pio:<name>`` annotation.  Per dispatch, never per request."""
-    hist = get_registry().histogram(
+    serve) as span ``name``, ``pio_dispatch_stage_ms{stage}`` with its
+    CPU twin ``pio_dispatch_stage_cpu_ms{stage}`` and a ``pio:<name>``
+    annotation.  Per dispatch, never per request."""
+    reg = get_registry()
+    hist = reg.histogram(
         "pio_dispatch_stage_ms",
         "Host stages of one batched dispatch, by stage.", ("stage",))
+    cpu_hist = reg.histogram(
+        "pio_dispatch_stage_cpu_ms",
+        "CPU time the dispatching thread ran inside each host stage of "
+        "one batched dispatch, by stage.", ("stage",))
     return span(name, hist=hist, labels={"stage": stage}, annotate=True,
-                **attrs)
+                cpu_hist=cpu_hist, **attrs)
 
 
 def reset_observability() -> None:

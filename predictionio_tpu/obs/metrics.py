@@ -26,10 +26,14 @@ Naming convention (enforced only by review, documented in README):
 
 from __future__ import annotations
 
+import bisect
+import logging
 import math
 import re
 import threading
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
+
+logger = logging.getLogger(__name__)
 
 __all__ = [
     "Counter",
@@ -114,11 +118,15 @@ class _Metric:
         self._lock = threading.Lock()
 
     def _key(self, labels: Dict[str, object]) -> Tuple[str, ...]:
-        if set(labels) != set(self.labelnames):
-            raise ValueError(
-                f"{self.name} expects labels {self.labelnames}, "
-                f"got {tuple(sorted(labels))}")
-        return tuple(str(labels[n]) for n in self.labelnames)
+        names = self.labelnames
+        if len(labels) == len(names):
+            try:
+                return tuple([str(labels[n]) for n in names])
+            except KeyError:
+                pass
+        raise ValueError(
+            f"{self.name} expects labels {names}, "
+            f"got {tuple(sorted(labels))}")
 
     def render(self) -> List[str]:  # pragma: no cover - overridden
         raise NotImplementedError
@@ -229,6 +237,11 @@ class Histogram(_Metric):
         if bs and bs[-1] == math.inf:
             bs = bs[:-1]  # +Inf is implicit
         self.buckets = tuple(bs)
+        # The declaration as the caller wrote it: a module's constant
+        # comes back as the same object at every get-or-create, and the
+        # registry then has nothing to normalise (spans look their
+        # histograms up once a stage).
+        self._declared = buckets
         self._series: Dict[Tuple[str, ...], _HistSeries] = {}
 
     def observe(self, value: float, exemplar: Optional[str] = None,
@@ -244,11 +257,10 @@ class Histogram(_Metric):
             s = self._series.get(key)
             if s is None:
                 s = self._series[key] = _HistSeries(len(self.buckets) + 1)
-            i = len(self.buckets)  # +Inf slot
-            for j, b in enumerate(self.buckets):
-                if v <= b:
-                    i = j
-                    break
+            # First bound with v <= bound; past the last (and NaN, which
+            # is <= nothing) is the +Inf slot.
+            i = (bisect.bisect_left(self.buckets, v) if v == v
+                 else len(self.buckets))
             s.counts[i] += 1
             s.sum += v
             s.count += 1
@@ -387,11 +399,26 @@ class MetricsRegistry:
     the name is already registered (validating kind and labelnames match),
     so a second server instance in the same process shares series rather
     than shadowing them.
+
+    A COLLECTOR (:meth:`add_collector`) is a function the registry calls
+    with itself before every :meth:`render`: series read from outside
+    the process's own code paths (``obs.host``: ``/proc``, the cgroup)
+    are brought up to date when somebody looks, and cost nothing
+    between two looks.
     """
 
     def __init__(self):
         self._lock = threading.Lock()
         self._metrics: Dict[str, _Metric] = {}
+        self._collectors: List[Callable[["MetricsRegistry"], None]] = []
+
+    def add_collector(self,
+                      fn: Callable[["MetricsRegistry"], None]) -> None:
+        """``fn(registry)`` runs before every render; adding it twice
+        keeps one."""
+        with self._lock:
+            if fn not in self._collectors:
+                self._collectors.append(fn)
 
     def _get_or_create(self, cls, name, help, labelnames, **kw) -> _Metric:
         with self._lock:
@@ -405,7 +432,8 @@ class MetricsRegistry:
                         f"metric {name!r} labelnames mismatch: "
                         f"{m.labelnames} vs {tuple(labelnames)}")
                 want_buckets = kw.get("buckets")
-                if want_buckets is not None:
+                if want_buckets is not None \
+                        and want_buckets is not m._declared:
                     norm = tuple(sorted(float(b) for b in want_buckets
                                         if b != math.inf))
                     if norm != m.buckets:
@@ -449,6 +477,14 @@ class MetricsRegistry:
         over it — so the default render stays clean and the servers only
         opt in for ``/metrics?exemplars=1`` (our own tools: the trace
         resolver behind the waterfall buckets)."""
+        with self._lock:
+            collectors = list(self._collectors)
+        for fn in collectors:
+            try:
+                fn(self)
+            except Exception:
+                # A scrape must answer with what the process has.
+                logger.exception("metrics collector %r failed", fn)
         lines: List[str] = []
         for m in self.metrics():
             if m.help:
@@ -463,7 +499,8 @@ class MetricsRegistry:
             self._metrics.pop(name, None)
 
     def reset(self) -> None:
-        """Drop every instrument (test isolation; never in production)."""
+        """Drop every instrument (test isolation; never in production).
+        Collectors stay: what they publish starts again from zero."""
         with self._lock:
             self._metrics.clear()
 

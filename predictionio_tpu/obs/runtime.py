@@ -40,6 +40,7 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
+from predictionio_tpu.obs.host import get_host_ledger, register_thread
 from predictionio_tpu.obs.metrics import MetricsRegistry, get_registry
 from predictionio_tpu.obs.trace import (
     Span,
@@ -48,6 +49,7 @@ from predictionio_tpu.obs.trace import (
     current_trace_id,
     get_recorder,
     new_trace_id,
+    span,
 )
 
 logger = logging.getLogger(__name__)
@@ -479,10 +481,28 @@ class DeviceMemorySampler:
             self._thread.start()
         return True
 
+    def _tick(self) -> span:
+        """One tick as a span: ``pio:mem_sampler.sample`` on a capture's
+        clock, ``pio_mem_sampler_ms`` and its CPU twin.  Wall far over
+        CPU says ``memory_stats()`` waited for the runtime (and whether
+        it held the interpreter meanwhile shows in what else stalled
+        under the annotation)."""
+        reg = self._reg()
+        return span(
+            "mem_sampler.sample", annotate=True,
+            hist=reg.histogram(
+                "pio_mem_sampler_ms",
+                "One tick of the device-memory sampler."),
+            cpu_hist=reg.histogram(
+                "pio_mem_sampler_cpu_ms",
+                "CPU time the sampler's thread ran inside one tick."))
+
     def _run(self) -> None:
+        register_thread("sampler")
         while not self._stop.wait(self.interval_s):
             try:
-                self.sample_once()
+                with self._tick():
+                    self.sample_once()
             except Exception:
                 logger.exception("device-memory sample failed")
 
@@ -719,7 +739,10 @@ def set_timeline(timeline: StepTimeline) -> StepTimeline:
 def start_runtime_introspection(*, sample: bool = True) -> None:
     """Idempotent per-process bring-up, called by the servers: register
     the compile/memory instruments (so ``/metrics`` exposes the names
-    before the first event) and start the memory-sampler thread."""
+    before the first event), start the memory-sampler thread and hang
+    the host ledger (``obs.host``: threads by role, the cycle
+    collector's pauses, CPU throttling) on the registry's render."""
+    get_host_ledger().install()
     get_compile_tracker().touch()
     sampler = get_memory_sampler()
     sampler.touch()

@@ -19,6 +19,12 @@ Usage shape (the tentpole's API)::
   (never per request): ``hist=``/``labels=`` observes its duration into
   a histogram series on exit, and ``annotate=True`` holds a
   ``jax.profiler.TraceAnnotation("pio:<name>")`` open for its lifetime.
+  ``cpu_hist=`` is the wall histogram's twin: the CPU time the span's
+  own thread ran between enter and exit (``time.thread_time``), under
+  the same labels.  Wall minus CPU is the span's off-CPU time: a wait
+  it asked for, or time it wanted to run and did not (the GIL, or the
+  OS; ``obs.host`` tells which).  Where the host's thread clock is too
+  coarse for a span (``_probe_thread_clock``) the twin is not observed.
   ``TraceMe`` is inert while no profiler capture runs; under one (``pio
   profile``, ``POST /admin/profile``, a harness's ``start_trace``) the
   span sits on the device trace's clock, nested per thread.  jax is
@@ -111,6 +117,46 @@ _EPOCH_WALL = time.time() - time.perf_counter()
 # an injected clock (tiling and self-time invariants hold exactly there).
 _now = time.perf_counter
 
+# The calling thread's CPU clock, read only by a span with a ``cpu_hist``
+# (a syscall, ~0.5 us: a span without one pays nothing).  A module
+# attribute for the same reason as ``_now``.
+_thread_now = time.thread_time
+
+# Whether this host's thread clock can time a sub-millisecond span; None
+# until the first span with a ``cpu_hist`` asks.  A sandboxed kernel
+# (gVisor, measured on the chip's host) advances CLOCK_THREAD_CPUTIME_ID
+# in 10 ms ticks and charges ~6 us a reading, where Linux steps by a
+# microsecond for 0.4 us: there the twin would cost a serve-steady
+# request 2% of its median and say nothing, so it is not observed.
+_thread_clock_fine: Optional[bool] = None
+
+
+def _probe_thread_clock() -> bool:
+    """Spin until the calling thread's CPU clock moves, 2 ms at most:
+    fine if its first step is under a millisecond."""
+    global _thread_clock_fine
+    fine = False
+    start = time.thread_time()
+    deadline = time.perf_counter() + 0.002
+    while time.perf_counter() < deadline:
+        step = time.thread_time() - start
+        if step > 0:
+            fine = step < 1e-3
+            break
+    _thread_clock_fine = fine
+    return fine
+
+
+def _usable(cpu_hist):
+    """``cpu_hist`` where the thread clock can serve it, else None."""
+    if cpu_hist is None:
+        return None
+    fine = _thread_clock_fine
+    if fine is None:
+        fine = _probe_thread_clock()
+    return cpu_hist if fine else None
+
+
 # jax.profiler.TraceAnnotation once the process holds jax (None before).
 _TraceAnnotation = None
 
@@ -194,22 +240,24 @@ class span:
     query.  Detached use (no open trace) still times the block — callers
     may read ``.duration_ms`` — but joins no tree.
 
-    ``hist``/``labels`` and ``annotate`` are the two sinks that do not
-    need an open trace (module docstring); the exception path closes
-    both.
+    ``hist``/``labels``, ``cpu_hist`` and ``annotate`` are the sinks
+    that do not need an open trace (module docstring); the exception
+    path closes all of them.  The thread clock is read inside the wall
+    clock's two readings, so a span's CPU time never exceeds its wall.
     """
 
     __slots__ = ("_name", "_attrs", "_span", "_token", "_hist", "_labels",
-                 "_annotate", "_ann")
+                 "_annotate", "_ann", "_cpu_hist", "_cpu0")
 
     def __init__(self, name: str, *, hist=None,
                  labels: Optional[Dict[str, str]] = None,
-                 annotate: bool = False, **attrs):
+                 annotate: bool = False, cpu_hist=None, **attrs):
         self._name = name
         self._attrs = attrs
         self._hist = hist
         self._labels = labels or {}
         self._annotate = annotate
+        self._cpu_hist = _usable(cpu_hist)
 
     def __enter__(self) -> Span:
         self._ann = _open_annotation(self._name) if self._annotate else None
@@ -220,9 +268,13 @@ class span:
         else:
             parent.children.append(s)
             self._token = _current_span.set(s)
+        if self._cpu_hist is not None:
+            self._cpu0 = _thread_now()
         return s
 
     def __exit__(self, *exc) -> bool:
+        if self._cpu_hist is not None:
+            cpu_ms = (_thread_now() - self._cpu0) * 1e3
         s = self._span
         s.finish()
         if self._token is not None:
@@ -231,6 +283,8 @@ class span:
             self._ann.__exit__(*exc)
         if self._hist is not None:
             self._hist.observe(s.duration_ms, **self._labels)
+        if self._cpu_hist is not None:
+            self._cpu_hist.observe(cpu_ms, **self._labels)
         return False
 
 
@@ -259,26 +313,30 @@ def attach_event(parent: Optional[Span], name: str, **attrs) -> Span:
 def trace(name: str, trace_id: Optional[str] = None,
           slow_ms: Optional[float] = None, recorder: Optional["TraceRecorder"] = None,
           *, hist=None, labels: Optional[Dict[str, str]] = None,
-          annotate: bool = False, **attrs):
+          annotate: bool = False, cpu_hist=None, **attrs):
     """Root span + trace id binding; records the finished tree on exit.
 
     Nested ``trace()`` calls degrade to plain child spans of the enclosing
     trace (one tree per request/run, never silently dropped timing).
-    ``hist``/``labels``/``annotate`` are :class:`span`'s.
+    ``hist``/``labels``/``annotate``/``cpu_hist`` are :class:`span`'s.
     """
     if _current_span.get() is not None:
         with span(name, hist=hist, labels=labels, annotate=annotate,
-                  **attrs) as s:
+                  cpu_hist=cpu_hist, **attrs) as s:
             yield s
         return
+    cpu_hist = _usable(cpu_hist)
     tid = sanitize_trace_id(trace_id) or new_trace_id()
     ann = _open_annotation(name) if annotate else None
     root = Span(name, attrs)
     tok_span = _current_span.set(root)
     tok_tid = _current_trace_id.set(tid)
+    cpu0 = _thread_now() if cpu_hist is not None else 0.0
     try:
         yield root
     finally:
+        if cpu_hist is not None:
+            cpu_ms = (_thread_now() - cpu0) * 1e3
         root.finish()
         _current_span.reset(tok_span)
         _current_trace_id.reset(tok_tid)
@@ -286,6 +344,8 @@ def trace(name: str, trace_id: Optional[str] = None,
             ann.__exit__(*sys.exc_info())
         if hist is not None:
             hist.observe(root.duration_ms, **(labels or {}))
+        if cpu_hist is not None:
+            cpu_hist.observe(cpu_ms, **(labels or {}))
         (recorder or get_recorder()).record(tid, root, slow_ms=slow_ms)
 
 

@@ -39,6 +39,7 @@ from typing import Any, Dict, List, Optional, Tuple, Union
 from urllib.parse import parse_qs, urlparse
 
 from predictionio_tpu.obs import waterfall as _waterfall
+from predictionio_tpu.obs.host import register_thread, retire_thread
 from predictionio_tpu.obs.trace import (
     attach_event,
     current_trace_id,
@@ -73,6 +74,15 @@ PROMETHEUS_CTYPE = "text/plain; version=0.0.4"
 class ThreadingHTTPServer(_ThreadingHTTPServer):
     # Default accept backlog (5) resets connections under load bursts.
     request_queue_size = 128
+
+    def process_request_thread(self, request, client_address):
+        """A connection's thread, start to end: its CPU time counts as
+        the ``handler`` role's in the host ledger (``obs.host``)."""
+        register_thread("handler")
+        try:
+            super().process_request_thread(request, client_address)
+        finally:
+            retire_thread()
 
 
 def incoming_request_id(headers) -> Optional[str]:

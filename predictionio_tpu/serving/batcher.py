@@ -40,6 +40,7 @@ import uuid
 from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 from predictionio_tpu.obs import get_registry
+from predictionio_tpu.obs.host import register_thread
 from predictionio_tpu.obs.trace import attach_event, span, trace as _trace
 from predictionio_tpu.obs.waterfall import Waterfall, dispatch_sink
 from predictionio_tpu.resilience.deadline import DeadlineExceeded
@@ -125,6 +126,11 @@ class MicroBatcher:
             "an empty queue, waiting out the batch window, claiming and "
             "shedding, inside dispatch_fn, handing results back.",
             ("model", "phase"))
+        self._m_thread_cpu = reg.histogram(
+            "pio_batcher_thread_cpu_ms",
+            "CPU time the batcher thread ran inside each phase of its "
+            "wall: wall minus this is what it spent off the CPU.",
+            ("model", "phase"))
         self._phase_labels = {p: {"model": model, "phase": p}
                               for p in THREAD_PHASES}
         self._m_dispatches = reg.counter(
@@ -160,12 +166,13 @@ class MicroBatcher:
 
     def _phase(self, phase: str) -> span:
         """One entry of the thread's ledger: span ``batcher.<phase>``,
-        ``pio_batcher_thread_ms{phase}`` and a ``pio:`` annotation (the
-        span's trace-independent sinks; per batcher turn, never per
-        request).  The five phases tile the thread's wall: whatever the
-        loop does belongs inside one of them."""
+        ``pio_batcher_thread_ms{phase}`` with its CPU twin and a ``pio:``
+        annotation (the span's trace-independent sinks; per batcher
+        turn, never per request).  The five phases tile the thread's
+        wall: whatever the loop does belongs inside one of them."""
         return span("batcher." + phase, hist=self._m_thread,
-                    labels=self._phase_labels[phase], annotate=True)
+                    labels=self._phase_labels[phase], annotate=True,
+                    cpu_hist=self._m_thread_cpu)
 
     def _latest_dispatch_s(self, entry: Pending) -> float:
         """Latest clock time this entry could still be dispatched and
@@ -289,7 +296,8 @@ class MicroBatcher:
             with _trace("batcher.dispatch", trace_id=trace_id,
                         hist=self._m_thread,
                         labels=self._phase_labels["dispatch"],
-                        annotate=True, model=self.model,
+                        annotate=True, cpu_hist=self._m_thread_cpu,
+                        model=self.model,
                         batch_id=batch_id, batch_size=len(live)) as troot:
                 with dispatch_sink(sink):
                     results, generation = self.dispatch_fn(
@@ -449,6 +457,7 @@ class MicroBatcher:
         return self.dispatch(batch)
 
     def _loop(self) -> None:
+        register_thread("batcher")
         while not self.queue.closed():
             try:
                 self.run_once()

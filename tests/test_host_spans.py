@@ -107,6 +107,186 @@ def test_span_observes_its_histogram_with_no_trace_open(monkeypatch):
     assert hist.sum(part="a") == pytest.approx(750.0)
 
 
+class TwoDials:
+    """The wall clock and the thread's CPU clock, each moved by hand;
+    ``reads`` is the order in which a span read them."""
+
+    def __init__(self):
+        self.wall, self.cpu, self.reads = 100.0, 7.0, []
+
+    def now(self):
+        self.reads.append("wall")
+        return self.wall
+
+    def thread_now(self):
+        self.reads.append("cpu")
+        return self.cpu
+
+    def run(self, wall_s, cpu_s):
+        self.wall += wall_s
+        self.cpu += cpu_s
+
+
+@pytest.fixture(autouse=True)
+def fine_thread_clock(monkeypatch):
+    """The host's thread clock is taken as fine (Linux); the tests of
+    the probe itself say otherwise."""
+    monkeypatch.setattr(trace_mod, "_thread_clock_fine", True)
+
+
+@pytest.fixture()
+def dials(monkeypatch):
+    d = TwoDials()
+    monkeypatch.setattr(trace_mod, "_now", d.now)
+    monkeypatch.setattr(trace_mod, "_thread_now", d.thread_now)
+    return d
+
+
+def test_a_span_books_its_threads_cpu_beside_its_wall(dials):
+    reg = get_registry()
+    wall = reg.histogram("pio_test_span_ms", "t", ("part",))
+    cpu = reg.histogram("pio_test_span_cpu_ms", "t", ("part",))
+    with span("work", hist=wall, cpu_hist=cpu, labels={"part": "a"}):
+        dials.run(0.25, 0.1)
+    assert wall.sum(part="a") == pytest.approx(250.0)
+    assert cpu.sum(part="a") == pytest.approx(100.0)
+    assert cpu.count(part="a") == wall.count(part="a") == 1
+    # The thread clock is read INSIDE the wall clock's two readings, so
+    # on real clocks a span's CPU time cannot pass its wall.
+    assert dials.reads == ["wall", "cpu", "cpu", "wall"]
+    # The root of a trace books it the same way, and a nested trace().
+    with trace("root", hist=wall, cpu_hist=cpu, labels={"part": "r"}):
+        dials.run(0.5, 0.5)
+        with trace("nested", hist=wall, cpu_hist=cpu,
+                   labels={"part": "n"}):
+            dials.run(0.125, 0.0)
+    assert (wall.sum(part="r"), cpu.sum(part="r")) == pytest.approx(
+        (625.0, 500.0))
+    assert (wall.sum(part="n"), cpu.sum(part="n")) == pytest.approx(
+        (125.0, 0.0))
+
+
+@pytest.mark.parametrize("opener", ["span", "trace"])
+def test_the_cpu_observation_is_made_on_the_exception_path(dials, opener):
+    reg = get_registry()
+    wall = reg.histogram("pio_test_span_ms", "t", ("part",))
+    cpu = reg.histogram("pio_test_span_cpu_ms", "t", ("part",))
+    open_ = span if opener == "span" else trace
+    with pytest.raises(KeyError):
+        with open_("work", hist=wall, cpu_hist=cpu, labels={"part": "a"}):
+            dials.run(0.004, 0.003)
+            raise KeyError("boom")
+    assert cpu.count(part="a") == 1
+    assert cpu.sum(part="a") == pytest.approx(3.0)
+    assert wall.sum(part="a") == pytest.approx(4.0)
+
+
+def test_a_span_without_a_cpu_histogram_reads_no_thread_clock(monkeypatch):
+    def no_thread_clock():
+        raise AssertionError("a span with no cpu_hist read thread_time")
+
+    monkeypatch.setattr(trace_mod, "_thread_now", no_thread_clock)
+    wall = get_registry().histogram("pio_test_span_ms", "t", ("part",))
+    with trace("http.request"):
+        with span("http.read"):
+            pass
+        with span("work", hist=wall, labels={"part": "a"}, annotate=True):
+            pass
+    assert wall.count(part="a") == 1
+
+
+@pytest.mark.parametrize("step_s, reads_until_it_moves, fine", [
+    (1e-6, 1, True),        # Linux: a microsecond a reading
+    (1e-2, 40, False),      # a sandboxed kernel: one 10 ms tick
+    (0.0, 0, False),        # never moves within the 2 ms
+])
+def test_the_thread_clock_is_probed_once_and_a_coarse_one_gets_no_twin(
+        monkeypatch, step_s, reads_until_it_moves, fine):
+    state = {"reads": 0, "wall": 50.0}
+
+    def thread_time():
+        state["reads"] += 1
+        moved = reads_until_it_moves and \
+            state["reads"] > reads_until_it_moves
+        return 3.0 + (step_s if moved else 0.0)
+
+    def perf_counter():
+        state["wall"] += 1e-5
+        return state["wall"]
+
+    monkeypatch.setattr(trace_mod, "_thread_clock_fine", None)
+    monkeypatch.setattr(trace_mod.time, "thread_time", thread_time)
+    monkeypatch.setattr(trace_mod.time, "perf_counter", perf_counter)
+    reg = get_registry()
+    wall = reg.histogram("pio_test_span_ms", "t", ("part",))
+    cpu = reg.histogram("pio_test_span_cpu_ms", "t", ("part",))
+    with span("work", hist=wall, cpu_hist=cpu, labels={"part": "a"}):
+        pass
+    assert trace_mod._thread_clock_fine is fine
+    probed = state["reads"]
+    assert probed <= 1 + (reads_until_it_moves or 200)
+    with span("work", hist=wall, cpu_hist=cpu, labels={"part": "a"}):
+        pass
+    assert state["reads"] == probed         # asked once a process
+    assert wall.count(part="a") == 2
+    assert cpu.count(part="a") == (2 if fine else 0)
+
+
+def test_on_the_real_clocks_cpu_is_at_most_wall():
+    for _ in range(50):
+        with dispatch_stage("predict.assemble", "assemble"):
+            sum(range(2000))
+    reg = get_registry()
+    wall = reg.get("pio_dispatch_stage_ms").sum(stage="assemble")
+    cpu = reg.get("pio_dispatch_stage_cpu_ms").sum(stage="assemble")
+    assert 0.0 < cpu <= wall
+
+
+def _open_dispatch_stages():
+    for stage in ("bind", "supplement", "lookup", "h2d", "launch", "wait",
+                  "assemble", "serve", "seq_extend", "seq_h2d",
+                  "seq_launch", "seq_wait"):
+        with dispatch_stage("stage." + stage, stage):
+            pass
+
+
+def _open_train_phases():
+    for name in ("train.prepare", "prep.plan", "prep.lower_loop"):
+        with phase(name):
+            pass
+
+
+def _run_a_batcher_turn():
+    queue = ModelQueue("m", depth=4)
+    clock = Dial()
+    clock.wait = lambda cond, timeout: False
+    batcher = MicroBatcher(
+        "m", queue, lambda qs: ([{"ok": True}] * len(qs), 1),
+        window_s=0.0, max_size=4, clock=clock)
+    queue.put(Pending({"q": 1}, clock.now()))
+    assert batcher.run_once() == 1
+
+
+@pytest.mark.parametrize("wall_family, cpu_family, drive", [
+    ("pio_dispatch_stage_ms", "pio_dispatch_stage_cpu_ms",
+     _open_dispatch_stages),
+    ("pio_train_phase_ms", "pio_train_phase_cpu_ms", _open_train_phases),
+    ("pio_batcher_thread_ms", "pio_batcher_thread_cpu_ms",
+     _run_a_batcher_turn),
+])
+def test_a_twin_family_carries_its_wall_familys_label_sets(
+        wall_family, cpu_family, drive):
+    drive()
+    reg = get_registry()
+    wall, cpu = reg.get(wall_family), reg.get(cpu_family)
+    assert wall.labelnames == cpu.labelnames
+    series = {k: s.count for k, s in wall._series.items()}
+    assert series and series == {k: s.count
+                                 for k, s in cpu._series.items()}
+    for key, s in cpu._series.items():
+        assert s.sum <= wall._series[key].sum
+
+
 def test_annotation_carries_the_pio_name_and_closes_on_the_exception_path(
         annotations):
     with pytest.raises(KeyError):

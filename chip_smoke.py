@@ -581,6 +581,19 @@ def run_kernels(args) -> int:
         check(bool(jnp.isfinite(a).all() and jnp.isfinite(b).all()),
               f"gram {r}x{l}: non-finite output")
         worst = max(worst, rel_err(a, ra), rel_err(b, rb))
+        # the same rows as halves of 128-lane rows (the packed view's
+        # form), the other half noise: the kernel keeps each slot's half
+        # itself and builds the same sums in the same order
+        part = jnp.asarray(rng.integers(0, 2, (r, l)), jnp.int32)
+        noise = jnp.asarray(rng.standard_normal(f.shape) * 1e3, f.dtype)
+        wide = jnp.where(part[..., None] == 1,
+                         jnp.concatenate([noise, f], -1),
+                         jnp.concatenate([f, noise], -1))
+        pa, pb = pk.fused_gram_vector_pallas(wide, w, c, part, pack=2,
+                                             interpret=interpret)
+        check(interpret or bool((pa == a).all() and (pb == b).all()),
+              f"gram {r}x{l}: packed rows differ from the same rows plain")
+        worst = max(worst, rel_err(pa, ra), rel_err(pb, rb))
     check(worst <= 1e-4, f"gram kernel off by {worst:.2e} vs XLA twin")
     say("gram", shapes=gram_shapes, twin="fused_gram_vector_xla",
         worst_rel_err=f"{worst:.2e}", tol="1e-4")

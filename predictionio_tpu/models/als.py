@@ -48,6 +48,7 @@ from predictionio_tpu.ops.pallas_kernels import (
     fused_gram_dense,
     fused_gram_vector_pallas,
     gather_table_pack,
+    gram_takes_packed,
     lanes_solve_fits_vmem,
     pallas_supported,
     ridge_solve_lu_pallas,
@@ -200,19 +201,31 @@ def _resolve_gram_dtype(gram_dtype: str) -> str:
     return gram_dtype
 
 
-def _gather_packed(table: jax.Array, indices: jax.Array,
-                   pack: int) -> jax.Array:
-    """``table[indices]`` read through the view that lays ``pack`` rows
-    side by side in one 128-lane row: gather row ``index // pack`` of the
-    view, keep the part ``index % pack`` names.  The view is padded to
-    whole rows; an index is wrapped and clamped first, as ``table[...]``
-    does it, so the pad never reaches a result."""
+def _gather_wide(table: jax.Array, indices: jax.Array,
+                 pack: int) -> Tuple[jax.Array, jax.Array]:
+    """The rows ``table[indices]`` lie in, read through the view that lays
+    ``pack`` rows side by side in one 128-lane row: row ``index // pack``
+    of the view ``[R, L, pack·K]``, and the part ``index % pack`` of it
+    that is the row asked for ``[R, L]``.  The view is padded to whole
+    rows; an index is wrapped and clamped first, as ``table[...]`` does
+    it, so the pad is never the part that is named."""
     n, k = table.shape
     indices = jnp.clip(jnp.where(indices < 0, indices + n, indices), 0, n - 1)
     if n % pack:
         table = jnp.pad(table, ((0, -n % pack), (0, 0)))
-    wide = table.reshape(-1, pack * k)[indices // pack]   # [R, L, pack·K]
-    part = (indices % pack)[..., None]
+    return table.reshape(-1, pack * k)[indices // pack], indices % pack
+
+
+def _gather_packed(table: jax.Array, indices: jax.Array,
+                   pack: int) -> jax.Array:
+    """``table[indices]`` through the packed view (:func:`_gather_wide`),
+    each slot's part kept HERE: XLA makes that a pass of its own over the
+    gathered rows, so this is the form of the XLA twin and of every view
+    the sparse gram kernel does not take (``gram_takes_packed``); the
+    kernel path hands the wide rows over and the kernel keeps the part."""
+    wide, part = _gather_wide(table, indices, pack)
+    k = table.shape[1]
+    part = part[..., None]
     rows = wide[..., :k]
     for j in range(1, pack):
         rows = jnp.where(part == j, wide[..., j * k:(j + 1) * k], rows)
@@ -260,8 +273,19 @@ def _gram_pieces(
         # layout, so no relayout copy is emitted (the einsum path's dots
         # want L-minor and XLA copies the whole [R,L,K] block to get it:
         # 47.7 ms/iter at the ML-25M shape, round-3 phase profile).
-        f = _gather_rows(factors, indices, gram_dtype)   # [R, L, K]
-        a, b = fused_gram_vector_pallas(f, w, cvec,
+        # A table read through the packed view goes to the kernel as the
+        # view's 128-lane rows with each slot's part beside them: the
+        # kernel's fetch of a row is 128 lanes wide either way, and the
+        # pass XLA makes of keeping the part outside it is not made.
+        pack = gather_table_pack(
+            *factors.shape, jnp.dtype(gram_dtype).itemsize) or 1
+        if gram_takes_packed(factors.shape[1], pack):
+            f, part = _gather_wide(factors.astype(gram_dtype), indices,
+                                   pack)              # [R, L, pack·K]
+        else:
+            f, part, pack = _gather_rows(factors, indices, gram_dtype), \
+                None, 1                               # [R, L, K]
+        a, b = fused_gram_vector_pallas(f, w, cvec, part, pack=pack,
                                         interpret=not pallas_supported())
     else:
         # Gather in gram_dtype: the factor cast is [N, K] (cheap, one pass)
@@ -1220,18 +1244,31 @@ def train_als_prepared(inputs: ALSInputs, config: ALSConfig, *,
         "128-lane row brings the table into the chip's fast memory) or "
         "plain (the table as it is).",
         ("side", "form"))
+    select_ratings = get_registry().counter(
+        "pio_als_gather_select_ratings_total",
+        "Gathered ratings (pio_als_gather_ratings_total) by side and by "
+        "where each packed row's part is kept: kernel (inside the sparse "
+        "gram kernel, on the rows it fetches anyway), xla (a pass of its "
+        "own over the gathered rows, between the gather and the gram) or "
+        "none (the table is gathered as it is: nothing to keep).",
+        ("side", "where"))
     gdt = jnp.dtype(statics["gram_dtype"])
+    packs = tuple(gather_table_pack(n_src, k, gdt.itemsize) or 1
+                  for n_src in (itf.shape[0], uf.shape[0]))
     forms = tuple(
-        "packed" if (gather_table_pack(n_src, k, gdt.itemsize) or 1) > 1
-        else "plain" for n_src in (itf.shape[0], uf.shape[0]))
+        ("plain", "none") if pack == 1 else
+        ("packed", "kernel" if any(flags) and gram_takes_packed(k, pack)
+         else "xla")
+        for pack, flags in zip(packs, statics["pallas_flags"]))
 
     def sweeps(uf, itf, n):
-        for side, form, (dense, gathered) in zip(("user", "item"), forms,
-                                                 inputs.gram_ratings):
+        for side, (form, where), (dense, gathered) in zip(
+                ("user", "item"), forms, inputs.gram_ratings):
             gram_ratings.inc(float(dense) * n, side=side, path="dense")
             gram_ratings.inc(float(gathered) * n, side=side,
                              path="gathered")
             gather_ratings.inc(float(gathered) * n, side=side, form=form)
+            select_ratings.inc(float(gathered) * n, side=side, where=where)
         if warm_exe is not None:
             return warm_exe(uf, itf, ubk, ibk, reg, alpha, jnp.int32(n))
         return _train_loop(

@@ -33,7 +33,8 @@ __all__ = ["fused_gram_vector", "fused_gram_vector_pallas",
            "fused_gram_vector_xla", "pallas_supported",
            "fused_gram_dense", "fused_gram_dense_pallas",
            "fused_gram_dense_xla", "dense_weights", "dense_block_width",
-           "dense_row_density", "gather_table_pack", "DENSE_TILE_R",
+           "dense_row_density", "gather_table_pack", "gram_takes_packed",
+           "DENSE_TILE_R",
            "ridge_solve_lu_pallas", "lanes_solve_fits_vmem",
            "fused_topk", "fused_topk_pallas", "fused_topk_tiles",
            "pq_scan", "pq_scan_pallas", "pq_scan_xla"]
@@ -86,10 +87,17 @@ def fits_vmem(l: int, k: int) -> bool:
 #   900,000 (of which the pass that keeps each row's half is 1.9), 4.36
 #   at rank 32, 5.26-5.59 in float32; 12.79 at 1,000,000 rows, past the
 #   view's reach, where the table is gathered as it is.  In
-#   als-netflix-r64's loop a 128-lane row from VMEM costs 1.85 ns a slot
+#   als-netflix-r64's loop a 128-lane row from VMEM cost 1.85 ns a slot
 #   and the pass 0.93; with the merged rows' padding and the sparse gram
 #   kernel's 0.6 ns, which a dense row does not pay, a rating at the
-#   margin costs 3.5.
+#   margin cost 3.5.  Since PR 42 the pass is not made at ranks 64 and
+#   32 (``gram_takes_packed``: the sparse gram kernel takes the view's
+#   rows and keeps each slot's part in VMEM; every other view and the
+#   XLA twin keep the pass): the item side's 31.07M slots a sweep cost
+#   1.76 ns in the gathers, all 21 of which the compiler now serves from
+#   VMEM, and 0.37 in the kernel (chip runs, PRs 41 and 42), 2.4 a rating at
+#   the margin.  The rule keeps 3.5: planned at 2.4 the sweep read 456.9
+#   ms against 458.6 (the same runs) for more chunks to lower.
 # - From HBM (no view under the step: Amazon 2014's 21M users; rank
 #   128) a row cost the loop 11.97 ns (the parent's trace, PR 29).  A
 #   table under the step as it is (als-netflix-r64's 17,770 items, every
@@ -118,6 +126,16 @@ def gather_table_pack(n_rows: int, rank: int, itemsize: int) -> Optional[int]:
                 <= _GATHER_FAST_TABLE_BYTES:
             return pack
     return None
+
+
+def gram_takes_packed(rank: int, pack: int) -> bool:
+    """Whether the sparse gram kernel keeps each slot's part of a packed
+    row itself (``fused_gram_vector_pallas(..., part, pack=pack)``): where
+    the parts fill the 128 lanes and, transposed, each is whole packed
+    bf16 sublane tiles, at the ranks it was compiled and timed for: 64
+    and 32.  Every other view (rank 10's twelve parts of 10 lanes) keeps
+    the pass XLA makes of it."""
+    return pack * rank == _LANES and rank in (32, 64)
 
 
 def dense_row_density(rank: int, n_src: int) -> float:
@@ -149,8 +167,8 @@ TILE_R = 8     # rows per program — TPU sublane granularity for f32
 _L_CHUNK = 1024  # max slots staged per grid step (VMEM tile bound)
 
 
-def _gram_kernel(f_ref, w_ref, c_ref, a_ref, b_ref, *, l_real: int,
-                 l_chunk: int):
+def _gram_kernel(f_ref, w_ref, c_ref, *rest, l_real: int, l_chunk: int,
+                 pack: int = 1):
     """One (row-tile, L-chunk) grid step of the fused (A, b) build.
 
     ``f`` arrives in the gather's NATURAL layout and dtype — bf16,
@@ -160,7 +178,23 @@ def _gram_kernel(f_ref, w_ref, c_ref, a_ref, b_ref, *, l_real: int,
     chunk of a non-multiple L masks the over-read tail (Pallas pads OOB
     block loads with unspecified values — a NaN there would poison the
     accumulation through 0·NaN).
+
+    ``pack > 1``: ``f`` holds the packed view's rows, ``pack`` factor rows
+    side by side in 128 lanes, and ``part_ref`` names the one each slot
+    asked for.  The part is kept in VMEM, on the rows the kernel fetches
+    anyway, and in TRANSPOSED space: after one ``[LC, 128] → [128, LC]``
+    transpose (the MXU's contraction wants one operand transposed
+    either way) the parts are blocks of K sublanes and ``part``, ``w``
+    and ``c`` are lane vectors, so keeping a part is a select between
+    sublane blocks under a mask that broadcasts along sublanes, and no
+    per-slot value has to be spread from lanes to sublanes.  A part not
+    asked for reaches no arithmetic (it may hold anything, NaN too).
+    The products and the order of the sum are the plain body's.
     """
+    if pack > 1:
+        part_ref, a_ref, b_ref = rest
+    else:
+        a_ref, b_ref = rest
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -170,10 +204,11 @@ def _gram_kernel(f_ref, w_ref, c_ref, a_ref, b_ref, *, l_real: int,
 
     n_chunks = pl.num_programs(1)
     partial_tail = l_real % l_chunk != 0
+    k = a_ref.shape[-1]
 
     def accumulate(masked: bool):
         for r in range(TILE_R):
-            f = f_ref[r]                              # [LC, K] bf16
+            f = f_ref[r]                              # [LC, pack·K] bf16
             w = w_ref[r]                              # [LC] f32
             c = c_ref[r]
             if masked:
@@ -187,15 +222,26 @@ def _gram_kernel(f_ref, w_ref, c_ref, a_ref, b_ref, *, l_real: int,
                 w = jnp.where(valid1, w, 0.0)
                 c = jnp.where(valid1, c, 0.0)
                 f = jnp.where(valid2, f, jnp.zeros((), f.dtype))
-            # Reshape to 2-D in f32 BEFORE the dtype cast: Mosaic only
-            # supports minor-dim insertion on 32-bit vectors.
-            fw = f * w[:, None].astype(f.dtype)       # VPU
-            a_ref[r] += jax.lax.dot_general(          # MXU: [K,L]·[L,K]
-                fw, f, dimension_numbers=(((0,), (0,)), ((), ())),
+            if pack > 1:
+                ft = f.T                              # [pack·K, LC]
+                part = part_ref[r][None, :]           # [1, LC] int32
+                fs = ft[:k]                           # [K, LC]
+                for h in range(1, pack):
+                    fs = jnp.where(part == h, ft[h * k:(h + 1) * k], fs)
+                fw = fs * w[None, :].astype(f.dtype)  # VPU
+                on = (1,)        # the slots' axis; MXU: [K,L]·[K,L]ᵀ
+            else:
+                # Reshape to 2-D in f32 BEFORE the dtype cast: Mosaic only
+                # supports minor-dim insertion on 32-bit vectors.
+                fs = f
+                fw = f * w[:, None].astype(f.dtype)   # VPU
+                on = (0,)                             # MXU: [K,L]·[L,K]
+            a_ref[r] += jax.lax.dot_general(
+                fw, fs, dimension_numbers=((on, on), ((), ())),
                 preferred_element_type=jnp.float32)
-            b_ref[r] += jax.lax.dot_general(          # MXU: [1,L]·[L,K]
-                c[None, :].astype(f.dtype), f,
-                dimension_numbers=(((1,), (0,)), ((), ())),
+            b_ref[r] += jax.lax.dot_general(          # MXU: [1,L]·(the same)
+                c[None, :].astype(f.dtype), fs,
+                dimension_numbers=(((1,), on), ((), ())),
                 preferred_element_type=jnp.float32)[0]
 
     if partial_tail:
@@ -210,9 +256,10 @@ def _gram_kernel(f_ref, w_ref, c_ref, a_ref, b_ref, *, l_real: int,
         accumulate(masked=False)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("pack", "interpret"))
 def fused_gram_vector_pallas(f: jax.Array, w: jax.Array, c: jax.Array,
-                             *, interpret: bool = False
+                             part: Optional[jax.Array] = None,
+                             *, pack: int = 1, interpret: bool = False
                              ) -> Tuple[jax.Array, jax.Array]:
     """Fused (A, b) build — one VMEM pass over the gathered factors.
 
@@ -220,27 +267,36 @@ def fused_gram_vector_pallas(f: jax.Array, w: jax.Array, c: jax.Array,
     measured row-rate AND avoids a materialized f32 convert); rows are
     padded up to the TILE_R sublane granule (padding rows compute garbage
     that is sliced off), L is chunked so any bucket length fits VMEM.
+
+    With ``pack > 1`` (:func:`gram_takes_packed`), ``f [R, L, pack·K]``
+    is the packed view's rows as the gather returns them and ``part [R,
+    L]`` the part of each that is the row asked for (``index % pack``):
+    the kernel keeps it, so no pass over the gathered rows stands
+    between the gather and this call.
     """
-    r, l, k = f.shape
+    r, l, lanes = f.shape
+    k = lanes // pack
+    operands = [f, w.astype(jnp.float32), c.astype(jnp.float32)]
+    if pack > 1:
+        operands.append(part.astype(jnp.int32))
     r_pad = (-r) % TILE_R
     if r_pad:
-        f = jnp.pad(f, ((0, r_pad), (0, 0), (0, 0)))
-        w = jnp.pad(w, ((0, r_pad), (0, 0)))
-        c = jnp.pad(c, ((0, r_pad), (0, 0)))
+        operands = [jnp.pad(x, ((0, r_pad),) + ((0, 0),) * (x.ndim - 1))
+                    for x in operands]
     rp = r + r_pad
     # Chunk length scales inversely with rank to hold the staged tile at
-    # ~[TILE_R, 1024, 64]-equivalent bytes.
-    lc = min(l, max(128, _L_CHUNK * 64 // max(k, 1)))
+    # ~[TILE_R, 1024, 64]-equivalent bytes; a packed row is 128 real
+    # lanes at any rank, the bytes a rank-64 row takes padded.
+    lc = min(l, max(128, _L_CHUNK * 64 // max(k, 1)) if pack == 1
+             else _L_CHUNK)
     n_chunks = -(-l // lc)
-    kernel = functools.partial(_gram_kernel, l_real=l, l_chunk=lc)
+    kernel = functools.partial(_gram_kernel, l_real=l, l_chunk=lc, pack=pack)
+    per_slot = pl.BlockSpec((TILE_R, lc), lambda i, j: (i, j))
     a, b = pl.pallas_call(
         kernel,
         grid=(rp // TILE_R, n_chunks),
-        in_specs=[
-            pl.BlockSpec((TILE_R, lc, k), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((TILE_R, lc), lambda i, j: (i, j)),
-            pl.BlockSpec((TILE_R, lc), lambda i, j: (i, j)),
-        ],
+        in_specs=[pl.BlockSpec((TILE_R, lc, lanes), lambda i, j: (i, j, 0))]
+        + [per_slot] * (len(operands) - 1),
         out_specs=[
             pl.BlockSpec((TILE_R, k, k), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((TILE_R, k), lambda i, j: (i, 0)),
@@ -250,7 +306,7 @@ def fused_gram_vector_pallas(f: jax.Array, w: jax.Array, c: jax.Array,
             jax.ShapeDtypeStruct((rp, k), jnp.float32),
         ],
         interpret=interpret,
-    )(f, w.astype(jnp.float32), c.astype(jnp.float32))
+    )(*operands)
     return a[:r], b[:r]
 
 

@@ -682,3 +682,80 @@ def test_gather_form_counter_follows_the_source_table(monkeypatch):
     assert entry["workloads"] == ["als-netflix-r64.retrain"]
     assert (entry["layer"], entry["moves"]) == ("fused ALS loop",
                                                 "rating_iters_per_s")
+
+
+def test_train_through_the_kernel_that_keeps_the_part_equals_the_xla_pass(
+        monkeypatch):
+    """The packed view forced on a small table at rank 64: the sparse gram
+    kernel (interpreter) takes the 128-lane rows and keeps each slot's
+    half itself; the same train with the half kept by XLA before the
+    kernel (``_gather_rows``) gives the same factors, and
+    ``pio_als_gather_select_ratings_total`` says which of the two ran
+    (``als_select_in_kernel_pct`` reads the item side's share)."""
+    from benchmark import manifest, prom
+    from benchmark.readers import prom_ratio
+    from predictionio_tpu.models import als
+    from predictionio_tpu.models.als import (
+        prepare_als_inputs, train_als_prepared,
+    )
+    from predictionio_tpu.ops import pallas_kernels
+
+    users, items, ratings = _dense_toy(seed=9)
+    cfg = ALSConfig(rank=64, iterations=3, reg=1.0, seed=11,
+                    device_prep=False, split_above=16, use_pallas=True,
+                    gram_dtype="bfloat16", solver="cholesky")
+    inputs = prepare_als_inputs(users, items, ratings, 60, 40, cfg)
+    # a fast memory that holds the 40 items' bf16 table (128 lanes x 2 B
+    # a row) and the 60 users' only two rows to a 128-lane row
+    monkeypatch.setattr(pallas_kernels, "_GATHER_FAST_TABLE_BYTES",
+                        50 * 128 * 2)
+    assert pallas_kernels.gather_table_pack(60, 64, 2) == 2
+    assert pallas_kernels.gather_table_pack(40, 64, 2) == 1
+    spec = manifest.layer_metric_spec("als_select_in_kernel_pct")
+    wide_calls, models, shares, grown = [], {}, {}, {}
+    gather_wide = als._gather_wide
+    monkeypatch.setattr(
+        als, "_gather_wide",
+        lambda table, idx, pack: wide_calls.append(table.shape)
+        or gather_wide(table, idx, pack))
+    for where in ("kernel", "xla"):
+        if where == "xla":
+            monkeypatch.setattr(als, "gram_takes_packed",
+                                lambda rank, pack: False)
+        als._train_loop.clear_cache()
+        del wide_calls[:]
+        before = prom.snapshot()
+        try:
+            models[where] = train_als_prepared(inputs, cfg)
+        finally:
+            als._train_loop.clear_cache()
+        ctx = {"before": before, "after": prom.snapshot()}
+        assert wide_calls and set(wide_calls) == {(60, 64)}
+        shares[where] = prom_ratio.read(ctx, **spec["args"])
+        grown[where] = {
+            (side, w): prom.delta(ctx["before"], ctx["after"],
+                                  "pio_als_gather_select_ratings_total",
+                                  {"side": side, "where": w})
+            for side in ("user", "item") for w in ("kernel", "xla", "none")}
+    (_, gu), (_, gi) = inputs.gram_ratings
+    for where, other in (("kernel", "xla"), ("xla", "kernel")):
+        assert grown[where]["item", where] == gi * cfg.iterations
+        assert grown[where]["user", "none"] == gu * cfg.iterations
+        assert grown[where]["item", other] == grown[where]["item", "none"] \
+            == grown[where]["user", "kernel"] == grown[where]["user", "xla"] \
+            == 0
+    assert shares == {"kernel": pytest.approx(100.0), "xla": 0.0}
+    assert prom_ratio.read({"before": {}, "after": {}},
+                           **spec["args"]) is None
+    # the same rows reach the same products (whole-number ratings: exact
+    # in bf16); the two bodies' float32 sums may differ in their last
+    # bits here, which a ridge this strong does not amplify
+    for name in ("user_factors", "item_factors"):
+        np.testing.assert_allclose(
+            np.asarray(getattr(models["kernel"], name)),
+            np.asarray(getattr(models["xla"], name)), rtol=1e-4, atol=1e-5)
+    (entry,) = [m for m in manifest.load()["per_layer"]
+                if m["name"] == "als_select_in_kernel_pct"]
+    assert entry["workloads"] == ["als-netflix-r64.retrain"]
+    assert (entry["layer"], entry["moves"], entry["source"]) == (
+        "ALS kernels", "rating_iters_per_s", "program_counter")

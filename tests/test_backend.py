@@ -216,6 +216,16 @@ F32, BF16, U8 = jnp.float32, jnp.bfloat16, jnp.uint8
      [((256, 40, 64), BF16), ((256, 40), F32), ((256, 40), F32)]),
     ("gram-ragged", partial(pk.fused_gram_vector_pallas, interpret=False),
      [((64, 1160, 64), BF16), ((64, 1160), F32), ((64, 1160), F32)]),
+    # the packed view's 128-lane rows and each slot's part (rank 64: two
+    # halves; rank 32: four quarters, a ragged last chunk)
+    ("gram-packed", lambda f, w, c, part: pk.fused_gram_vector_pallas(
+        f, w, c, part, pack=2, interpret=False),
+     [((2048, 512, 128), BF16), ((2048, 512), F32), ((2048, 512), F32),
+      ((2048, 512), jnp.int32)]),
+    ("gram-packed-r32", lambda f, w, c, part: pk.fused_gram_vector_pallas(
+        f, w, c, part, pack=4, interpret=False),
+     [((64, 2100, 128), BF16), ((64, 2100), F32), ((64, 2100), F32),
+      ((64, 2100), jnp.int32)]),
     ("lu", partial(pk.ridge_solve_lu_pallas, interpret=False),
      [((6040, 64, 64), F32), ((6040, 64), F32), ((6040,), F32)]),
     # the templates' default rank (a last block of 2 rows and 2 columns),

@@ -289,6 +289,74 @@ def test_fused_topk_dispatcher_cpu_falls_back_to_chunked():
 
 
 # ---------------------------------------------------------------------------
+# The packed view's rows straight into the sparse gram kernel: 128-lane
+# rows and each slot's part, the part kept inside the kernel, against the
+# same rows with the part kept by XLA before it (``_gather_rows``).
+# ---------------------------------------------------------------------------
+
+_EDGE = "edge"      # a slot list that visits the wrap, the clamp, the pad
+
+
+@pytest.mark.parametrize("rank,n_rows,r,l,neighbour", [
+    (64, 600, 8, 2048, None),      # two whole L-chunks of 1,024
+    (64, 600, 8, 1100, None),      # a ragged last chunk (masked tail)
+    (64, 601, 5, 24, None),        # R no multiple of TILE_R, an odd table
+    (64, 601, 3, 16, _EDGE),       # indices 0, n-1, negative, past the end
+    (64, 601, 8, 1100, 1e30),      # the other half huge: it reaches no sum
+    (64, 601, 4, 40, float("nan")),    # ... nor does a NaN there
+    (32, 603, 5, 1100, _EDGE),     # four parts a row, three pad rows
+    (32, 603, 8, 136, float("nan")),
+], ids=["whole-chunks", "ragged-chunk", "ragged-rows-odd-table",
+        "wrap-clamp-pad", "neighbour-1e30", "neighbour-nan",
+        "rank32-wrap-clamp-pad", "rank32-neighbour-nan"])
+def test_gram_kernel_keeps_each_packed_rows_part(rank, n_rows, r, l,
+                                                 neighbour):
+    from predictionio_tpu.models import als
+    from predictionio_tpu.ops.pallas_kernels import gram_takes_packed
+
+    pack, dtype = 128 // rank, jnp.dtype(jnp.bfloat16)
+    assert gram_takes_packed(rank, pack)
+    rng = np.random.default_rng(n_rows + l)
+    table = rng.standard_normal((n_rows, rank)).astype(np.float32)
+    idx = rng.integers(0, n_rows, (r, l)).astype(np.int32)
+    if neighbour is _EDGE:
+        idx[0, :8] = [0, n_rows - 1, -1, -n_rows, n_rows, n_rows + 7,
+                      -n_rows - 5, 1]
+    elif neighbour is not None:
+        # every slot names the first row of a 128-lane row (the table's
+        # last whole one among them); every other row, the other parts
+        # of each fetched row, is poisoned
+        idx = idx // pack * pack
+        idx[0, 0] = (n_rows - 1) // pack * pack
+        poisoned = np.arange(n_rows) % pack != 0
+        table[poisoned] = neighbour
+    table, idx = jnp.asarray(table), jnp.asarray(idx)
+    # weights whose products with a bf16 value are exact in bf16, so that
+    # a path in which the CPU's XLA skips a rounding (it may, where it
+    # fuses a convert away) computes the same numbers
+    w = jnp.asarray(rng.integers(0, 3, (r, l)), jnp.float32)
+    c = jnp.asarray(rng.integers(-4, 5, (r, l)) / 2, jnp.float32)
+
+    rows = als._gather_rows(table, idx, dtype)
+    assert np.array_equal(np.asarray(rows, np.float32),
+                          np.asarray(table.astype(dtype)[idx], np.float32))
+    wide, part = als._gather_wide(table.astype(dtype), idx, pack)
+    assert wide.shape == (r, l, 128) and part.shape == (r, l)
+    a, b = fused_gram_vector_pallas(wide, w, c, part, pack=pack,
+                                    interpret=True)
+    assert a.shape == (r, rank, rank) and b.shape == (r, rank)
+    # The same products in the plain kernel's order, the part kept before
+    # any arithmetic: equal to float32 rounding here, bit for bit on the
+    # chip at rank 64 (chip_smoke.py checks that).
+    a0, b0 = fused_gram_vector_pallas(rows, w, c, interpret=True)
+    for got, want in ((a, a0), (b, b0)) + tuple(
+            zip((a, b), fused_gram_vector_xla(rows, w, c))):
+        assert np.isfinite(np.asarray(got)).all()
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
 # Dense normal equations: the masked product over the whole factor table
 # against its XLA twin and against the gathered path on the same ratings.
 # ---------------------------------------------------------------------------

@@ -222,3 +222,73 @@ def test_gather_operand_lies_in_vmem_where_the_rule_says(
         assert table.startswith(
             f"bf16[{-(-n_rows // 2)},128]" if pack == 2
             else f"bf16[{n_rows},64]")
+
+
+# The item side's step of als-netflix-r64 (PERF.md §6, PR 41/42): the packed
+# view's 128-lane rows go from the gather straight into the sparse gram
+# kernel, which keeps each slot's half itself.  XLA's pass between the
+# two (``slice_select_fusion``: a read and a write of the gathered rows in
+# HBM) is not in the program; the gather's operand keeps its place in
+# VMEM; a view the kernel does not take (rank 10: twelve parts that are
+# no whole sublane tiles once transposed) compiles through the pass, as
+# before.
+
+def _side_step_text(one_chip, n_src, rank):
+    from predictionio_tpu.models import als
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    r, l = 2048, 512            # a chunk of the cell's item side
+    als._side_step.clear_cache()
+    try:
+        return als._side_step.lower(
+            shape((r, l), jnp.int32), shape((r, l), jnp.float32),
+            shape((r, l), jnp.bool_), shape((r,), jnp.int32),
+            shape((17_770, rank), jnp.float32),
+            shape((n_src, rank), jnp.float32),
+            shape((), jnp.float32), shape((), jnp.float32), implicit=False,
+            use_pallas=True, gram_dtype="bfloat16",
+            solver="lu").compile().as_text()
+    finally:
+        als._side_step.clear_cache()
+
+
+def _copies(text):
+    import re
+
+    return len(re.findall(r" = \S+ copy\(", text))
+
+
+@pytest.mark.parametrize("n_src,rank,in_kernel", [
+    (480_189, 64, True), (1_000_000, 10, False)],
+    ids=["netflix-items", "rank-10-view"])
+def test_packed_rows_reach_the_gram_kernel_with_no_pass_between(
+        one_chip, monkeypatch, n_src, rank, in_kernel):
+    from predictionio_tpu.models import als
+    from predictionio_tpu.ops import pallas_kernels
+
+    pack = pallas_kernels.gather_table_pack(n_src, rank, 2)
+    assert pack == 128 // rank > 1
+    assert pallas_kernels.gram_takes_packed(rank, pack) == in_kernel
+    # the code asks the backend, which is the CPU here: steer it to the
+    # compiled kernels, as on the chip
+    monkeypatch.setattr(als, "pallas_supported", lambda: True)
+    text = _side_step_text(one_chip, n_src, rank)
+    # the names als_gram_roofline and als_solve_roofline read
+    assert "fused_gram_vector_pallas" in text
+    assert "ridge_solve_lu_pallas" in text
+    (table,) = _gather_operands(text)
+    assert table.startswith(f"bf16[{-(-n_src // pack)},{pack * rank}]")
+    assert "S(1)" in table, table
+    halved = f"bf16[2048,512,{rank}]"       # the rows after XLA's pass
+    if not in_kernel:
+        assert "select_fusion" in text and halved in text
+        return
+    assert "slice_select_fusion" not in text and halved not in text
+    assert "bf16[2048,512,128]" in text
+    # against the same step with the pass in it (the parent's program)
+    monkeypatch.setattr(als, "gram_takes_packed", lambda rank, pack: False)
+    parent = _side_step_text(one_chip, n_src, rank)
+    assert "slice_select_fusion" in parent and halved in parent
+    assert _copies(text) <= _copies(parent)

@@ -5,7 +5,8 @@
 with the :class:`~predictionio_tpu.serving.state_cache.StateCache`, and
 runs the backbone's device programs; a turn longer than a bucket is taken
 in chunks.  What is the backbone's own comes from its STEP object
-(``models.lfm2.LFM2Step``, ``models.sala.SALAStep``):
+(``models.lfm2.LFM2Step``, ``models.sala.SALAStep``,
+``models.sambay.SambaYStep``, ``models.granite_h.GraniteHStep``):
 
     step.cfg, step.token_buckets, step.read_buckets
     step.program(cache, t, r, k)   a jitted ``fn(params, arrays, vec) ->

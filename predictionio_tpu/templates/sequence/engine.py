@@ -8,7 +8,10 @@ grouped-query attention, routed experts; the default) or ``sala``
 beside lightning linear attention) or ``sambay``
 (:mod:`predictionio_tpu.models.sambay`: Mamba and sliding-window layers
 under one full-attention cache that the later layers read, with gated
-memory units).
+memory units) or ``granite_h``
+(:mod:`predictionio_tpu.models.granite_h`: Mamba-2 state-space layers
+beside grouped-query attention with no positional encoding, under
+Granite's four multipliers).
 Serving keeps each user's state between queries in the
 :class:`~predictionio_tpu.serving.state_cache.StateCache`, so a query
 pays for the events it brings, not for the history behind them.
@@ -64,6 +67,8 @@ BACKBONES = {
              "predictionio_tpu.models.sala_reference"),
     "sambay": ("predictionio_tpu.models.sambay",
                "predictionio_tpu.models.sambay_reference"),
+    "granite_h": ("predictionio_tpu.models.granite_h",
+                  "predictionio_tpu.models.granite_h_reference"),
 }
 
 
@@ -211,6 +216,14 @@ class SequenceAlgorithmParams(Params):
     numHiddenLayers: int = 8  # noqa: N815
     slidingWindow: int = 16  # noqa: N815
     ssmConfig: Optional[Dict[str, int]] = None  # noqa: N815
+    # The ``granite_h`` backbone: ``layerTypes`` of "mamba" and
+    # "attention", heads of ``headDim``, the Mamba-2 sizes (mamba_n_heads,
+    # mamba_d_head, mamba_d_state, mamba_d_conv, mamba_expand) under
+    # ``ssmConfig``, and the published model's four multipliers.
+    embeddingMultiplier: float = 12.0  # noqa: N815
+    residualMultiplier: float = 0.22  # noqa: N815
+    attentionMultiplier: float = 0.015625  # noqa: N815
+    logitsScaling: float = 8.0  # noqa: N815
     # Training (next-item cross-entropy over windows of the histories).
     steps: int = 200
     batchSize: int = 16  # noqa: N815

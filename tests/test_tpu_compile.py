@@ -1,6 +1,6 @@
 """Mosaic's verdict on the kernels of the main paths at real widths (the
 ALS dense gram and lanes solve; the sequence engine's selected attention
-and lightning update), with no
+and lightning update, its Mamba-2 update), with no
 chip: libtpu compiles for a described v5e in the sandbox (PERF.md, PR 21).
 It proves compilation, not results.  The topology is described inside a
 fixture, by the one worker that runs this file; keep every such test
@@ -171,6 +171,56 @@ def test_paged_attention_kernel_compiles_for_v5e(one_chip, name, tiles, rows,
         shape((pages * 128, 2560), jnp.bfloat16)).compile()
     # the names window_attn_ms / shared_attn_ms and their rooflines read
     assert name in compiled.as_text()
+
+
+# The Mamba-2 / no-position attention backbone's kernel at the published
+# widths (64 heads of 64 over a state of 128, 66 slots of 2 MiB a layer),
+# in the tile shapes of its programs: 16 events a tile for turns (32
+# users and the 128-token bucket's spare tiles), 64 for a 1,024-event
+# prefill chunk; and its attention layers' call of the paged-attention
+# kernel (4 kv pairs, 8 query rows an event, a table of 256 pages).
+
+@pytest.mark.parametrize("tiles,tq", [(41, 16), (25, 64)])
+def test_ssd_update_kernel_compiles_for_v5e(one_chip, tiles, tq):
+    from predictionio_tpu.ops import granite_h_kernels
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    rows = shape((tiles, tq, 64 * 64))
+    compiled = jax.jit(
+        lambda xd, cs, dl, b, c, state, *per_tile:
+        granite_h_kernels._ssd_pallas(
+            xd, cs, dl, b, c, state, *per_tile,
+            hb=granite_h_kernels.HEAD_BLOCK, interpret=False),
+        donate_argnums=(5,)).lower(
+        rows, shape((tiles, tq, 64)), shape((tiles, 64)),
+        shape((tiles, tq, 128), jnp.bfloat16),
+        shape((tiles, tq, 128), jnp.bfloat16), shape((66, 64, 64, 128)),
+        *[shape((tiles,), jnp.int32)] * 4).compile()
+    # the name the benchmark's ssd_update_ms and ssd_update_roofline read
+    assert "granite_h_ssd_update" in compiled.as_text()
+
+
+@pytest.mark.parametrize("tiles,tq", [(41, 16), (25, 64)])
+def test_gqa_attention_kernel_compiles_for_v5e(one_chip, tiles, tq):
+    from predictionio_tpu.ops import sambay_kernels
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    rows = 8 * tq
+    compiled = jax.jit(
+        lambda q, qpos, cnt, lists, pool:
+        sambay_kernels._attention_pallas(
+            q, qpos, cnt, lists, pool, page=128, window=0, pb=4,
+            name="granite_h_gqa_attention", interpret=False)).lower(
+        shape((tiles, 4, rows, 128), jnp.bfloat16),
+        shape((tiles, rows), jnp.int32), shape((tiles,), jnp.int32),
+        shape((tiles, 256), jnp.int32),
+        shape((2901 * 128, 1024), jnp.bfloat16)).compile()
+    # the name gqa_attn_ms and gqa_attn_roofline read
+    assert "granite_h_gqa_attention" in compiled.as_text()
 
 
 # The ALS gather's step (PERF.md §6, PR 36): XLA:TPU keeps a gather's

@@ -29,14 +29,19 @@ user's cached state (:func:`extend_step`):
 
 * FIXED, a slot a user: per Mamba-2 layer the float32 ``[H, P, N]`` state
   (``N`` along the lanes) and the convolution's last ``mamba_d_conv - 1``
-  input rows; the last event's hidden row (what a query with no new event
-  answers from).  At the published sizes a slot is 77.4 MB, so the
-  programs touch at most 32 users (``READ_BUCKETS``) and the cache's
-  write pool is that large;
+  input rows as ONE row (oldest first, ``(mamba_d_conv - 1) x
+  conv_width`` lanes, read and written whole); the last event's hidden
+  row (what a query with no new event answers from).  At the published
+  sizes a slot is 77.4 MB, so the programs touch at most 32 users
+  (``READ_BUCKETS``) and the cache's write pool is that large;
 * PAGED: the attention layers' keys and values, an event a row (its keys
   by head, then its values), and a page table a user on the device.
 
 The new events are cut into TILES of up to ``tq`` events of one user.
+Every array of a Mamba-2 layer between its two products keeps its
+channels along the lanes, rows of them, in the order its reader takes
+it: nothing is viewed ``[..., H, P]`` here and nothing is turned
+tokens-minor and back.
 :func:`predictionio_tpu.ops.granite_h_kernels.ssd_update` runs the
 recurrence a tile at a time in its matmul form;
 :func:`predictionio_tpu.ops.sambay_kernels.paged_attention` reads a tile's
@@ -62,6 +67,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from predictionio_tpu.models.lfm2 import _mm, rms
 from predictionio_tpu.models.seq_head import top_k_head
@@ -252,7 +258,9 @@ def state_layout(cfg: GraniteHConfig, page_size: int,
                  table_len: int = TABLE_LEN) -> Dict[str, Any]:
     """What the :class:`~predictionio_tpu.serving.state_cache.StateCache`
     holds for this model: per Mamba-2 layer a float32 ``[H, P, N]`` state
-    and the convolution's ``mamba_d_conv - 1`` last rows a slot, and the
+    (``s{i}``) and the convolution's ``mamba_d_conv - 1`` last input rows
+    a slot as ONE row of ``(mamba_d_conv - 1) * conv_width`` lanes, oldest
+    first (``c{i}``: a slot's row is read and written whole), and the
     last event's hidden row; per attention layer a page of ``page_size``
     rows, an event a row (its keys by head, then its values); a page
     table a user.  Paged arrays are 2-D, the rows of page ``p`` at ``p *
@@ -261,7 +269,7 @@ def state_layout(cfg: GraniteHConfig, page_size: int,
     for i in range(cfg.count(MAMBA)):
         fixed[f"s{i}"] = ((cfg.mamba_n_heads, cfg.mamba_d_head,
                            cfg.mamba_d_state), jnp.float32)
-        fixed[f"c{i}"] = ((cfg.mamba_d_conv - 1, cfg.conv_width),
+        fixed[f"c{i}"] = (((cfg.mamba_d_conv - 1) * cfg.conv_width,),
                           jnp.float32)
     fixed["h_last"] = ((cfg.hidden_size,), jnp.float32)
     n_attn = cfg.count(ATTENTION)
@@ -293,30 +301,35 @@ def _conv(cfg: GraniteHConfig, p: Dict[str, jax.Array], x: jax.Array,
           ) -> Tuple[jax.Array, jax.Array]:
     """The causal depthwise convolution of ``x`` [T, C] along each
     segment, continued from the segment's stored ``tail`` (its last ``w -
-    1`` input rows, oldest first) -> (silu(conv + bias), the tails with
-    the segments' new last rows written)."""
+    1`` input rows, oldest first, one row of ``(w - 1) C`` lanes a slot)
+    -> (silu(conv + bias), the tails with the segments' new last rows
+    written)."""
     t, cw, w = x.shape[0], x.shape[1], cfg.mamba_d_conv
     # The row ``j`` events back: this dispatch's where the segment holds
-    # it, else the user's stored tail.
+    # it, else the user's stored tail.  Below ``x`` the stored rows, the
+    # segments' oldest first: row ``m`` of segment ``s`` at ``t + m G + s``.
+    # (Gathered a tap: placing the stored rows into shifted copies of
+    # ``x`` is a scatter, and a row scattered costs the chip four gathered.)
     seg = jnp.maximum(batch["tok_seg"], 0)
-    old = tail[batch["seg_read"]]                       # [G, w - 1, C]
-    both = jnp.concatenate([x, old.reshape(-1, cw)], axis=0)
+    old = tail[batch["seg_read"]]                       # [G, (w - 1) C]
+    g = old.shape[0]
+    both = jnp.concatenate(
+        [x] + [old[:, m * cw:(m + 1) * cw] for m in range(w - 1)], axis=0)
     at = jnp.arange(t)
     conv = p["conv_w"][w - 1] * x
     for j in range(1, w):
         back = batch["tok_idx"] - j
-        row = jnp.where(back >= 0, at - j,
-                        t + seg * (w - 1) + (w - 1 + back))
+        row = jnp.where(back >= 0, at - j, t + (w - 1 + back) * g + seg)
         conv = conv + p["conv_w"][w - 1 - j] * both[jnp.maximum(row, 0)]
     # The new tails: row ``m`` (oldest first) lies ``w - 2 - m`` back from
     # the segment's last event.
-    m = jnp.arange(w - 1)[None, :]
-    back = batch["seg_len"][:, None] - 1 - (w - 2 - m)
-    row = jnp.where(back >= 0, batch["seg_last"][:, None] - (w - 2 - m),
-                    t + jnp.arange(old.shape[0])[:, None] * (w - 1)
-                    + (w - 1 + back))
-    tail = tail.at[batch["seg_write"]].set(
-        both[jnp.clip(row, 0, both.shape[0] - 1)])
+    rows = []
+    for m in range(w - 1):
+        back = batch["seg_len"] - 1 - (w - 2 - m)
+        row = jnp.where(back >= 0, batch["seg_last"] - (w - 2 - m),
+                        t + (w - 1 + back) * g + jnp.arange(g))
+        rows.append(both[jnp.clip(row, 0, both.shape[0] - 1)])
+    tail = tail.at[batch["seg_write"]].set(jnp.concatenate(rows, axis=1))
     return jax.nn.silu(conv + p["conv_b"]), tail
 
 
@@ -324,22 +337,27 @@ def mamba_op(cfg: GraniteHConfig, p: Dict[str, jax.Array], u: jax.Array,
              batch: Dict[str, jax.Array], state: jax.Array, tail: jax.Array
              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """(output [T, d], the state array, the convolution tails)."""
-    t, e, n = u.shape[0], cfg.d_inner, cfg.mamba_d_state
-    heads, hp = cfg.mamba_n_heads, cfg.mamba_d_head
-    proj = _mm(u, p["w_in"])
+    e, n = cfg.d_inner, cfg.mamba_d_state
+    # Held row-major: ``E + conv_width + H`` columns are no whole number
+    # of lane tiles at the published widths (8,512 = 66.5 x 128), and the
+    # compiler then prefers the result tokens-minor, which every reader
+    # below (rows of channels, all of them) would have to turn back.
+    proj = with_layout_constraint(_mm(u, p["w_in"]),
+                                  Layout(major_to_minor=(0, 1)))
     z = proj[:, :e]
     xbc, tail = _conv(cfg, p, proj[:, e:e + cfg.conv_width], batch, tail)
     dt = jax.nn.softplus(proj[:, e + cfg.conv_width:] + p["dt_b"])
     tiles, real = batch["tile_tok"], batch["tile_real"]
-    x = xbc[:, :e].reshape(t, heads, hp)
+    x = xbc[:, :e]
     with jax.named_scope("ssd_update"):
         y, state = granite_h_kernels.ssd_update(
             x[tiles], jnp.where(real[..., None], dt[tiles], 0.0),
             xbc[:, e:e + n][tiles], xbc[:, e + n:][tiles],
             -jnp.exp(p["a_log"]), state, batch["tile_first"],
             batch["tile_cnt"], batch["tile_read"], batch["tile_write"])
-    y = (y[batch["tok_tile"], batch["tok_in_tile"]]
-         + p["d_skip"][:, None] * x).reshape(t, e)
+    # The skip term on the event's own row, ``D`` a number a lane.
+    y = y[batch["tok_tile"], batch["tok_in_tile"]] \
+        + jnp.repeat(p["d_skip"], cfg.mamba_d_head) * x
     y = rms(y * jax.nn.silu(z), p["gate_norm"], cfg.rms_norm_eps)
     return _mm(y, p["w_out"]), state, tail
 
@@ -387,15 +405,14 @@ def extend_step(params: Dict[str, Any], state: Dict[str, Any],
 
     ``state``: the arrays of :func:`state_layout` and ``table`` [users,
     table_len].  ``batch`` (int32): per token ``tokens``, ``tok_seg`` (-1
-    = padding), ``tok_pos``, ``tok_idx`` (index in its segment),
-    ``tok_row`` (pool row of its keys and values), ``tok_tile``,
-    ``tok_in_tile``; per segment ``seg_read``, ``seg_write`` (slots),
-    ``seg_last`` (token), ``seg_len``; per tile ``tile_user`` (page-table
-    row), ``tile_start`` (token), ``tile_cnt``, ``tile_first``,
-    ``tile_read``, ``tile_write`` (slots); ``new_pages`` [n, 3] (table
-    row, index, pool page) of the pages this dispatch's plan handed out;
-    per read ``read_tok`` (-1 = the user's stored last hidden row),
-    ``read_slot``."""
+    = padding), ``tok_pos``, ``tok_row`` (pool row of its keys and
+    values), ``tok_tile``, ``tok_in_tile``; per segment ``seg_read``,
+    ``seg_write`` (slots), ``seg_last`` (token), ``seg_len``; per tile
+    ``tile_user`` (page-table row), ``tile_start`` (token), ``tile_cnt``,
+    ``tile_first``, ``tile_read``, ``tile_write`` (slots); ``new_pages``
+    [n, 3] (table row, index, pool page) of the pages this dispatch's
+    plan handed out; per read ``read_tok`` (-1 = the user's stored last
+    hidden row), ``read_slot``."""
     new = batch["new_pages"]
     table = state["table"].at[new[:, 0], new[:, 1]].set(new[:, 2])
     t = batch["tok_seg"].shape[0]

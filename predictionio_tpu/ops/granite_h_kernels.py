@@ -23,8 +23,13 @@ tests hold the kernel to).  The backbone's attention layers run
     ONE [tq, tq] product a tile, the carried state's read-out ONE [tq, N]
     x [N, heads x P] product and the state's update ONE [heads x P, tq] x
     [tq, N] product a head block; only the masked product over a tile's
-    own events is a head's ([tq, tq] x [tq, P]).  The numbers a (tile,
-    event, head) (``cs``) are made by XLA beside the call.
+    own events is a head's ([tq, tq] x [tq, P]).  The rows come and go
+    as the caller holds them, ``heads x P`` channels along the lanes:
+    nothing outside the kernel is viewed ``[..., heads, P]``.  The
+    numbers a (tile, event, head) (``dt`` and ``cs``) are made by XLA
+    beside the call, and a head's are spread over its ``P`` lanes HERE,
+    for all of a block's heads at once (:func:`_spread`: a product with
+    a 0/1 matrix in full float32, exact, on the MXU).
     Across a user's tiles the state is carried in VMEM: read from the
     user's slot at the user's first tile, written to the slot the plan
     names after each (the state array aliased to the result).  A padding
@@ -77,19 +82,39 @@ def _read_out(bb, cc, s2):
                                      preferred_element_type=f32))
 
 
-def _head(h, hp, xd, cs, cs_t, g, tri, before):
-    """Head ``h`` of a tile: (y [tq, P] without the skip term, ``w dt x``
-    [tq, P]: its events' increments as the tile's last event sees them).
-    ``xd`` [tq, heads * P] = dt x; ``cs`` [tq, heads] and ``cs_t`` [heads,
-    tq] the running sums of the log-decays; ``before`` [tq, heads * P]."""
+def _spread(v, hp):
+    """A number a (row, head) [m, heads] over the head's ``hp`` lanes ->
+    [m, heads * hp]: a product with a 0/1 matrix in full float32 (exact:
+    one term a sum), on the MXU.  A lane broadcast a head and number
+    costs the kernel more than it has to spare beside its DMA."""
+    heads = v.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (heads, heads * hp), 1) \
+        - hp * jax.lax.broadcasted_iota(jnp.int32, (heads, heads * hp), 0)
+    ones = ((lane >= 0) & (lane < hp)).astype(jnp.float32)
+    return jnp.dot(v, ones, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
+def _block(hp, x, dt, cs, before):
+    """For all of a block's heads at once: (``dt x`` [tq, heads * P],
+    the carried state's part of y, ``w dt x``: the events' increments as
+    the tile's last event sees them)."""
+    tq = x.shape[0]
+    sp = _spread(jnp.concatenate(
+        [dt, jnp.exp(cs), jnp.exp(cs[-1:, :] - cs)], axis=0), hp)
+    xd = sp[:tq] * x
+    return xd, sp[tq:2 * tq] * before, sp[2 * tq:] * xd
+
+
+def _head(h, hp, xd, cs, cs_t, g, tri):
+    """Head ``h`` of a tile: the masked product over the tile's own
+    events [tq, P]."""
     lanes = slice(h * hp, (h + 1) * hp)
     col, row = cs[:, h:h + 1], cs_t[h:h + 1, :]
     decay = jnp.where(tri, jnp.exp(jnp.minimum(col - row, 0.0)), 0.0)
-    inside = jnp.dot((g * decay).astype(jnp.bfloat16),
-                     xd[:, lanes].astype(jnp.bfloat16),
-                     preferred_element_type=jnp.float32)
-    return (inside + jnp.exp(col) * before[:, lanes],
-            jnp.exp(col[-1:, :] - col) * xd[:, lanes])
+    return jnp.dot((g * decay).astype(jnp.bfloat16),
+                   xd[:, lanes].astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32)
 
 
 def _update(xw, bb):
@@ -108,9 +133,9 @@ def _triangle(tq: int):
             <= jax.lax.broadcasted_iota(jnp.int32, (tq, tq), 0))
 
 
-def _ssd_kernel(first_ref, cnt_ref, rd_ref, wr_ref, dl_ref, xd_ref, cs_ref,
-                cst_ref, b_ref, c_ref, s_in_ref, y_ref, s_out_ref, s_scr,
-                xw_scr, *, hb: int, heads: int):
+def _ssd_kernel(first_ref, cnt_ref, rd_ref, wr_ref, dl_ref, x_ref, dt_ref,
+                cs_ref, cst_ref, b_ref, c_ref, s_in_ref, y_ref, s_out_ref,
+                s_scr, *, hb: int, heads: int):
     del rd_ref, wr_ref                      # the index maps read them
     blk, i = pl.program_id(0), pl.program_id(1)
     hp, n = s_scr.shape[1], s_scr.shape[2]
@@ -121,15 +146,16 @@ def _ssd_kernel(first_ref, cnt_ref, rd_ref, wr_ref, dl_ref, xd_ref, cs_ref,
 
     @pl.when(cnt_ref[i] > 0)
     def _():
-        xd, cs, cs_t = xd_ref[0], cs_ref[0, 0], cst_ref[0, 0]
+        x, dt, cs, cs_t = x_ref[0], dt_ref[0, 0], cs_ref[0, 0], cst_ref[0, 0]
         bb = b_ref[0]
         g, before = _read_out(bb, c_ref[0], s_scr[...].reshape(hb * hp, n))
-        tri = _triangle(xd.shape[0])
+        tri = _triangle(x.shape[0])
+        xd, carried, xw = _block(hp, x, dt, cs, before)
         for h in range(hb):
             lanes = slice(h * hp, (h + 1) * hp)
-            y_ref[0, :, lanes], xw_scr[:, lanes] = _head(
-                h, hp, xd, cs, cs_t, g, tri, before)
-        upd = _update(xw_scr[...], bb)
+            y_ref[0, :, lanes] = _head(h, hp, xd, cs, cs_t, g, tri) \
+                + carried[:, lanes]
+        upd = _update(xw, bb)
         for h in range(hb):
             s_scr[h] = dl_ref[i * heads + blk * hb + h] * s_scr[h] \
                 + upd[h * hp:(h + 1) * hp]
@@ -137,21 +163,24 @@ def _ssd_kernel(first_ref, cnt_ref, rd_ref, wr_ref, dl_ref, xd_ref, cs_ref,
     s_out_ref[0] = s_scr[...]
 
 
-def _ssd_pallas(xd, cs, dl, b, c, state, first, cnt, rd, wr, *, hb: int,
+def _ssd_pallas(x, dt, cs, dl, b, c, state, first, cnt, rd, wr, *, hb: int,
                 interpret: bool):
-    nt, tq, _ = xd.shape
+    nt, tq, _ = x.shape
     heads, hp, n = state.shape[1:]
     nb = heads // hb
-    # The running sums by head block, the events along the sublanes and
-    # along the lanes: a head's column and row are static slices of them.
-    cs_b = jnp.transpose(cs.reshape(nt, tq, nb, hb), (0, 2, 1, 3))
+
+    def by_block(v):
+        """A number a (tile, event, head) by head block, the events along
+        the sublanes: a head's column is a static slice of it."""
+        return jnp.transpose(v.reshape(nt, tq, nb, hb), (0, 2, 1, 3))
+    cs_b = by_block(cs)
     rows = pl.BlockSpec((1, tq, hb * hp), lambda g, i, *_: (i, 0, g))
+    cols = pl.BlockSpec((1, 1, tq, hb), lambda g, i, *_: (i, g, 0, 0))
     shared = pl.BlockSpec((1, tq, n), lambda g, i, *_: (i, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5, grid=(nb, nt),
         in_specs=[
-            rows,
-            pl.BlockSpec((1, 1, tq, hb), lambda g, i, *_: (i, g, 0, 0)),
+            rows, cols, cols,
             pl.BlockSpec((1, 1, hb, tq), lambda g, i, *_: (i, g, 0, 0)),
             shared, shared,
             pl.BlockSpec((1, hb, hp, n),
@@ -162,71 +191,71 @@ def _ssd_pallas(xd, cs, dl, b, c, state, first, cnt, rd, wr, *, hb: int,
             pl.BlockSpec((1, hb, hp, n),
                          lambda g, i, f, k, rd, wr, dl: (wr[i], g, 0, 0)),
         ],
-        scratch_shapes=[pltpu.VMEM((hb, hp, n), jnp.float32),
-                        pltpu.VMEM((tq, hb * hp), jnp.float32)])
+        scratch_shapes=[pltpu.VMEM((hb, hp, n), jnp.float32)])
     y, state = pl.pallas_call(
         functools.partial(_ssd_kernel, hb=hb, heads=heads),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(xd.shape, jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct(x.shape, jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
-        # operands: 5 prefetched, xd, cs, cs_t, b, c, state
-        input_output_aliases={10: 1},
+        # operands: 5 prefetched, x, dt, cs, cs_t, b, c, state
+        input_output_aliases={11: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=64 << 20),
         name="granite_h_ssd_update", interpret=interpret,
-    )(first, cnt, rd, wr, dl.reshape(-1), xd, cs_b,
+    )(first, cnt, rd, wr, dl.reshape(-1), x, by_block(dt), cs_b,
       jnp.swapaxes(cs_b, 2, 3), b, c, state)
     return y, state
 
 
-def _ssd_xla(xd, cs, dl, b, c, state, first, cnt, rd, wr):
+def _ssd_xla(x, dt, cs, dl, b, c, state, first, cnt, rd, wr):
     del cnt                                 # a padding row has dt 0
     heads, hp, n = state.shape[1:]
-    tri = _triangle(xd.shape[1])
+    tri = _triangle(x.shape[1])
 
     def tile(carry, t):
         state, s = carry
-        xdt, cst, dlt, bb, cc, f, r, w = t
+        xt, dtt, cst, dlt, bb, cc, f, r, w = t
         s = jnp.where(f == 1, state[r], s)
         g, before = _read_out(bb, cc, s.reshape(heads * hp, n))
-        y, xw = zip(*[_head(h, hp, xdt, cst, cst.T, g, tri, before)
-                      for h in range(heads)])
-        upd = _update(jnp.concatenate(xw, axis=1), bb)
+        xd, carried, xw = _block(hp, xt, dtt, cst, before)
+        y = [_head(h, hp, xd, cst, cst.T, g, tri)
+             + carried[:, h * hp:(h + 1) * hp] for h in range(heads)]
+        upd = _update(xw, bb)
         s = dlt[:, None, None] * s + upd.reshape(heads, hp, n)
         return (state.at[w].set(s), s), jnp.concatenate(y, axis=1)
 
     (state, _), y = jax.lax.scan(
         tile, (state, jnp.zeros(state.shape[1:], state.dtype)),
-        (xd, cs, dl, b, c, first, rd, wr))
+        (x, dt, cs, dl, b, c, first, rd, wr))
     return y, state
 
 
 def ssd_update(x, dt, b, c, a, state, first, cnt, rd, wr, *,
                hb: int = HEAD_BLOCK, use_pallas=None
                ) -> Tuple[jax.Array, jax.Array]:
-    """``x`` [tiles, tq, heads, P] float32; ``dt`` [tiles, tq, heads]
-    float32 (0 on a tile's rows past its count); ``b``, ``c`` [tiles, tq,
-    N]; ``a`` [heads] = -exp(A_log); ``state`` [slots,
-    heads, P, N] float32 (donated to the result); per tile: ``first`` (1
-    at a user's first tile: the state is read from slot ``rd``), ``cnt``
-    real events, ``wr`` the slot the state after the tile is written to (a
-    user's tiles name one slot; a padding tile names the slots of the
-    tile before it, with ``first`` 0, and so moves nothing).  Returns (y
-    [tiles, tq, heads, P] float32 = ``S_t C_t``: the skip term ``D_h
-    x_t[h]`` is the caller's, on the event's own row; the state array)."""
+    """``x`` [tiles, tq, heads * P] float32, head ``h``'s channels lanes
+    ``h P ... (h + 1) P``; ``dt`` [tiles, tq, heads] float32 (0 on a
+    tile's rows past its count), a number a head: the kernel spreads it
+    (and the running sums made from it here) over the head's lanes, the
+    caller never does; ``b``, ``c`` [tiles, tq, N]; ``a`` [heads] =
+    -exp(A_log); ``state`` [slots, heads, P, N] float32 (donated to the
+    result); per tile: ``first`` (1 at a user's first tile: the state is
+    read from slot ``rd``), ``cnt`` real events, ``wr`` the slot the
+    state after the tile is written to (a user's tiles name one slot; a
+    padding tile names the slots of the tile before it, with ``first``
+    0, and so moves nothing).  Returns (y [tiles, tq, heads * P] float32
+    = ``S_t C_t``: the skip term ``D_h x_t[h]`` is the caller's, on the
+    event's own row; the state array)."""
     if use_pallas is None:
         use_pallas = pallas_supported()
-    nt, tq, heads, hp = x.shape
+    heads = state.shape[1]
     # The running sums of the log-decays, a number a (tile, event, head),
     # are made here, by XLA.
     cs = jnp.cumsum(dt * a, axis=1)                 # [tiles, tq, heads]
-    args = ((dt[..., None] * x).reshape(nt, tq, heads * hp), cs,
-            jnp.exp(cs[:, -1]), b.astype(jnp.bfloat16),
+    args = (x, dt, cs, jnp.exp(cs[:, -1]), b.astype(jnp.bfloat16),
             c.astype(jnp.bfloat16), state, first, cnt, rd, wr)
     if use_pallas:
-        y, state = _ssd_pallas(*args, hb=hb if heads % hb == 0 else heads,
-                               interpret=not pallas_supported())
-    else:
-        y, state = _ssd_xla(*args)
-    return y.reshape(x.shape), state
+        return _ssd_pallas(*args, hb=hb if heads % hb == 0 else heads,
+                           interpret=not pallas_supported())
+    return _ssd_xla(*args)

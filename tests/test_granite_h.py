@@ -143,6 +143,10 @@ def test_the_published_shape():
     assert layout["fixed_bytes"] == 36 * (64 * 64 * 128 + 3 * 4352) * 4 \
         + 2048 * 4 == 77_385_728
     assert layout["paged_bytes"] == 4 * 128 * 1024 * 2 == 1 << 20
+    # ... the convolution's three rows as ONE row a slot, 102 x 128 lanes.
+    arrays = jax.eval_shape(lambda: layout["allocate"](66, 4))
+    assert arrays["c35"].shape == (66, 3 * 4352) == (66, 102 * 128)
+    assert arrays["s35"].shape == (66, 64, 64, 128)
     assert granite_h.READ_BUCKETS == (0, 8, 32)
     with pytest.raises(ValueError, match="routed experts"):
         granite_h.GraniteHConfig.from_published({**doc,
@@ -282,14 +286,15 @@ def test_a_state_stored_in_bfloat16_would_not_pass():
     rng = np.random.default_rng(2)
     heads, hp, n, tq = 8, 8, 16, 8
     a = -jnp.asarray(np.geomspace(0.02, 1.0, heads), jnp.float32)
-    x = rng.normal(size=(N, heads, hp)).astype(np.float32)
+    x = rng.normal(size=(N, heads * hp)).astype(np.float32)
     dt = rng.uniform(0.05, 0.15, (N, heads)).astype(np.float32)
     b, c = (rng.normal(size=(N, n)).astype(np.float32) for _ in range(2))
     truth = np.zeros((heads, hp, n))
     for t in range(N):
         truth = np.exp(np.float64(dt[t]) * np.asarray(a, np.float64)
                        )[:, None, None] * truth \
-            + (np.float64(dt[t])[:, None] * x[t])[:, :, None] * b[t]
+            + (np.float64(dt[t])[:, None]
+               * x[t].reshape(heads, hp))[:, :, None] * b[t]
 
     def chain(stored):
         state = jnp.zeros((4, heads, hp, n), jnp.float32)
@@ -348,10 +353,10 @@ def test_float8_weights_move_the_answers(params, history, want):
 
 # -- the kernel against its XLA twin and the recurrence ----------------------
 
-def _tiles(rng, nt=6, tq=16, heads=8, hp=8, n=16, slots=8):
+def _tiles(rng, hp, nt=6, tq=16, heads=8, n=16, slots=8):
     cnt = np.array([16, 3, 5, 16, 0, 0])
     real = np.arange(tq)[None, :] < cnt[:, None]
-    x = jnp.asarray(rng.normal(size=(nt, tq, heads, hp)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(nt, tq, heads * hp)), jnp.float32)
     dt = jnp.asarray(np.where(real[..., None], rng.uniform(
         1e-3, 0.5, (nt, tq, heads)), 0.0), jnp.float32)
     b, c = (jnp.asarray(rng.normal(size=(nt, tq, n)), jnp.float32)
@@ -367,12 +372,21 @@ def _tiles(rng, nt=6, tq=16, heads=8, hp=8, n=16, slots=8):
     return (x, dt, b, c, a, state, *per_tile), real
 
 
-def test_ssd_kernel_matches_its_twin_and_the_recurrence():
-    args, real = _tiles(np.random.default_rng(0))
+@pytest.mark.parametrize("hp", [8, 32], ids=["64-lanes", "256-lanes"])
+def test_ssd_kernel_matches_its_twin_and_the_recurrence(hp):
+    """The rows flat, ``heads * P`` channels along the lanes (a head
+    block's 4 x P of them no multiple of 128, and one), ``dt`` a number a
+    head: the kernel (interpreted) against its twin, and both against
+    the recurrence an event at a time."""
+    args, real = _tiles(np.random.default_rng(0), hp)
     x, dt, b, c, a, state = (np.asarray(v, np.float64)
                              for v in args[:6])
+    heads = dt.shape[-1]
+    assert args[0].shape == dt.shape[:2] + (heads * hp,)
+    x = x.reshape(x.shape[:2] + (heads, hp))
     y_x, s_x = granite_h_kernels.ssd_update(*args, use_pallas=False)
     y_p, s_p = granite_h_kernels.ssd_update(*args, use_pallas=True, hb=4)
+    assert y_x.shape == y_p.shape == args[0].shape
     at = real.nonzero()
     np.testing.assert_allclose(np.asarray(y_p)[at], np.asarray(y_x)[at],
                                rtol=1e-5, atol=1e-5)
@@ -392,12 +406,92 @@ def test_ssd_kernel_matches_its_twin_and_the_recurrence():
         for t in range(cnt[i]):
             s = np.exp(dt[i, t] * a)[:, None, None] * s \
                 + (dt[i, t][:, None] * x[i, t])[:, :, None] * b[i, t]
-            want_y = s @ c[i, t]
+            want_y = (s @ c[i, t]).reshape(-1)
             # bfloat16 inputs of the tile's products: 2^-8 of |y| ~ 30
             np.testing.assert_allclose(y_x[i, t], want_y, atol=0.25,
                                        rtol=0.02)
         want_state[wr[i]] = s
     np.testing.assert_allclose(s_x, want_state, atol=0.03, rtol=0.01)
+
+
+def test_a_heads_number_is_spread_over_its_lanes_exactly():
+    """``_spread``: a float32 number a (row, head), of any size, comes
+    out on each of the head's lanes bit for bit (the 0/1 product in full
+    float32 adds one term and zeros)."""
+    rng = np.random.default_rng(4)
+    v = jnp.asarray(rng.normal(size=(48, 8))
+                    * np.exp(8 * rng.normal(size=(48, 8))), jnp.float32)
+    for hp in (8, 64):
+        np.testing.assert_array_equal(
+            jax.jit(granite_h_kernels._spread, static_argnums=1)(v, hp),
+            np.repeat(np.asarray(v), hp, axis=1))
+
+
+# -- the convolution against the plain recurrence ----------------------------
+
+def _plain_conv(w, rows):
+    """``sum_j w[j] * rows[t - 3 + j]`` over a user's whole line of input
+    rows from nothing, float32, the newest tap first (the program's order
+    of the sum) -> [len(rows), C]."""
+    k = len(w) - 1
+    line = np.concatenate([np.zeros((k, rows.shape[1]), np.float32), rows])
+    out = w[k] * line[k:]
+    for j in range(1, k + 1):
+        out = out + w[k - j] * line[k - j:len(line) - j]
+    return out
+
+
+@pytest.mark.parametrize("held", [0, 1, 2, 3])
+@pytest.mark.parametrize("events", [1, 2, 3, 4, 16, 17])
+def test_the_convolution_is_the_plain_one_bit_for_bit(events, held):
+    """A segment of ``events`` events of a user whose stored rows hold
+    ``held`` real events (the rest zeros: the user's line began there),
+    then a second user's segment (another length, another past) and
+    padding tokens, in one batch: every new row of the convolution and
+    every new stored row are the plain sum's over the user's whole line,
+    bit for bit in float32; the padding segment's slots keep the zero
+    slot's rows and no other slot is touched."""
+    rng = np.random.default_rng(100 * events + held)
+    cw, k, t = CFG.conv_width, CFG.mamba_d_conv - 1, 32
+    p = {"conv_w": jnp.asarray(rng.normal(size=(k + 1, cw)), jnp.float32),
+         "conv_b": jnp.asarray(rng.normal(size=cw), jnp.float32)}
+    w = np.asarray(p["conv_w"])
+    lens, past = [events, 1 + (events + held) % 5], [held, (held + 2) % 4]
+    lines = [rng.normal(size=(h + n, cw)).astype(np.float32)
+             for h, n in zip(past, lens)]
+    tails = rng.normal(size=(6, k * cw)).astype(np.float32)
+    tails[0] = 0.0                                  # the zero slot
+    for slot, line, h in zip((2, 3), lines, past):
+        tails[slot] = np.concatenate(
+            [np.zeros((k, cw), np.float32), line[:h]])[-k:].reshape(-1)
+    x = rng.normal(size=(t, cw)).astype(np.float32)     # padding: anything
+    x[:lens[0]], x[lens[0]:sum(lens)] = lines[0][held:], lines[1][past[1]:]
+    pad = t - sum(lens)
+    batch = {
+        "tok_seg": np.concatenate([np.repeat([0, 1], lens), -np.ones(pad)]),
+        "tok_idx": np.concatenate([np.arange(lens[0]), np.arange(lens[1]),
+                                   np.zeros(pad)]),
+        # two segments and a padding one: the zero slot -> the scrap slot
+        "seg_read": [2, 3, 0], "seg_write": [4, 5, 1],
+        "seg_last": [lens[0] - 1, sum(lens) - 1, 0],
+        "seg_len": lens + [0]}
+    batch = {name: jnp.asarray(v, jnp.int32) for name, v in batch.items()}
+    # Op by op: inside one program XLA's CPU backend contracts a product
+    # and a sum into one rounding, which the plain sum does not.
+    got, new = (np.asarray(v) for v in granite_h._conv(
+        CFG, p, jnp.asarray(x), batch, jnp.asarray(tails)))
+    at = 0
+    for slot, line, h, n in zip((4, 5), lines, past, lens):
+        want = jax.nn.silu(jnp.asarray(_plain_conv(w, line)[h:])
+                           + p["conv_b"])
+        np.testing.assert_array_equal(got[at:at + n], np.asarray(want))
+        np.testing.assert_array_equal(
+            new[slot], np.concatenate([np.zeros((k, cw), np.float32),
+                                       line])[-k:].reshape(-1))
+        at += n
+    np.testing.assert_array_equal(new[1], tails[0])
+    np.testing.assert_array_equal(new[[0, 2, 3]], tails[[0, 2, 3]])
+    assert np.isfinite(got).all()
 
 
 def test_the_reference_in_blocks_is_the_recurrence():

@@ -1,6 +1,6 @@
 """Mosaic's verdict on the kernels of the main paths at real widths (the
 ALS dense gram and lanes solve; the sequence engine's selected attention
-and lightning update, its Mamba-2 update), with no
+and lightning update, its Mamba-2 update and the program around it), with no
 chip: libtpu compiles for a described v5e in the sandbox (PERF.md, PR 21).
 It proves compilation, not results.  The topology is described inside a
 fixture, by the one worker that runs this file; keep every such test
@@ -187,14 +187,14 @@ def test_ssd_update_kernel_compiles_for_v5e(one_chip, tiles, tq):
     def shape(dims, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-    rows = shape((tiles, tq, 64 * 64))
+    rows, heads = shape((tiles, tq, 64 * 64)), shape((tiles, tq, 64))
     compiled = jax.jit(
-        lambda xd, cs, dl, b, c, state, *per_tile:
+        lambda x, dt, cs, dl, b, c, state, *per_tile:
         granite_h_kernels._ssd_pallas(
-            xd, cs, dl, b, c, state, *per_tile,
+            x, dt, cs, dl, b, c, state, *per_tile,
             hb=granite_h_kernels.HEAD_BLOCK, interpret=False),
-        donate_argnums=(5,)).lower(
-        rows, shape((tiles, tq, 64)), shape((tiles, 64)),
+        donate_argnums=(6,)).lower(
+        rows, heads, heads, shape((tiles, 64)),
         shape((tiles, tq, 128), jnp.bfloat16),
         shape((tiles, tq, 128), jnp.bfloat16), shape((66, 64, 64, 128)),
         *[shape((tiles,), jnp.int32)] * 4).compile()
@@ -221,6 +221,91 @@ def test_gqa_attention_kernel_compiles_for_v5e(one_chip, tiles, tq):
         shape((2901 * 128, 1024), jnp.bfloat16)).compile()
     # the name gqa_attn_ms and gqa_attn_roofline read
     assert "granite_h_gqa_attention" in compiled.as_text()
+
+
+# The Mamba-2 mixer's arrays between its two products (PERF.md §6, PR 44):
+# the (128, 32) program of a two-layer backbone (one ``mamba``, one
+# ``attention``) at the published widths over the cell's 66 slots, the
+# arguments row-major as ``device_put`` leaves them on the chip.  Every
+# array of the Mamba-2 layer keeps its channels along the lanes from the
+# in-projection to the out-projection: nothing float32 of a megabyte is
+# shaped ``[..., 64, 64]`` (the head-major view), no state array of the
+# pool's 66 slots is copied or reshaped (the parent's tails: four copies of
+# ``[66, 3, 4352]`` a layer), and no ``[128, 4352]`` / ``[128, 4096]`` row
+# array is turned tokens-minor and back.
+
+def _entry_results(text):
+    """(op, dtype, dims, bytes) of every instruction of the ENTRY
+    computation whose result is one array."""
+    import re
+
+    entry = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", text, re.S | re.M).group(1)
+    size = {"f32": 4, "s32": 4, "bf16": 2}
+    out = []
+    for m in re.finditer(
+            r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(",
+            entry, re.M):
+        dims = tuple(int(d) for d in m.group(2).split(",") if d)
+        n = size.get(m.group(1), 1)
+        for d in dims:
+            n *= d
+        out.append((m.group(3), m.group(1), dims, n))
+    return out
+
+
+def test_the_mamba_mixer_keeps_one_layout_between_its_products(
+        one_chip, monkeypatch):
+    from jax.experimental.layout import Format, Layout
+
+    from predictionio_tpu.models import granite_h
+    from predictionio_tpu.ops import granite_h_kernels, sambay_kernels
+
+    def shape(x):
+        at = one_chip if x.ndim < 2 else Format(
+            Layout(major_to_minor=tuple(range(x.ndim))), one_chip)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=at)
+
+    cfg = granite_h.GraniteHConfig(
+        vocab_size=100352, hidden_size=2048, intermediate_size=8192,
+        num_attention_heads=32, num_key_value_heads=8, head_dim=64,
+        layer_types=("mamba", "attention"), mamba_n_heads=64,
+        mamba_d_head=64, mamba_d_state=128)
+    # the code asks the backend, which is the CPU here: steer it to the
+    # compiled kernels, as on the chip
+    for module in (granite_h_kernels, sambay_kernels):
+        monkeypatch.setattr(module, "pallas_supported", lambda: True)
+    params = jax.eval_shape(lambda: granite_h.cast_for_serving(
+        granite_h.init_params(cfg, jax.random.PRNGKey(0))))
+    state = jax.eval_shape(
+        lambda: granite_h.state_layout(cfg, 128)["allocate"](66, 2900))
+    state["table"] = jax.ShapeDtypeStruct((33, granite_h.TABLE_LEN),
+                                          jnp.int32)
+    step = granite_h.GraniteHStep(cfg)
+
+    class Cache:
+        page_size = 128
+    sizes = granite_h.vector_sizes(128, 32, step.shapes(128, 32, Cache))
+    text = step.program(Cache, 128, 32, 10).lower(
+        jax.tree_util.tree_map(shape, params),
+        jax.tree_util.tree_map(shape, state),
+        shape(jax.ShapeDtypeStruct((sum(sizes),), jnp.int32))
+    ).compile().as_text()
+    # the names ssd_update_ms and gqa_attn_ms read
+    assert "granite_h_ssd_update" in text
+    assert "granite_h_gqa_attention" in text
+    results = _entry_results(text)
+    assert len(results) > 100
+    head_major = [r for r in results if r[1] == "f32" and r[3] >= 1 << 20
+                  and r[2][-2:] == (64, 64)]
+    assert not head_major, head_major
+    relayouts = [r for r in results if r[0] in ("copy", "reshape")]
+    of_the_pool = [r for r in relayouts if r[2][:1] == (66,)]
+    assert not of_the_pool, of_the_pool
+    of_the_rows = [r for r in relayouts if r[1] == "f32"
+                   and r[2] in ((128, 4352), (128, 4096), (128, 8512))]
+    assert not of_the_rows, of_the_rows
+    # what is left is the attention layer's (its query rows and output)
+    assert sum(r[3] for r in relayouts) < 40e6, relayouts
 
 
 # The ALS gather's step (PERF.md §6, PR 36): XLA:TPU keeps a gather's
